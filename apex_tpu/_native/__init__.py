@@ -7,8 +7,9 @@ Pallas; this module is the native host runtime: multithreaded
 flatten/unflatten of numpy buffers, DDP bucket planning, and the digest
 primitive for the L1 conformance harness.
 
-The library auto-builds from ``csrc/`` on first import when a toolchain is
-present (``make -C csrc``); everything has a pure-numpy fallback, and
+Every import runs ``make -C csrc`` when the source tree is present (a
+no-op unless ``csrc/`` is newer than the library); everything has a
+pure-numpy fallback, taken with a warning when the build or load fails, and
 ``available`` mirrors ``multi_tensor_applier.available`` in the reference —
 consumers probe it and degrade gracefully.  Set ``APEX_TPU_NATIVE=0`` to
 force the fallback.
@@ -19,6 +20,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import warnings
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -38,7 +40,9 @@ def _load() -> None:
         import_err = RuntimeError("disabled via APEX_TPU_NATIVE=0")
         return
     try:
-        if not os.path.exists(_LIB_PATH) and os.path.isdir(_CSRC):
+        if os.path.isdir(_CSRC):
+            # make decides: its rule rebuilds only when csrc/ is newer,
+            # so a stale binary never shadows the source
             subprocess.run(["make", "-C", _CSRC], check=True,
                            capture_output=True)
         lib = ctypes.CDLL(_LIB_PATH)
@@ -62,8 +66,10 @@ def _load() -> None:
             raise RuntimeError("apex_tpu_C ABI version mismatch")
         _lib = lib
         available = True
-    except BaseException as e:  # noqa: BLE001 — mirror reference import probe
+    except Exception as e:  # noqa: BLE001 — mirror reference import probe
         import_err = e
+        warnings.warn(f"apex_tpu._native unavailable, using the numpy "
+                      f"fallback: {type(e).__name__}: {e}", RuntimeWarning)
 
 
 _load()

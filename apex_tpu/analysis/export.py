@@ -319,18 +319,26 @@ def _entry_dir(cache_dir, key: str) -> Path:
 
 def serialize_compiled(compiled) -> bytes:
     """One blob for one ``jax.stages.Compiled``: the PJRT executable
-    serialization plus the arg/out pytree structure it is called
-    through (``jax.experimental.serialize_executable`` returns them
-    separately; the cache stores the whole calling convention)."""
+    serialization, the arg/out pytree structure it is called through
+    (``jax.experimental.serialize_executable`` returns them
+    separately; the cache stores the whole calling convention), and
+    the ids of the devices it was compiled for, in assignment order —
+    a loaded executable runs on those and no others."""
     from jax.experimental import serialize_executable as se
-    return pickle.dumps(se.serialize(compiled))
+    device_ids = [d.id for d in
+                  compiled._executable.xla_executable.local_devices()]
+    return pickle.dumps(se.serialize(compiled) + (device_ids,))
 
 
 def deserialize_compiled(blob: bytes, backend=None):
     from jax.experimental import serialize_executable as se
-    serialized, in_tree, out_tree = pickle.loads(blob)
-    return se.deserialize_and_load(serialized, in_tree, out_tree,
-                                   backend=backend)
+    serialized, in_tree, out_tree, device_ids = pickle.loads(blob)
+    if backend is None or isinstance(backend, str):
+        backend = jax.devices(backend)[0].client
+    by_id = {d.id: d for d in backend.devices()}
+    return se.deserialize_and_load(
+        serialized, in_tree, out_tree, backend=backend,
+        execution_devices=[by_id[i] for i in device_ids])
 
 
 def write_entry(cache_dir, key: str, parts: Mapping[str, Any],

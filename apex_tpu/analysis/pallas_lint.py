@@ -173,8 +173,9 @@ def _is_smem(aval) -> bool:
 
 
 def _block_dims(block_shape) -> Tuple[int, ...]:
-    """Block extents as ints — squeezed (``Mapped``) dims are size 1."""
-    return tuple(int(b) if isinstance(b, int) else 1 for b in block_shape)
+    """Block extents as ints — ``Squeezed`` dims (no ``block_size``)
+    are size 1."""
+    return tuple(int(getattr(b, "block_size", 1)) for b in block_shape)
 
 
 def describe_call(eqn) -> KernelCall:
@@ -182,17 +183,13 @@ def describe_call(eqn) -> KernelCall:
     params = eqn.params
     gm = params["grid_mapping"]
     grid = tuple(gm.grid)
-    nsi = params.get("name_and_src_info")
-    name = getattr(nsi, "name", None) or "pallas_call"
+    name = (params.get("name")
+            or getattr(params["jaxpr"].debug_info, "func_name", None)
+            or "pallas_call")
 
-    sem_raw = None
-    cp = params.get("compiler_params") or {}
-    mosaic = cp.get("mosaic") if isinstance(cp, dict) else None
-    if mosaic is not None:
-        sem_raw = (mosaic.get("dimension_semantics")
-                   if isinstance(mosaic, dict)
-                   else getattr(mosaic, "dimension_semantics", None))
-    sem = tuple(str(s) for s in sem_raw) if sem_raw else ()
+    mosaic = (params.get("compiler_params") or {}).get("mosaic_tpu")
+    sem_raw = getattr(mosaic, "dimension_semantics", None)
+    sem = tuple(getattr(s, "value", s) for s in sem_raw) if sem_raw else ()
     # undeclared dims default to "arbitrary" (sequential) — Mosaic's own
     # default, and the conservative one for the race rules
     sem = sem + ("arbitrary",) * (len(grid) - len(sem))
@@ -200,7 +197,7 @@ def describe_call(eqn) -> KernelCall:
     operands: List[Operand] = []
     n_in = int(gm.num_inputs)
     for i, bm in enumerate(gm.block_mappings):
-        sd = bm.array_shape_dtype
+        sd = bm.array_aval
         operands.append(Operand(
             index=i, role="in" if i < n_in else "out",
             name=str(getattr(bm, "origin", "") or f"operand{i}"),

@@ -32,9 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from apex_tpu.utils.jax_compat import axis_size as _axis_size
-from apex_tpu.utils.jax_compat import pvary as _pvary
-
 NEG_INF = -1e30
 
 
@@ -52,7 +49,7 @@ def _vary_like(reference_array, axis_name):
         vma = tuple(set(jax.typeof(reference_array).vma) | {axis_name})
     except Exception:
         vma = (axis_name,)
-    return lambda t: _pvary(t, vma)
+    return lambda t: lax.pcast(t, vma, to="varying")
 
 
 def _block_scores(q, k, scale, q_off, k_off, causal, kv_mask):
@@ -79,7 +76,7 @@ def _ring_attention_flash(q, k, v, axis_name, causal, kv_mask, scale):
     from apex_tpu.ops.pallas.flash_attention import NEG_INF as FLASH_NEG
     from apex_tpu.ops.pallas.flash_attention import flash_attention
 
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     b, l_local, h, d = q.shape
     if scale is None:
@@ -172,7 +169,7 @@ def ring_attention(
     if impl == "flash" or (impl is None and _use_pallas_blocks()):
         return _ring_attention_flash(q, k, v, axis_name, causal, kv_mask,
                                      scale)
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     b, l_local, h, d = q.shape
     if scale is None:
@@ -237,7 +234,7 @@ def ulysses_attention(
     preferable to the ring when heads are plentiful and the sequence fits
     once per device.
     """
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
     b, l_local, h, d = q.shape
     if h % world != 0:
         raise ValueError(f"heads ({h}) must divide by the axis size "

@@ -35,6 +35,8 @@ import threading
 import time
 from typing import Callable, List, Optional
 
+import jax
+
 from apex_tpu.obs import metrics as metrics_mod
 from apex_tpu.utils.profiling import nvtx_range
 
@@ -56,11 +58,7 @@ def current_path() -> str:
 
 def _tracing() -> bool:
     """True while jax is tracing (span timings suppressed there)."""
-    try:
-        import jax
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - very old/new jax
-        return False
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def metric_name(path: str) -> str:

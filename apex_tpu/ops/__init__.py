@@ -14,6 +14,7 @@ of the reference L1 harness's ext-vs-no-ext install axis
 """
 
 import os
+import re
 
 import jax
 
@@ -34,6 +35,30 @@ def use_pallas() -> bool:
     if mode == "jnp":
         return False
     return on_tpu()
+
+
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def mosaic_kernels(hlo_text: str) -> list:
+    """Sorted names of the Mosaic custom calls (compiled Pallas kernels)
+    in a compiled program's HLO text — what ran, read off the executable
+    rather than assumed from ``use_pallas()``.  A name is the scope just
+    above ``pallas_call`` in the call's ``op_name`` metadata: the
+    ``name=`` every production ``pallas_call`` in this package passes."""
+    names = set()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = _OP_NAME_RE.search(line)
+        scopes = m.group(1).split("/") if m else []
+        if "pallas_call" in scopes[1:]:
+            # autodiff wraps the scope: transpose(jvp(layer_norm_bwd))
+            scope = scopes[scopes.index("pallas_call", 1) - 1]
+            names.add(re.findall(r"[A-Za-z_]\w*", scope)[-1])
+        else:
+            names.add(m.group(1) if m else "<no op_name>")
+    return sorted(names)
 
 
 def sds(shape, dtype, *likes):

@@ -202,8 +202,9 @@ def packed_adam_tree(p: jax.Array, m: jax.Array, v: jax.Array, g: jax.Array,
         out_specs=[spec(), spec(), spec()],
         out_shape=[sds((n // _LANES, _LANES), jnp.float32, p, m, v, g)
                    for _ in range(3)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="adam_tree",
         interpret=not on_tpu(),
     )(scalars, steps, _view2d(p), _view2d(m), _view2d(v), _view2d(g))
     return tuple(o.reshape(-1) for o in outs)
@@ -265,8 +266,9 @@ def packed_adam(p: jax.Array, m: jax.Array, v: jax.Array, g: jax.Array,
         # every grid step touches disjoint row blocks, so the in-place
         # aliasing (donate) is hazard-free under either semantics
         input_output_aliases={1: 0, 2: 1, 3: 2} if donate else {},
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="adam",
         interpret=not on_tpu(),
     )(scalars, *(t.reshape(rows, lanes) for t in (p, m, v, g)))
     return tuple(o.reshape(-1) for o in outs)

@@ -514,6 +514,7 @@ def _flash_bwd_fused(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        name="flash_bwd_fused",
         interpret=not on_tpu(),
     )(qf, kf, vf, do_f, lse, delta, bias, *rope_ops)
     dq = dq_part.sum(axis=0).astype(qf.dtype)
@@ -621,6 +622,7 @@ def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=not on_tpu(),
     )(qf, kf, vf, bias, *rope_ops)
     return o, lse
@@ -665,6 +667,7 @@ def _flash_bwd(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f, *,
                                lambda bh_, iq, ik: (bh_, iq, 0)),
         out_shape=_sds((bh, lp, d), qf.dtype, qf),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=not on_tpu(),
     )(*common_in, *rope_ops_q)
 
@@ -695,6 +698,7 @@ def _flash_bwd(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f, *,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
+        name="flash_bwd_dkv",
         interpret=not on_tpu(),
     )(*common_in, *rope_ops_k)
     return dq, dk, dv
@@ -782,9 +786,18 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, has_bias, rope_mode,
     dv = _unprep(dvf, b, l, h, d, layout)
     # The rope tables are position functions (int positions carry no
     # gradient); their zero cotangents DCE under jit.
-    return (dq, dk, dv, jnp.zeros((b, l), jnp.float32),
-            jnp.zeros(table_shape, cos_t.dtype),
-            jnp.zeros(table_shape, sin_t.dtype))
+    return (dq, dk, dv, _zeros_typed_like((b, l), bias_p),
+            _zeros_typed_like(table_shape, cos_t),
+            _zeros_typed_like(table_shape, sin_t))
+
+
+def _zeros_typed_like(shape, like):
+    """Zero cotangent of ``shape`` with ``like``'s dtype and varying-axes
+    type: under ``shard_map`` custom_vjp holds a cotangent to the type of
+    its primal, and fresh zeros vary over nothing."""
+    zeros = jnp.zeros(shape, like.dtype)
+    vma = tuple(jax.typeof(like).vma)
+    return jax.lax.pcast(zeros, vma, to="varying") if vma else zeros
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -846,23 +859,6 @@ def _warn_block_override(name: str, asked: int, got: int) -> None:
         stacklevel=3)
 
 
-def _varying(x) -> bool:
-    try:
-        return bool(jax.typeof(x).vma)
-    except Exception:
-        pass
-    # pre-VMA jax has no varying type to ask; any active mapped axis
-    # (legacy shard_map / pmap trace) means x MAY be device-varying,
-    # which is the same "don't run the pallas interpreter" situation
-    # the VMA check routes around (and legacy check_rep has no
-    # replication rule for pallas_call at all)
-    try:
-        from jax._src.core import get_axis_env
-        return bool(get_axis_env().axis_sizes)
-    except Exception:
-        return False
-
-
 def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
                     block_q=None, block_k=None, return_lse=False,
                     layout="blhd", rope=None):
@@ -915,7 +911,7 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
     if rope is not None and k.shape[seq_ax] != l:
         raise ValueError("rope requires self-attention (Lq == Lk): q and "
                          "k share one position table")
-    if k.shape[seq_ax] != l or (not on_tpu() and _varying(q)):
+    if k.shape[seq_ax] != l or (not on_tpu() and jax.typeof(q).vma):
         # Cross-attention (blockwise packing needs one shared length) and
         # interpret-mode-under-shard_map (a VMA propagation limitation in
         # jax's pallas interpreter; compiled Mosaic is unaffected) route

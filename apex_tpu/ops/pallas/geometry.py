@@ -27,8 +27,8 @@ Two selection surfaces:
   partial-norm slot).  K is capped by ``max_unroll`` — Mosaic compile
   time scales with the unrolled sub-block count.
 
-The VMEM budget is half the chip's ~16 MiB VMEM by default (the other
-half belongs to Mosaic's own scratch and the double-buffer partner),
+The VMEM budget is half of Mosaic's default 16 MiB scoped limit (the
+other half belongs to the kernel body's own working set),
 overridable via ``APEX_TPU_VMEM_BUDGET_MB`` for experiments; per-call
 geometry overrides (the ``block_rows=`` / ``chunks_per_block=`` kwargs
 on the kernels) are what ``tools/kernel_bench.py --autotune`` sweeps.
@@ -54,8 +54,12 @@ from apex_tpu.ops.packing import round_up as _round_up
 #: sublanes), and halving steps keep the autotune sweep small.
 BLOCK_ROWS_LADDER = (1024, 512, 256, 128, 64, 32, 16, 8)
 
-#: Default streaming VMEM budget (bytes): half of the ~16 MiB core VMEM.
-DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+#: Mosaic's default scoped-VMEM limit for one kernel (v5e); a kernel that
+#: needs more says so with ``vmem_limit_bytes``.
+DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+
+#: Default streaming VMEM budget (bytes): half of that limit.
+DEFAULT_VMEM_BUDGET = DEFAULT_SCOPED_VMEM // 2
 
 #: Static-unroll cap for multi-chunk grid steps (compile-time bound).
 DEFAULT_MAX_UNROLL = 8

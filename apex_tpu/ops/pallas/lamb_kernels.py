@@ -211,8 +211,9 @@ def packed_lamb_stage1(g: jax.Array, p: jax.Array, m: jax.Array,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
+        name="lamb_stage1",
         interpret=not on_tpu(),
     )(scalars, decay, bc1, bc2, _view2d(g), _view2d(p), _view2d(m),
       _view2d(v))
@@ -269,8 +270,9 @@ def packed_lamb_stage2(p: jax.Array, u: jax.Array,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="lamb_stage2",
         interpret=not on_tpu(),
     )(ratio, _view2d(p), _view2d(u))
     if p_copy_dtype is None:

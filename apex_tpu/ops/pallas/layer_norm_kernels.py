@@ -70,10 +70,29 @@ def supported(n2: int, dtype=None) -> bool:
         return False
     if dtype is None:
         return True
-    itemsize = jnp.dtype(dtype).itemsize
-    streams = 2 * 3 * _BLOCK_ROWS * n2 * itemsize   # x, dy, dx x2 buffers
+    streams = _bwd_stream_bytes(n2, jnp.dtype(dtype).itemsize)
     tables = 3 * 4 * n2 + 2 * 2 * 4 * _BLOCK_ROWS   # w/dw/db + mean/inv
     return streams + tables <= 2 * geometry.vmem_budget()
+
+
+def _bwd_stream_bytes(n2: int, itemsize: int) -> int:
+    return 2 * 3 * _BLOCK_ROWS * n2 * itemsize      # x, dy, dx x2 buffers
+
+
+#: fp32 copies of one row block the backward body keeps live besides the
+#: streamed blocks.  Mosaic counts them against the same scoped limit: it
+#: refused the 4096-feature fp32 backward at 17.99 MiB, 12.4 MiB of
+#: streams plus about 2.8 such blocks (v5e, PR 21).  Rounded up, with
+#: room for the casts of half-precision inputs.
+_BWD_BODY_BLOCKS = 6
+
+
+def _bwd_vmem_limit(n2: int, itemsize: int) -> int:
+    """Scoped-VMEM limit the backward asks Mosaic for: its streams plus
+    the body's working set, never under the 16 MiB default."""
+    body = _BWD_BODY_BLOCKS * _BLOCK_ROWS * n2 * 4
+    return max(geometry.DEFAULT_SCOPED_VMEM,
+               _bwd_stream_bytes(n2, itemsize) + body)
 
 
 def _fwd_kernel(x_ref, w_ref, b_ref, y_ref, mean_ref, inv_ref, *, eps,
@@ -154,8 +173,9 @@ def _forward(x2d, w, b, eps: float, affine: bool,
             sds((n1, 1), jnp.float32, x2d),
             sds((n1, 1), jnp.float32, x2d),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="layer_norm_fwd",
         interpret=not on_tpu(),
     )(x2d, w2, b2)
     return y, mean, inv
@@ -193,6 +213,9 @@ def _backward(dy, x2d, w, mean, inv, affine: bool):
             sds((1, n2), jnp.float32, x2d, dy, w),
             sds((1, n2), jnp.float32, x2d, dy, w),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_bwd_vmem_limit(n2, x2d.dtype.itemsize)),
+        name="layer_norm_bwd",
         interpret=not on_tpu(),
     )(dyp, xp, w2, meanp, invp)
     return dx[:n1], dw.reshape(n2), db.reshape(n2)
@@ -212,6 +235,13 @@ def _ln_affine_fwd(x2d, w, b, eps):
 def _ln_affine_bwd(eps, res, dy):
     x2d, w, mean, inv = res
     dx, dw, db = _backward(dy, x2d, w, mean, inv, affine=True)
+    # Under shard_map a replicated weight meets rows that vary over mesh
+    # axes (sequence parallelism): its cotangent is the sum over those
+    # axes — what autodiff's transpose of the implicit broadcast does on
+    # the jnp path, and what custom_vjp's type check demands here.
+    rows_only = tuple(jax.typeof(dw).vma - jax.typeof(w).vma)
+    if rows_only:
+        dw, db = jax.lax.psum((dw, db), rows_only)
     return dx, dw.astype(w.dtype), db.astype(w.dtype)
 
 

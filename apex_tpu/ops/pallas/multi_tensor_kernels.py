@@ -90,6 +90,7 @@ def packed_scale(flat: jax.Array, scale: jax.Array, chunk_size: int,
         # and halves the HBM traffic; XLA copies if the input stays live
         input_output_aliases=(
             {1: 0} if jnp.dtype(out_dtype) == flat.dtype else {}),
+        name="mt_scale",
         interpret=not on_tpu(),
     )(jnp.asarray(scale, jnp.float32).reshape(1), _view2d(flat))
     return out.reshape(-1), flag[0]
@@ -148,6 +149,7 @@ def packed_axpby(x_flat: jax.Array, y_flat: jax.Array, a: jax.Array,
         # in-place onto x when dtypes match (see packed_scale)
         input_output_aliases=(
             {1: 0} if jnp.dtype(out_dtype) == x_flat.dtype else {}),
+        name="mt_axpby",
         interpret=not on_tpu(),
     )(ab, _view2d(x_flat), _view2d(y_flat))
     return out.reshape(-1), flag[0]
@@ -192,6 +194,7 @@ def packed_sumsq_per_chunk(flat: jax.Array, chunk_size: int) -> jax.Array:
         in_specs=[pl.BlockSpec(br, lambda i: (i, 0))],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=sds((n_chunks,), jnp.float32, flat),
+        name="mt_sumsq_per_chunk",
         interpret=not on_tpu(),
     )(_view2d(flat))
 
@@ -210,6 +213,7 @@ def packed_sumsq(flat: jax.Array, chunk_size: int) -> jax.Array:
         in_specs=[pl.BlockSpec(br, lambda i: (i, 0))],
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=sds((1,), jnp.float32, flat),
+        name="mt_sumsq",
         interpret=not on_tpu(),
     )(_view2d(flat))
     return acc[0]

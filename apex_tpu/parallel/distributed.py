@@ -39,9 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from apex_tpu.utils.jax_compat import axis_size as _axis_size
-from apex_tpu.utils.jax_compat import pvary as _pvary
-
 
 class ReduceOp(enum.Enum):
     """Reference re-exports torch.distributed.ReduceOp
@@ -118,9 +115,15 @@ def pvary_params(params: Any, axis_name: str) -> Any:
     (predivide, fp32 upcast, sign compression) to.  Calling this on the
     params before ``jax.grad`` restores the reference's model: per-rank grads
     (``allreduce_hook`` inputs) that the caller then reduces explicitly with
-    :func:`reduce_gradients`.  No data movement — it only tags the values.
+    :func:`reduce_gradients`.  No data movement — it only tags the values;
+    a leaf that already varies over the axis (an expert or pipeline shard)
+    is left as it is.
     """
-    return jax.tree.map(lambda p: _pvary(p, (axis_name,)), params)
+    def tag(p):
+        if axis_name in jax.typeof(p).vma:
+            return p
+        return lax.pcast(p, (axis_name,), to="varying")
+    return jax.tree.map(tag, params)
 
 
 def reduce_gradients(grads: Any, axis_name: str,
@@ -132,7 +135,7 @@ def reduce_gradients(grads: Any, axis_name: str,
     through :func:`pvary_params`; reducing already-summed grads would
     multiply them by the world size.
     """
-    world = _axis_size(axis_name)
+    world = lax.axis_size(axis_name)
 
     @jax.named_scope("ddp_allreduce")
     def reduce_leaf(g):

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from apex_tpu.models.generate import greedy_argmax
-from apex_tpu.utils.jax_compat import axis_size as _axis_size
 
 
 def top1_routing(logits: jax.Array, capacity: int
@@ -84,7 +83,7 @@ def moe_apply(
     (psum-averaged over ranks).
     """
     import math
-    n_ranks = _axis_size(axis_name)
+    n_ranks = lax.axis_size(axis_name)
     t_local, d = x.shape
     e_local = jax.tree.leaves(expert_params)[0].shape[0]
     e_global = n_ranks * e_local

@@ -20,8 +20,8 @@ to spawn per chip on a Cloud TPU VM.  This module provides:
 
 from __future__ import annotations
 
+import glob
 import hashlib
-import inspect
 import itertools
 import json
 import os
@@ -260,10 +260,7 @@ def initialize(coordinator_address: Optional[str] = None,
     backoff_s = backoff_s if backoff_s is not None else \
         _env_float("APEX_TPU_INIT_BACKOFF_S", 5.0)
 
-    # older jax has no per-call timeout knob; feature-detect once
-    if "initialization_timeout" in inspect.signature(
-            jax.distributed.initialize).parameters:
-        kwargs["initialization_timeout"] = max(1, int(timeout_s))
+    kwargs["initialization_timeout"] = max(1, int(timeout_s))
 
     attempts = retries + 1
     last_error: Optional[BaseException] = None
@@ -323,6 +320,22 @@ def _stderr_tail(path: str, limit: int = 2000) -> str:
     return data[-limit:] if data else "<stderr empty>"
 
 
+def _local_tpu_device_nodes() -> List[str]:
+    """Device nodes of this host's TPU chips, found without touching JAX
+    (enumerating devices would claim them in the launcher)."""
+    return sorted(glob.glob("/dev/accel[0-9]*")
+                  + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _children_would_share_the_chips(world_size: int) -> bool:
+    """``world_size`` local processes on a host with TPU chips, whose
+    environment does not keep JAX off them."""
+    platforms = os.environ.get("JAX_PLATFORMS", "").lower()
+    off_chip = platforms and "tpu" not in platforms.split(",")
+    return (world_size > 1 and not off_chip
+            and bool(_local_tpu_device_nodes()))
+
+
 def spawn(argslist: Sequence[str], world_size: Optional[int] = None,
           coordinator_port: Optional[int] = None,
           log_prefix: str = "PROC_") -> List[int]:
@@ -364,6 +377,17 @@ def spawn(argslist: Sequence[str], world_size: Optional[int] = None,
     reaped and reported as wedged; such callers must raise
     ``APEX_TPU_SPAWN_GRACE_S``, or set it ``<= 0`` to disable reaping
     entirely (restoring the old wait-forever behavior).
+
+    One process per host on TPU: a TPU host's chips belong to the one
+    process that opens them, and every child started here would claim
+    all of them: the first gets them and the second fails at libtpu's
+    lockfile ("Unable to initialize backend 'tpu'", four-chip v5e
+    host, PR 21).
+    The spawner does not partition chips among children; on a host
+    with TPU chips it refuses ``world_size > 1`` at once unless
+    ``JAX_PLATFORMS`` keeps the children off the chips (the CPU/gloo
+    drills).  Run one process per host and let it drive every local
+    chip through a mesh.
     """
     argslist = list(argslist)
     if world_size is None:
@@ -374,6 +398,16 @@ def spawn(argslist: Sequence[str], world_size: Optional[int] = None,
                 "(not derived from the device count: that would "
                 "initialize the JAX runtime inside the launcher)")
         world_size = int(ws_env)
+    if _children_would_share_the_chips(world_size):
+        raise ClusterInitError(
+            f"spawn() was asked for {world_size} processes on a host "
+            f"with TPU chips ({', '.join(_local_tpu_device_nodes())}): "
+            "the TPU launch model is ONE process per host — it owns "
+            "every local chip and drives them through a mesh; a second "
+            "process fails at libtpu's lockfile.  Start one "
+            "process per host (jax.distributed.initialize via "
+            "initialize()), or set JAX_PLATFORMS=cpu for the CPU-backend "
+            "drills.")
     if coordinator_port is None:
         coordinator_port = int(os.environ.get("COORDINATOR_PORT")
                                or _free_port())

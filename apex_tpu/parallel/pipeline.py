@@ -28,9 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from apex_tpu.utils.jax_compat import axis_size as _axis_size
-from apex_tpu.utils.jax_compat import pvary as _pvary
-
 
 def stack_stage_params(params_list: Sequence[Any]) -> Any:
     """Stack per-stage param pytrees along a new leading "stage" axis, the
@@ -68,7 +65,7 @@ def pipeline_apply(
       ``(batch, ...)`` outputs of the final stage, identical on every rank
       (so an ``out_specs=P()`` works directly).
     """
-    S = _axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     s = lax.axis_index(axis_name)
     M = n_microbatches or S
     batch = x.shape[0]
@@ -95,8 +92,9 @@ def pipeline_apply(
     # the rotating buffer and the fed microbatches are device-varying over
     # the pipe axis (each rank holds different activations); type them so
     # (replicated x comes in unvarying and the scan carry stays stable)
-    micro = _pvary(micro, (axis_name,))
-    zero = _pvary(jnp.zeros((mb,) + x.shape[1:], x.dtype), (axis_name,))
+    micro = lax.pcast(micro, (axis_name,), to="varying")
+    zero = lax.pcast(jnp.zeros((mb,) + x.shape[1:], x.dtype),
+                     (axis_name,), to="varying")
     fwd_perm = [(i, (i + 1) % S) for i in range(S)]
 
     def tick(carry, t):

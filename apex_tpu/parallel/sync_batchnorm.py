@@ -47,8 +47,6 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax import lax
 
-from apex_tpu.utils.jax_compat import pvary as _pvary
-
 
 def local_mean_var(x: jax.Array, reduce_axes: Sequence[int]):
     """Local per-channel (mean, biased var, count) in fp32.
@@ -335,8 +333,8 @@ class SyncBatchNorm(nn.Module):
             # (welford.cu:557-585), but psum outputs are replication-typed,
             # which shard_map's VMA checker can verify, so running stats stay
             # provably replicated.
-            c = _pvary(jnp.asarray(float(local_count), jnp.float32),
-                       (self.axis_name,))
+            c = lax.pcast(jnp.asarray(float(local_count), jnp.float32),
+                          (self.axis_name,), to="varying")
             total_count = lax.psum(c, self.axis_name)
             mean = lax.psum(local_mean * c, self.axis_name) / total_count
             m2 = lax.psum(c * local_var + c * jnp.square(local_mean - mean),

@@ -4,8 +4,8 @@ Every failure mode the resilience layer claims to survive gets an
 injectable analog, so the claims are regression-tested instead of
 asserted: non-finite gradients (the r02-era overflow storms), checkpoint
 corruption/truncation (preemption mid-write), simulated SIGTERM
-mid-step, a hung step (the r02 chip-lease wedge,
-``INCIDENT_r02_wedge.json``), and slow/flaky checkpoint IO.
+mid-step, a hung step (the r02 chip-lease wedge), and slow/flaky
+checkpoint IO.
 
 Faults are plain frozen dataclasses; an injector composes any number of
 them and is driven by the resilience loop's hooks (or by hand in a

@@ -664,7 +664,7 @@ class _Workload:
         from apex_tpu import amp
         from apex_tpu.optimizers import FusedAdam
         from apex_tpu.parallel import DistributedDataParallel
-        from apex_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         self.cfg = cfg
         self.world = int(world)
@@ -1086,6 +1086,9 @@ def _child_env() -> dict:
 
 def _spawn_child(ledger: FleetLedger, gen: int, rank: int
                  ) -> Tuple[subprocess.Popen, list]:
+    """One generation child of this rank's supervisor.  Several ranks on
+    one machine is the CPU/gloo drill (``main`` pins the CPU platform in
+    every child); on TPU hosts the fleet is one process per host."""
     out = open(ledger.path("logs", f"child_g{gen}_r{rank}.out"), "w")
     err = open(ledger.path("logs", f"child_g{gen}_r{rank}.err"), "w")
     proc = subprocess.Popen(

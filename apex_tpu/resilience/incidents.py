@@ -1,6 +1,6 @@
 """Incident-record schema: the machine-readable artifact a failure leaves.
 
-The r02 chip-lease wedge (``INCIDENT_r02_wedge.json``) set the precedent:
+The r02 chip-lease wedge set the precedent:
 when a run dies — or survives something that should have killed it — the
 evidence goes into a JSON artifact with a fixed minimal shape, so the
 next round (and ``tools/gate_hygiene.py``) can machine-check it instead

@@ -1,6 +1,6 @@
 """Self-healing training loop: watchdog, IO retry, divergence rewind.
 
-The r02 incident (``INCIDENT_r02_wedge.json``) is the design brief: a
+The r02 incident (a chip-lease wedge) is the design brief: a
 hung device call wedged a session for 6+ hours with no watchdog, no
 incident artifact, and no resumable state.  :func:`run_resilient` wraps
 a jitted train step so that the failure modes a production run actually

@@ -34,7 +34,7 @@ from apex_tpu.models.bert import (
 from apex_tpu.optimizers import fused_lamb
 from apex_tpu.parallel import DistributedDataParallel, data_parallel_mesh
 from apex_tpu.utils import maybe_print
-from apex_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 # "large-tpu" = bert-large with the TPU-native 8x128 head geometry (same
 # parameter count, ~20% faster pretraining steps on v5e)
@@ -69,6 +69,8 @@ def synthetic_mlm_batch(key, cfg, batch, seq_len):
 
 def main():
     args = parse_args()
+    from apex_tpu.utils import compile_cache
+    compile_cache.enable()
     cfg = CONFIGS[args.size]()
     seq_len = min(args.seq_len, cfg.max_position_embeddings)
     model = BertForPreTraining(cfg)

@@ -40,6 +40,8 @@ def parse_args():
 
 def main():
     args = parse_args()
+    from apex_tpu.utils import compile_cache
+    compile_cache.enable()
     n_up = {32: 2, 64: 3}[args.image_size]
     G = Generator(feature_maps=64, n_upsample=n_up)
     D = Discriminator(feature_maps=64, n_down=n_up + 1)

@@ -63,9 +63,11 @@ def main():
         ).strip()
     import dataclasses
     import jax
-    from apex_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     if args.force_cpu:
         jax.config.update("jax_platforms", "cpu")
+    from apex_tpu.utils import compile_cache
+    compile_cache.enable()
     import jax.numpy as jnp
     import numpy as np
 
@@ -101,7 +103,12 @@ def main():
 
     if args.seq_parallel:
         from jax.sharding import Mesh, PartitionSpec as P
-        n = min(args.devices, len(jax.devices()))
+        n = args.devices
+        if len(jax.devices()) < n:
+            raise SystemExit(
+                f"--seq-parallel --devices {n} needs {n} devices in this "
+                f"process; JAX sees {len(jax.devices())} (pass "
+                f"--force-cpu for a virtual CPU mesh)")
         if l % n != 0:
             raise SystemExit(
                 f"--seq-parallel requires --seq-len divisible by the "
@@ -128,17 +135,11 @@ def main():
             # each shard holds local_sum/global_count: psum = global mean
             return new_state, jax.lax.psum(metrics["loss"], "seq")
 
-        # check_rep=False (legacy-jax only; stripped on the VMA API):
-        # the legacy checker can't see the seq-axis reductions through
-        # the ring-attention step (it infers replication from pvary
-        # annotations that are identity there) and rejects the
-        # replicated out_specs.  Safe: grad runs entirely inside the
-        # body with the loss normalizer/psum explicit (see lm_loss).
         step = jax.jit(shard_map(
             train_step, mesh=mesh,
             in_specs=(P(), P(None, "seq"), P(None, "seq"),
                       P(None, "seq"), P(None, "seq")),
-            out_specs=(P(), P()), check_rep=False))
+            out_specs=(P(), P())))
         batch = (ids, targets, positions, mask)
     else:
         model = GPTModel(cfg)

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from apex_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def parse_args():
@@ -66,6 +66,8 @@ def main():
         devices = jax.devices("cpu")
     else:
         devices = jax.devices()
+    from apex_tpu.utils import compile_cache
+    compile_cache.enable()
     world = min(len(devices), args.world_size or len(devices))
     if world < 2:
         print("NOTE: only one device visible — running a degenerate "

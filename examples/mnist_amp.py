@@ -50,6 +50,8 @@ def synthetic_mnist(key, n, batch):
 
 def main():
     args = parse_args()
+    from apex_tpu.utils import compile_cache
+    compile_cache.enable()
     model = MLP(features=(256, 256))
     key = jax.random.PRNGKey(0 if args.deterministic else int(time.time()))
     params = model.init(key, jnp.zeros((1, 784)))["params"]

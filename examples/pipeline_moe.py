@@ -55,9 +55,11 @@ def main():
             + f" --xla_force_host_platform_device_count={args.devices}"
         ).strip()
     import jax
-    from apex_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     if args.force_cpu:
         jax.config.update("jax_platforms", "cpu")
+    from apex_tpu.utils import compile_cache
+    compile_cache.enable()
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P

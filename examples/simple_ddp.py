@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import Mesh, PartitionSpec as P
-from apex_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def parse_args():
@@ -52,6 +52,8 @@ def main():
         # initializes every registered platform (incl. the TPU plugin,
         # which can block when the device is held elsewhere)
         jax.config.update("jax_platforms", "cpu")
+    from apex_tpu.utils import compile_cache
+    compile_cache.enable()
 
     from apex_tpu.parallel import DistributedDataParallel
 

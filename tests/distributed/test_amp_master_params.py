@@ -14,7 +14,7 @@ from jax.sharding import PartitionSpec as P
 from apex_tpu import amp
 from apex_tpu.models.mlp import MLP, cross_entropy_loss
 from apex_tpu.parallel import DistributedDataParallel, data_parallel_mesh
-from apex_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 WORLD = 8
 

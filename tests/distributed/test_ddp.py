@@ -25,7 +25,7 @@ from apex_tpu.parallel import (
     pvary_params,
     reduce_gradients,
 )
-from apex_tpu.utils.jax_compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 WORLD = 8
 

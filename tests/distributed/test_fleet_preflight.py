@@ -58,7 +58,7 @@ WORKER = textwrap.dedent("""
         from apex_tpu import amp
         from apex_tpu.optimizers import FusedAdam
         from apex_tpu.parallel import DistributedDataParallel
-        from apex_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.array(jax.devices()), ("data",))
         rank = jax.process_index()
@@ -145,6 +145,7 @@ def _launch(tmp_path, extra_env=None):
         cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300)
 
 
+@pytest.mark.slow
 @pytest.mark.skipif(
     os.environ.get("APEX_TPU_TEST_PLATFORM") not in (None, "cpu"),
     reason="local spawner test runs on the CPU backend")
@@ -170,6 +171,7 @@ def test_two_process_ddp_o2_trains_bit_identical_after_preflight(tmp_path):
     assert ncoll >= 2, t0
 
 
+@pytest.mark.slow
 @pytest.mark.skipif(
     os.environ.get("APEX_TPU_TEST_PLATFORM") not in (None, "cpu"),
     reason="local spawner test runs on the CPU backend")

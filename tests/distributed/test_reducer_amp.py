@@ -20,7 +20,7 @@ from jax.sharding import PartitionSpec as P
 from apex_tpu import amp
 from apex_tpu.models.mlp import MLP, cross_entropy_loss
 from apex_tpu.parallel import Reducer, data_parallel_mesh, pvary_params
-from apex_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 WORLD = 8
 N_MICRO = 2

@@ -15,7 +15,7 @@ from jax.sharding import PartitionSpec as P
 
 from apex_tpu.attention import attention, ring_attention, ulysses_attention
 from apex_tpu.parallel import data_parallel_mesh
-from apex_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 WORLD = 8
 B, L, H, D = 2, 64, 8, 16   # L/W = 8 per device
@@ -96,6 +96,7 @@ def test_fully_masked_rows_are_zero(mesh):
     assert bool(jnp.isfinite(got).all())
 
 
+@pytest.mark.slow
 def test_ring_gradients_match(mesh):
     """Differentiated OUTSIDE the shard_map (the replicated-scalar-loss
     form, like the flash-grad test below): grad-of-psum placed inside
@@ -177,6 +178,7 @@ def test_ring_flash_blocks_with_mask(mesh):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.slow
 def test_ring_flash_gradients_match_reference(mesh):
     """Gradients through the flash-block ring merge (differentiable lse).
     On CPU the jnp block engine stands in; the kernel dlse term is pinned
@@ -187,15 +189,9 @@ def test_ring_flash_gradients_match_reference(mesh):
         def inner(q, k, v):
             o = ring_attention(q, k, v, "data", causal=True, impl="flash")
             return jax.lax.psum(jnp.sum(jnp.sin(o)), "data")
-        # check_rep=False (legacy jax only; a no-op on the VMA API): the
-        # flash path's lax.switch trips "branches of cond produced
-        # mismatched replication types" in the legacy checker, which jax
-        # itself flags as a bug with this exact workaround.  Safe here:
-        # grads are wrt sharded inputs only, where the unrewritten psum
-        # transpose is correct.
         return shard_map(
             inner, mesh=mesh, in_specs=(P(None, "data"),) * 3,
-            out_specs=P(), check_rep=False)(q, k, v)
+            out_specs=P())(q, k, v)
 
     def ref_loss(q, k, v):
         return jnp.sum(jnp.sin(_reference(q, k, v, causal=True)))
@@ -244,6 +240,32 @@ def test_ring_flash_kernel_on_tpu():
                                rtol=2e-2, atol=2e-2)
     g = jax.grad(lambda q: jnp.sum(jax.jit(run)(q).astype(jnp.float32)))(q)
     assert bool(jnp.isfinite(g).all())
+
+
+def test_ring_flash_kernel_backward_types_under_shard_map(monkeypatch, mesh):
+    """The flash custom_vjp's zero cotangents (bias, rope tables) carry
+    their primals' varying-axes types: the ring's backward through the
+    KERNEL path traces and lowers for TPU under shard_map.  Trace-only
+    (the CPU tier cannot run Mosaic); the numbers are
+    test_ring_flash_kernel_on_tpu's on hardware, where this was first
+    met (PR 21)."""
+    from apex_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setenv("APEX_TPU_KERNELS", "pallas")
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    q, _, _ = _qkv(11)
+
+    def run(qq):
+        return shard_map(
+            lambda q: ring_attention(q, q, q, "data", causal=True,
+                                     impl="flash"),
+            mesh=mesh, in_specs=(P(None, "data"),),
+            out_specs=P(None, "data"))(qq)
+
+    grad = jax.jit(jax.grad(
+        lambda q: jnp.sum(run(q).astype(jnp.float32))))
+    text = grad.trace(q).lower(lowering_platforms=("tpu",)).as_text()
+    assert 'kernel_name = "flash_fwd"' in text
+    assert 'kernel_name = "flash_bwd' in text
 
 
 @pytest.mark.parametrize("impl", ["jnp", "flash", "ring", "ulysses"])

@@ -19,7 +19,7 @@ from apex_tpu.parallel import (
     data_parallel_mesh,
     welford_parallel,
 )
-from apex_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 WORLD = 8
 TOL = dict(rtol=1e-5, atol=1e-5)  # fp32 tolerance from two_gpu_unit_test.py
@@ -99,6 +99,7 @@ def test_sharded_batch_matches_whole_batch(mesh):
         np.asarray(stats_ref["batch_stats"]["var"]), **TOL)
 
 
+@pytest.mark.slow
 def test_sync_bn_gradients_match_whole_batch(mesh):
     """Backward through the synced stats == whole-batch backward
     (the reference's two-stage reduce_bn/batchnorm_backward correctness)."""
@@ -152,6 +153,7 @@ def test_process_groups(mesh):
     np.testing.assert_allclose(np.asarray(y)[8:], y_ref1, **TOL)
 
 
+@pytest.mark.slow
 def test_process_group_gradients_match_per_group_reference(mesh):
     """Backward through GROUPED stats == per-group whole-batch backward —
     pins the hand-written grouped collectives in _bn_train_bwd (group
@@ -303,7 +305,8 @@ class TestFusedBackwardFlag:
 
         return jax.grad(loss, argnums=(0, 1))(v["params"], x)
 
-    @pytest.mark.parametrize("axis_name", [None, "data"])
+    @pytest.mark.parametrize("axis_name", [
+        None, pytest.param("data", marks=pytest.mark.slow)])
     def test_autodiff_matches_fused(self, axis_name):
         g_fused = self._grads(True, axis_name)
         g_auto = self._grads(False, axis_name)

@@ -486,6 +486,7 @@ def test_joiner_takes_over_only_when_every_member_lease_is_stale(tmp_path):
     assert "takeover" in [e["kind"] for e in led.events()]
 
 
+@pytest.mark.slow
 def test_formation_death_replans_instead_of_cascading_fatal(tmp_path):
     """A peer dying during cluster FORMATION must end in a replan, not
     total fleet death.  jax's distributed client LOG(FATAL)s the child

@@ -329,6 +329,7 @@ def test_seeded_io_callback_refused_with_documented_id(tool_cache):
                for m in aot.list_entries(cache))
 
 
+@pytest.mark.slow
 def test_reload_in_fresh_process_is_bitwise_equal(tool_cache,
                                                   tmp_path):
     """The acceptance round trip: a SEPARATE python process loads only
@@ -379,6 +380,7 @@ def _tiny_serve(cache):
     return eng, eng.run()
 
 
+@pytest.mark.slow
 def test_serve_engine_probe_miss_then_hit_same_tokens(tmp_path):
     eng1, out1 = _tiny_serve(str(tmp_path))
     assert eng1.aot_info["source"] == "compile"

@@ -42,8 +42,9 @@ def test_native_lib_rebuilds_from_scratch(tmp_path):
 
 @pytest.mark.parametrize("env_overrides", [
     {"APEX_TPU_NATIVE": "0"},
-    {"APEX_TPU_NATIVE": "0", "APEX_TPU_KERNELS": "jnp"},
-    {"APEX_TPU_KERNELS": "pallas"},
+    pytest.param({"APEX_TPU_NATIVE": "0", "APEX_TPU_KERNELS": "jnp"},
+                 marks=pytest.mark.slow),
+    pytest.param({"APEX_TPU_KERNELS": "pallas"}, marks=pytest.mark.slow),
 ])
 def test_package_trains_in_every_install_mode(env_overrides, tmp_path):
     """Import + one amp train step in a fresh interpreter per mode (the

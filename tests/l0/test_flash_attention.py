@@ -341,6 +341,7 @@ class TestRopeFused:
         assert seen[-1][:2] == (1024, 1024)
         assert seen[-1][2] is None
 
+    @pytest.mark.slow
     def test_rope_under_shard_map_fallback(self):
         """Off-TPU, a varying-under-shard_map q routes to the jnp
         fallback (interpreter VMA limitation); with rope it must rotate
@@ -350,7 +351,7 @@ class TestRopeFused:
         import numpy as onp
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from apex_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         # 2-way data mesh on CPU (8 virtual devices); on the one-chip
         # TPU a 1-device mesh still compiles flash+rope under shard_map
         # (the kernel path — hardware coverage the fallback test line

@@ -48,6 +48,7 @@ def test_mh_forward_matches_reference(causal):
                                rtol=RTOL, atol=ATOL)
 
 
+@pytest.mark.slow
 @pytest.mark.experimental
 def test_mh_padded_mask_and_grads():
     q, k, v = _qkv(l=200, seed=1)          # padding active

@@ -106,3 +106,35 @@ def test_rejects_bad_trailing_shape():
     x = jnp.ones((4, 32))
     with pytest.raises(AssertionError):
         fused_layer_norm(x, (64,))
+
+
+def test_kernel_backward_types_with_replicated_weight_under_shard_map(
+        monkeypatch):
+    """Rows that vary over a mesh axis, a weight that does not (sequence
+    parallelism): the kernel path's custom_vjp must hand back a weight
+    cotangent summed over that axis.  Trace-only — the CPU tier cannot
+    run Mosaic; the numbers are tests/distributed/
+    test_onchip_pallas_shardmap.py's on hardware."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from apex_tpu.ops.pallas import layer_norm_kernels as lnk
+    monkeypatch.setenv("APEX_TPU_KERNELS", "pallas")
+    monkeypatch.setattr(lnk, "on_tpu", lambda: True)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("seq",))
+    x = jnp.ones((2, 512, 256), jnp.bfloat16)
+    w = jnp.ones((256,), jnp.bfloat16)
+    b = jnp.zeros((256,), jnp.bfloat16)
+
+    def loss(w, b, x):
+        def inner(w, b, x):
+            y = fused_layer_norm_affine(x, w, b, 256)
+            return jax.lax.psum(jnp.sum(y.astype(jnp.float32)), "seq")
+        return shard_map(inner, mesh=mesh,
+                         in_specs=(P(), P(), P(None, "seq")),
+                         out_specs=P())(w, b, x)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+        w, b, x).lower(lowering_platforms=("tpu",)).as_text()
+    assert 'kernel_name = "layer_norm_bwd"' in text
+    assert "all_reduce" in text

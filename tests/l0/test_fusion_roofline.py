@@ -1,7 +1,7 @@
 """Parser units for tools/fusion_roofline.py (the RN50 roofline audit).
 
-The tool's conclusions (ROOFLINE_RN50_r04.json: the b256 step is
-HBM-bound, MFU ceiling ~0.35) hang on its HLO accounting, so the shape/
+The tool's conclusions (round 4: the b256 step is HBM-bound, MFU
+ceiling ~0.35) hang on its HLO accounting, so the shape/
 byte/FLOP extraction is pinned here against a hand-written HLO snippet
 with the wrinkles that broke earlier drafts: tuple-valued fusion outputs
 whose type strings contain spaces and layout parens (``T(8,128)``),

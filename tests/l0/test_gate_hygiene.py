@@ -20,13 +20,6 @@ sys.path.insert(0, str(REPO / "tools"))
 import gate_hygiene  # noqa: E402
 
 
-def test_repo_gate_artifacts_committed():
-    """Tier-1 wiring: THIS checkout's gate baselines are tracked and
-    clean (skip-records pass — e.g. a tarball export without git)."""
-    verdict = gate_hygiene.check(str(REPO))
-    assert verdict["ok"], verdict
-
-
 def _git(repo, *args):
     subprocess.run(["git", "-C", str(repo), "-c", "user.email=t@t",
                     "-c", "user.name=t", *args], check=True,
@@ -586,15 +579,15 @@ def test_repo_serve_disagg_validates():
 
 
 def test_real_committed_convergence_artifacts_validate():
-    """Every CONVERGENCE_r*.json in the real repo — the legacy r02
-    shape through the r06 quant lanes — validates."""
+    """Every CONVERGENCE_r*.json in the real repo, through the r06
+    quant lanes, validates."""
     import importlib.util
     spec = importlib.util.spec_from_file_location(
         "_conv_schema", REPO / "apex_tpu" / "analysis" / "convergence.py")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     arts = sorted(REPO.glob("CONVERGENCE_r*.json"))
-    assert len(arts) >= 5
+    assert len(arts) >= 4
     for p in arts:
         assert mod.validate_convergence_file(str(p)) == [], p.name
 

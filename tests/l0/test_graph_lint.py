@@ -28,7 +28,7 @@ sys.path.insert(0, str(REPO / "tools"))
 from apex_tpu import analysis  # noqa: E402
 from apex_tpu.analysis import Finding, Report  # noqa: E402
 
-from apex_tpu.utils.jax_compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 
 def mesh8():
@@ -401,6 +401,7 @@ def test_cli_main_runs_selected_family(capsys):
 # ISSUE 4: strict mode + every in-tree entry point lints clean
 # ---------------------------------------------------------------------------
 
+@pytest.mark.slow
 def test_cli_strict_mode_memory_budget_enforced(capsys):
     """Tier-1 strict-mode run over the smallest family: the memlint
     passes execute with the v5e 16 GiB device budget ARMED (bare

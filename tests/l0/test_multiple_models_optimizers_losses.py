@@ -219,7 +219,8 @@ def _run_one_optimizer_case(n_models, opt_level, use_multiple_loss_scalers,
 # scaler-sharing x inject-inf grid through the same helper the larger
 # topologies drive — and the other three are slow-marked.
 
-@pytest.mark.parametrize("use_multiple_loss_scalers", (True, False))
+@pytest.mark.parametrize("use_multiple_loss_scalers", (
+    True, pytest.param(False, marks=pytest.mark.slow)))
 @pytest.mark.parametrize("opt_level", OPT_LEVELS)
 def test_2models2losses1optimizer(opt_level, use_multiple_loss_scalers):
     for case in case_grid(opt_level):

@@ -491,6 +491,7 @@ def test_committed_obs_and_profile_artifacts_validate():
 # overhead smoke + the profile_decode CPU-xplane smoke
 # ---------------------------------------------------------------------------
 
+@pytest.mark.slow
 def test_instrumentation_overhead_smoke():
     """The chaos_run-style measurement at (reduced) bench-smoke scale:
     the deterministic per-step instrument cost must sit far under the
@@ -503,6 +504,7 @@ def test_instrumentation_overhead_smoke():
     assert out["overhead_pct"] < 5.0, out
 
 
+@pytest.mark.slow
 def test_profile_decode_cpu_xplane_smoke(tmp_path):
     """Acceptance: tools/profile_decode.py captures the decode program
     on this backend, buckets device time via obs.xplane into the

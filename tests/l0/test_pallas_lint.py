@@ -52,7 +52,7 @@ def _call(out_shape, in_map, out_map, grid, sem, kern=_copy_k,
         out_specs=pl.BlockSpec((8, 128), out_map),
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         scratch_shapes=list(scratch),
-        compiler_params=dict(mosaic=dict(dimension_semantics=sem)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
         interpret=True, **kw)(_X)
 
 

@@ -134,7 +134,9 @@ def test_amp_ops_softmax_is_clean():
 # (c) the four in-tree families' O1 forwards audit clean
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family", ["mlp", "resnet", "gpt", "bert"])
+@pytest.mark.parametrize("family", [
+    "mlp", pytest.param("resnet", marks=pytest.mark.slow), "gpt",
+    pytest.param("bert", marks=pytest.mark.slow)])
 def test_model_family_o1_forward_is_policy_clean(family):
     sys.path.insert(0, str(REPO / "tools"))
     import policy_audit

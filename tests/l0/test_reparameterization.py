@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from apex_tpu.models.mlp import MLP, cross_entropy_loss
 from apex_tpu.reparameterization import (
@@ -65,6 +66,7 @@ def test_effective_weight_norm_equals_g():
         np.asarray(aux["kernel_g"][0]), rtol=1e-5)
 
 
+@pytest.mark.slow
 def test_gradients_flow_and_training_improves():
     model, p = _params()
     pw = apply_weight_norm(p)

@@ -29,7 +29,9 @@ def data(seed=0):
                        .astype(np.float32))
 
 
-@pytest.mark.parametrize("mode", ["relu", "tanh", "gru", "lstm", "mlstm"])
+@pytest.mark.parametrize("mode", [
+    pytest.param("relu", marks=pytest.mark.slow), "tanh",
+    pytest.param("gru", marks=pytest.mark.slow), "lstm", "mlstm"])
 def test_forward_backward(mode):
     model = apex_rnn.RNN(mode=mode, hidden_size=H)
     x = data()

@@ -182,6 +182,7 @@ def _solo(params, cfg, prompt, n, **kw):
     return np.asarray(out)[0, len(prompt):]
 
 
+@pytest.mark.slow
 def test_shared_system_prompt_stream_bitwise_and_saves_chunks(setup):
     """The tentpole gate: 4 requests sharing one 8-token system prompt
     through 2 slots — every output bitwise-equal to solo generate(),
@@ -268,6 +269,7 @@ def test_full_prompt_match_forks_copy_on_write(setup):
     assert eng.sched.allocator.live_count == 0
 
 
+@pytest.mark.slow
 def test_sampled_and_multi_turn_reuse_bitwise(setup):
     """Sampling under sharing stays on the exact per-request PRNG
     chain (pinned against the sharing-off engine, the arm existing
@@ -335,6 +337,7 @@ def test_preemption_under_sharing_stays_bitwise(setup):
     assert eng.sched.allocator.live_count == 0
 
 
+@pytest.mark.slow
 def test_int8_kv_scale_pools_share_bitwise(setup):
     """int8 KV under sharing: the scale pools ride the same refcounts
     (a shared block's scales are the registered content too), the CoW
@@ -368,6 +371,7 @@ def test_int8_kv_scale_pools_share_bitwise(setup):
 # disaggregated fleet: straight-to-decode + chaos drill under sharing
 # ---------------------------------------------------------------------------
 
+@pytest.mark.slow
 def test_fleet_straight_to_decode_and_kill_busiest_drill(setup):
     """Fleet sharing end-to-end: a warm replica's index admits a
     same-prefix request STRAIGHT to decode (no prefill slice, no

@@ -41,6 +41,7 @@ def tiny():
     return cfg, params, ids
 
 
+@pytest.mark.slow
 def test_run_cell_records_are_schema_shaped(tiny):
     """One spec-off/spec-on cell pair at a tiny shape: both records
     carry the schema's numbers and a gate DERIVED from them, and a
@@ -96,6 +97,7 @@ def test_cell_matrix_covers_contexts_and_axes():
     assert any(k["context"] == 32768 for _, k, _ in full)
 
 
+@pytest.mark.slow
 def test_chat_cell_reuses_history_and_churn_pins_sharing_off(tiny):
     """The multi-turn chat cell's second turn resubmits each request's
     own prompt + streamed reply, so the content index must HIT (prompt

@@ -143,6 +143,7 @@ def test_spec_through_preemption_matches_solo(setup):
     assert eng.sched.allocator.live_count == 0
 
 
+@pytest.mark.slow
 def test_spec_kv8_matches_solo_and_baseline(setup):
     """Speculation under the int8 KV cache: the verify step's
     quantize-on-write/fused-dequant path produces greedy streams
@@ -171,6 +172,7 @@ def test_spec_kv8_matches_solo_and_baseline(setup):
     assert eng.metrics.counter("serve_spec_accepted_total").value > 0
 
 
+@pytest.mark.slow
 def test_spec_sampled_streams_match_baseline_engine(setup):
     """Sampled slots: the verifier draws with the slot's key ladder
     through the same fused epilogue, so a spec-enabled sampled stream
@@ -191,6 +193,7 @@ def test_spec_sampled_streams_match_baseline_engine(setup):
     np.testing.assert_array_equal(out["g"], outb["g"])
 
 
+@pytest.mark.slow
 def test_advance_key_chain_identity_under_partial_accepts(setup):
     """Satellite: the draw-count chain under speculative drafts.  A
     spec round emits 1..k+1 tokens, but the slot's PRNG chain must
@@ -236,6 +239,7 @@ def test_advance_key_chain_identity_under_partial_accepts(setup):
         f"identity was only checked at the trivial j=0 point")
 
 
+@pytest.mark.slow
 def test_full_reach_requests_do_not_wrap_writes(setup):
     """Review-found corruption class: a request whose footprint fills
     the ENTIRE slot reach (prompt + budget == max_blocks_per_slot x
@@ -285,6 +289,7 @@ def test_spec_config_and_draft_validation(setup):
                    registry=Registry())
 
 
+@pytest.mark.slow
 def test_spec_profiler_partitions_latency_histograms(setup, engine):
     """The continuous-profiler contract holds on the SPECULATIVE
     engine too: attaching a profiler drives real capture windows, a

@@ -240,6 +240,7 @@ SCFG = ServeConfig(num_slots=2, block_size=4, num_blocks=17,
                    max_blocks_per_slot=8, prefill_chunk=4)
 
 
+@pytest.mark.slow
 def test_router_routes_around_slo_violating_replica(tiny_model):
     """Scripted fleet: one replica forced over its p99 objective
     loses admission eligibility — every new request lands on the

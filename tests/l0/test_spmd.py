@@ -39,7 +39,7 @@ from apex_tpu.analysis.fleetlint import (validate_fleetlint,  # noqa: E402
 from apex_tpu.parallel import multiproc  # noqa: E402
 from apex_tpu.parallel.distributed import (ReduceConfig,  # noqa: E402
                                            reduce_gradients)
-from apex_tpu.utils.jax_compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 
 def mesh(n=8):
@@ -192,7 +192,9 @@ def test_seeded_conditional_collective_fires():
     derived from the rank index."""
     def f(x):
         return jax.lax.cond(jax.lax.axis_index("data") < 4,
-                            lambda v: jax.lax.psum(v, "data"),
+                            lambda v: jax.lax.pcast(
+                                jax.lax.psum(v, "data"), "data",
+                                to="varying"),
                             lambda v: v, x)
 
     sm = shard_map(f, mesh=mesh(), in_specs=P("data"),

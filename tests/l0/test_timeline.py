@@ -192,11 +192,18 @@ def test_unreadable_artifact_excluded_from_coverage(tmp_path):
         perf_timeline.build_timeline(str(tmp_path), gated=[])
 
 
-def test_bench_adapter_reconstructs_truncated_round():
-    """BENCH_r05's tail is truncated past its configs map; the adapter
-    reconstructs each rate as prev x (1 + recorded delta) — the
-    artifact's own regression deltas are the recoverable witness."""
-    rows = timeline.ingest_repo(str(REPO))["rows"]
+def test_bench_adapter_reconstructs_truncated_round(tmp_path):
+    """A round whose tail is truncated past its configs map (the
+    driver keeps ~2000 chars): the adapter reconstructs each rate as
+    prev x (1 + recorded delta) — the artifact's own regression deltas
+    are the recoverable witness."""
+    (tmp_path / "BENCH_r04.json").write_text(_bench_artifact(
+        {"gpt_small_tpu_heads_o2": {"tok_s": 139660.56, "mfu": 0.55}}))
+    (tmp_path / "BENCH_r05.json").write_text(json.dumps({
+        "parsed": None,
+        "tail": 'eam": {"img_s": 354.08}}, "regression_check": {"deltas": '
+                '{"gpt_small_tpu_heads_o2": -0.0323}, "ok": true}}'}))
+    rows = timeline.ingest_repo(str(tmp_path))["rows"]
     by = {(r["family"], r["round"], r["config"], r["metric"]):
           r["value"] for r in rows}
     r4 = by[("BENCH", 4, "gpt_small_tpu_heads_o2", "tok_s")]

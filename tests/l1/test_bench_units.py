@@ -1,8 +1,7 @@
 """Units for bench.py's harness pieces (the benchmark itself runs on the
-driver's chip): the PJRT-init watchdog and the FLOP-count fallback."""
+driver's chip): the no-chip error, the FLOP-count fallback and the gates."""
 
 import sys
-import time
 from pathlib import Path
 
 import pytest
@@ -13,25 +12,13 @@ sys.path.insert(0, str(REPO))
 import bench  # noqa: E402
 
 
-def test_probe_devices_returns_devices():
-    devices = bench.probe_devices(60)
-    assert devices, "CPU backend must enumerate"
-
-
-def test_probe_devices_times_out_on_hang(monkeypatch):
-    monkeypatch.setattr(bench.jax, "devices",
-                        lambda *a: time.sleep(30))
-    t0 = time.time()
-    assert bench.probe_devices(1.0) is None
-    assert time.time() - t0 < 5
-
-
-def test_probe_devices_reraises_init_errors(monkeypatch):
-    def boom():
-        raise RuntimeError("plugin exploded")
-    monkeypatch.setattr(bench.jax, "devices", boom)
-    with pytest.raises(RuntimeError, match="plugin exploded"):
-        bench.probe_devices(30)
+def test_main_without_a_chip_is_an_error(capsys):
+    """bench.py measures the chip and nothing else: on the CPU platform
+    the tests run on it exits non-zero, says why, and prints no result."""
+    with pytest.raises(SystemExit) as exc:
+        bench.main([])
+    assert "no TPU" in str(exc.value)
+    assert capsys.readouterr().out == ""
 
 
 def test_step_flops_fallback():
@@ -40,10 +27,10 @@ def test_step_flops_fallback():
             raise NotImplementedError
     assert bench.step_flops(NoCost(), fallback=123.0) == 123.0
 
-    class ListCost:
+    class DictCost:
         def cost_analysis(self):
-            return [{"flops": 7.0}]
-    assert bench.step_flops(ListCost(), fallback=0.0) == 7.0
+            return {"flops": 7.0}
+    assert bench.step_flops(DictCost(), fallback=0.0) == 7.0
 
     class ZeroCost:  # some backends report 0 — fall back
         def cost_analysis(self):
@@ -116,15 +103,6 @@ def test_compare_configs_unwraps_driver_artifact(tmp_path):
     assert verdict["regressions"] == ["resnet50_o2"]
 
 
-def test_compare_against_real_r03_artifact():
-    # the shipped round-3 artifact must be readable by the gate
-    verdict = bench.compare_configs(
-        str(REPO / "BENCH_r03.json"),
-        {"resnet50_o2": {"img_s": 2461.55}}, threshold=0.10)
-    assert verdict["deltas"]["resnet50_o2"] == 0.0
-    assert verdict["ok"]
-
-
 def test_compare_configs_unreadable_baseline_never_fails(tmp_path):
     bad = tmp_path / "BENCH_r99.json"
     bad.write_text("{not json")
@@ -163,15 +141,17 @@ def test_pallas_attn_compiled_detection():
     # layer-norm custom call in the step must NOT vouch for the
     # attention kernel path (it would re-introduce the double count)
     assert bench._pallas_attn_compiled(Hlo(
-        '%jvp_jit__flash_fwd__.1 = custom-call(...), '
+        '%c.1 = custom-call(...), '
         'custom_call_target="tpu_custom_call", metadata={op_name='
-        '"jit(f)/jvp(jit(_flash_fwd))/pallas_call"}'))
+        '"jit(f)/jvp(jit(_flash_fwd))/flash_fwd/pallas_call"}'))
     assert bench._pallas_attn_compiled(Hlo(
-        'op_name="jit(f)/transpose(jvp(jit(_flash_bwd_fused)))/'
-        'pallas_call"'))
+        'custom_call_target="tpu_custom_call", metadata={op_name='
+        '"jit(f)/transpose(jvp(jit(_flash_bwd_fused)))/flash_bwd_fused/'
+        'pallas_call"}'))
     assert not bench._pallas_attn_compiled(Hlo(
-        '%_lamb_stage1.3 = custom-call(...), '
-        'custom_call_target="tpu_custom_call"'))
+        '%c.3 = custom-call(...), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="jit(f)/jit(packed_lamb_stage1)/lamb_stage1/'
+        'pallas_call"}'))
     assert not bench._pallas_attn_compiled(Hlo("fusion(...) dot(...)"))
 
     class NoText:
@@ -180,19 +160,15 @@ def test_pallas_attn_compiled_detection():
     assert bench._pallas_attn_compiled(NoText()) is None
 
 
-def test_compare_configs_lists_prior_only_and_ungated(tmp_path):
+def test_compare_configs_lists_prior_only(tmp_path):
     prior = _write_bench(tmp_path, "BENCH_r03.json", {
         "gpt_small_o2": {"tok_s": 50000.0},
-        "resnet50_o2_hoststream": {"img_s": 400.0},
         "deleted_config": {"img_s": 9.0},
     })
     verdict = bench.compare_configs(prior, {
         "gpt_small_o2": {"tok_s": 49000.0},
-        # wire-speed config: a 50% swing must NOT fail the gate
-        "resnet50_o2_hoststream": {"img_s": 200.0},
     }, threshold=0.10)
     assert verdict["ok"]
-    assert "resnet50_o2_hoststream" in verdict["uncompared"]
     assert "deleted_config" in verdict["uncompared"]  # baseline-only
 
 
@@ -300,22 +276,7 @@ def test_mfu_floor_gate():
     assert check["ok"] and not check["checked"]
 
 
-def test_mfu_floors_cover_all_gated_tpu_configs():
-    """Every non-wire-coupled TPU config with an MFU number must carry
-    a published floor — a floor-less config is ungated efficiency."""
-    import json
-    doc = json.load(open(REPO / "BENCH_r04.json"))
-    cfgs = doc.get("parsed", doc)["configs"]
-    for name, rec in cfgs.items():
-        if name in bench.UNGATED_CONFIGS or "mfu" not in rec:
-            continue
-        assert name in bench.MFU_FLOORS, name
-        # floors sit at-or-below the r4 measured value: the gate fires
-        # on future regressions, not retroactively
-        assert bench.MFU_FLOORS[name] * (1 - bench.MFU_VARIANCE_BAND) \
-            <= rec["mfu"], name
-
-
+@pytest.mark.slow
 def test_bench_generate_tiny_cpu():
     """The decode bench path runs end-to-end on CPU with the tiny
     config (the real config runs on the driver's chip)."""
@@ -348,7 +309,7 @@ def test_gate_exit_code_absolute_gates_fire_without_compare():
                                           "violations": ["resnet50_o2"]},
                "ab_failures": []}
     bad_ab = {"ok": True, "mfu_floors": {"ok": True},
-              "ab_failures": ["resnet50_pipeline_ab_64px"]}
+              "ab_failures": ["gpt_small_tpu_serve_c8"]}
     clean = {"ok": True, "mfu_floors": {"ok": True}, "ab_failures": []}
     assert bench.gate_exit_code(bad_mfu, compare_given=False) == 2
     assert bench.gate_exit_code(bad_ab, compare_given=False) == 2
@@ -623,6 +584,7 @@ def test_bench_serve_tiny_cpu():
     assert all(v["retraces"] == 1 for v in levels.values())
 
 
+@pytest.mark.slow
 def test_bench_serve_spec_tiny_cpu():
     """The speculative serve A/B end-to-end on CPU: the briefly
     trained model gives the layer-skip draft real margins, the same

@@ -40,6 +40,7 @@ def _naive_generate(model, params, prompt, steps):
     return ids
 
 
+@pytest.mark.slow
 def test_greedy_matches_naive_full_forward(setup):
     cfg, model, params, prompt = setup
     want = _naive_generate(model, params, prompt, NEW)
@@ -84,6 +85,7 @@ def test_single_token_decode(setup):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.slow
 def test_tpu_head_geometry_config():
     """Wide heads (d=128 class for the tiny scale) decode exactly too —
     the geometry the TPU configs use."""

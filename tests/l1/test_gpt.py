@@ -17,7 +17,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from apex_tpu import amp
 from apex_tpu.models import GPTModel, gpt_tiny, lm_loss
 from apex_tpu.optimizers import FusedAdam
-from apex_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 B, L = 2, 32
 
@@ -64,6 +64,7 @@ class TestGPT:
             np.testing.assert_allclose(np.asarray(got), np.asarray(logits),
                                        rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.slow
     def test_sequence_parallel_matches_local(self):
         """Ring attention over a ("seq",) mesh with global rope positions
         reproduces the single-device logits."""
@@ -120,8 +121,9 @@ class TestGPTKernelPathParity:
     This pins the fused-rope wiring: q/k reach the kernel UNROTATED and
     the rotation happens on VMEM blocks (round-4 fast path)."""
 
-    @pytest.mark.parametrize("num_heads,label", [(4, "narrow-16"),
-                                                 (1, "wide-64")])
+    @pytest.mark.parametrize("num_heads,label", [
+        pytest.param(4, "narrow-16", marks=pytest.mark.slow),
+        pytest.param(1, "wide-64", marks=pytest.mark.slow)])
     def test_pallas_matches_jnp(self, monkeypatch, num_heads, label):
         cfg = dc.replace(gpt_tiny(), num_heads=num_heads)
         model = GPTModel(cfg)

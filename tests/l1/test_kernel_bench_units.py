@@ -6,6 +6,8 @@ import json
 import sys
 from pathlib import Path
 
+import pytest
+
 REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO / "tools"))
 
@@ -89,6 +91,7 @@ def test_tiny_suite_runs_everywhere():
     assert all(v["ms_per_step"] >= 0 for v in result["kernels"].values())
 
 
+@pytest.mark.slow
 def test_geometry_recorded_per_kernel():
     """Every timed record names the geometry it measured (device kind +
     n_elements are top-level; block shape per kernel — the ISSUE-2

@@ -25,7 +25,7 @@ from apex_tpu.models import (
 )
 from apex_tpu.optimizers import FusedAdam, fused_lamb
 from apex_tpu.parallel import DistributedDataParallel, data_parallel_mesh
-from apex_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 class TestResNet:
@@ -56,6 +56,7 @@ class TestResNet:
             train=True, mutable=["batch_stats"])[0]))(variables["params"])
         assert all(bool(jnp.isfinite(l).all()) for l in jax.tree.leaves(g))
 
+    @pytest.mark.slow
     def test_s2d_stem_variant(self):
         """The TPU-native space-to-depth stem keeps the stage geometry
         (same output head, spatial/4 stem output) and trains; non-
@@ -122,6 +123,7 @@ class TestResNet:
         assert stem_bn_scale.dtype == jnp.float32   # keep_batchnorm_fp32
         assert conv_kernel.dtype == jnp.bfloat16
 
+    @pytest.mark.slow
     def test_sync_bn_conversion_and_ddp_step(self):
         from apex_tpu.parallel import convert_syncbn_model
         # first 8 devices: the x8 batch shards over an 8-wide mesh
@@ -190,6 +192,7 @@ class TestBert:
 
 
 class TestDCGAN:
+    @pytest.mark.slow
     def test_two_loss_scaler_training(self):
         """The num_losses=2 machinery: independent scalers for G and D."""
         G, D = Generator(feature_maps=8, n_upsample=1), \
@@ -281,6 +284,7 @@ class TestBertScanRemat:
             lambda *xs: jnp.stack(xs), *layers)}
         return {"params": p}
 
+    @pytest.mark.slow
     def test_scan_and_remat_match_loop(self):
         import dataclasses as dc
         cfg_loop = dc.replace(bert_tiny(), scan_layers=False)

@@ -106,7 +106,7 @@ def measure_kernels(names, n: int, tiny: bool) -> dict:
             # noise) has no meaningful fraction — skip the block
             # rather than divide by it
             if all(ms > 0 for ms in vals):
-                bw = kb._hbm_peak()
+                bw = kb._hbm_peak(tiny)
                 entry["roofline_frac"] = _stats(
                     [nbytes / (ms * 1e-3) / bw for ms in vals])
             entries[f"kernel:{name}"] = entry
@@ -123,8 +123,12 @@ def measure_configs(names, n: int, tiny: bool) -> dict:
     config."""
     import bench
 
-    on_tpu = not tiny and jax.devices()[0].platform == "tpu"
-    peak = bench.chip_peak_flops() if on_tpu else None
+    from apex_tpu.utils.chip_peaks import chip_peak
+
+    # full size measures the chip (an unknown chip is an error); --tiny
+    # is the explicit CPU smoke, marked as such in the artifact
+    on_tpu = not tiny
+    peak = chip_peak().bf16_flops_per_s if on_tpu else None
     if on_tpu:
         rn = dict(batch=256, size=224, warmup=4, iters=20)
         gpt = dict(batch=8, seq=2048, warmup=3, iters=12, tiny=False)

@@ -120,13 +120,13 @@ def main():
     model = sys.argv[1] if len(sys.argv) > 1 else "resnet50"
     opt_level = sys.argv[2] if len(sys.argv) > 2 else "O2"
     batch = int(sys.argv[3]) if len(sys.argv) > 3 else 256
-    import bench
     from apex_tpu import amp
     from apex_tpu.models.resnet import ARCHS
     from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.utils.chip_peaks import chip_peak
     import jax.numpy as jnp
 
-    peak = bench.chip_peak_flops()
+    peak = chip_peak().bf16_flops_per_s
     m = ARCHS[model]()
     x = jax.random.normal(jax.random.PRNGKey(0), (batch, 224, 224, 3),
                           jnp.float32)

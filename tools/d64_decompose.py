@@ -70,8 +70,9 @@ def main():
 
     import bench
     from apex_tpu.obs.xplane import parse_xplane
+    from apex_tpu.utils.chip_peaks import chip_peak
 
-    peak = bench.chip_peak_flops()
+    peak = chip_peak().bf16_flops_per_s
     iters = 8
 
     # measured numbers come from an UNTRACED run (profiling costs ~7%

@@ -272,8 +272,8 @@ def measured_reconciliation(batch: int):
         entry = doc[f"gpt_small_tpu_decode_b{batch}"][str(batch)]
     except (OSError, ValueError, KeyError):
         return None
-    import bench
-    bw = bench.HBM_BYTES_PER_S["v5e"]     # the r05 rig
+    from apex_tpu.utils.chip_peaks import CHIP_PEAKS
+    bw = CHIP_PEAKS["TPU v5 lite"].hbm_bytes_per_s     # the r05 rig
     step_s = batch / entry["tok_s"]
     return {
         "source": "BENCH_LADDER_BASELINES.json",

@@ -38,25 +38,12 @@ REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
 sys.path.insert(0, str(REPO / "tools"))
 
-#: v5e HBM peak (bytes/s); the roofline denominator.  Other chips can be
-#: added by device-kind match like bench.chip_peak_flops does for FLOPs.
-HBM_BYTES_PER_S = {"v5 lite": 819e9, "v5e": 819e9, "v4": 1228e9,
-                   "v6": 1640e9}
-
 _DTYPE_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "f64": 8, "s32": 4,
                 "u32": 4, "s8": 1, "u8": 1, "pred": 1, "s16": 2,
                 "u16": 2, "s64": 8, "u64": 8, "u2": 1}
 
 _SHAPE_RE = re.compile(r"(\w+)\[([0-9,]*)\]")
 _DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
-
-
-def hbm_peak() -> float:
-    kind = jax.devices()[0].device_kind.lower()
-    for key, bw in HBM_BYTES_PER_S.items():
-        if key in kind:
-            return bw
-    return 819e9
 
 
 def _shape_bytes(type_str: str) -> int:
@@ -223,9 +210,9 @@ def main():
     from apex_tpu import amp
     from apex_tpu.models.resnet import ARCHS
     from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.utils.chip_peaks import chip_peak
 
-    peak = bench.chip_peak_flops()
-    bw = hbm_peak()
+    peak, bw = chip_peak()
     m = ARCHS[model_name]()
     x = jax.random.normal(jax.random.PRNGKey(0), (batch, 224, 224, 3),
                           jnp.float32)

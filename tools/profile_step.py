@@ -49,7 +49,9 @@ from apex_tpu.obs.xplane import (  # noqa: E402
 
 def build(model_name: str, opt_level: str):
     import bench
-    peak = bench.chip_peak_flops()
+    from apex_tpu.utils.chip_peaks import chip_peak
+
+    peak = chip_peak().bf16_flops_per_s
     if model_name == "gpt":
         # same config as bench.py's headline GPT entry (keep in sync)
         fn = lambda: bench.bench_gpt(batch=8, seq=2048, warmup=2, iters=8,
