@@ -28,9 +28,11 @@ size on the CPU (Pallas in interpret mode); it reports ``"platform":
 "cpu"``, no timings, and is never taken by default or from the
 environment.
 
-The last line of standard output is one JSON object:
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...},
-...}``.
+The last two lines of standard output are one JSON object each: the
+report (versions, compile-cache directory, native extension, and per
+phase the compile and run seconds, losses, kernels found, peak bytes),
+then the verdict, with exactly these keys:
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
 """
 
 import argparse
@@ -544,10 +546,12 @@ def main(argv=None) -> int:
     cfg, shape = (gpt_tiny(), DRY) if dry else (gpt_small_tpu(), FULL)
 
     device = jax.devices()[0]
-    report = {
+    verdict = {
         "ok": True,
         "device": {"platform": device.platform, "kind": device.device_kind,
                    "count": len(jax.devices())},
+    }
+    report = {
         "dry_run": dry,
         "model": "gpt_tiny" if dry else "gpt_small_tpu",
         "devices_used": args.devices,
@@ -560,7 +564,7 @@ def main(argv=None) -> int:
                    else repr(native.import_err)},
         "phases": {},
     }
-    print(f"chip_smoke: {report['device']} {report['versions']} "
+    print(f"chip_smoke: {verdict['device']} {report['versions']} "
           f"native={report['native']} cache={cache_dir}", flush=True)
 
     train, params, cycle = phase_train(cfg, shape, args.devices, dry)
@@ -569,7 +573,9 @@ def main(argv=None) -> int:
         report["phases"]["serve"] = phase_serve(cfg, params, cycle, shape,
                                                 dry)
         report["phases"]["kernels"] = phase_kernels(shape, dry)
-    print(json.dumps(report))
+    print(json.dumps({"report": report}))
+    # the last line: the verdict and nothing else
+    print(json.dumps(verdict), flush=True)
     return 0
 
 

@@ -2,6 +2,7 @@
 compile-cache placement, the peak table, the smoke's and the spawner's
 refusals, and the reader of Mosaic kernel names."""
 
+import json
 import os
 import subprocess
 import sys
@@ -86,6 +87,25 @@ def test_chip_smoke_without_a_tpu_fails_and_prints_no_result():
     assert out.returncode != 0
     assert "no TPU" in out.stderr
     assert out.stdout == ""
+
+
+def test_chip_smoke_last_line_is_the_verdict_and_nothing_else():
+    """The chip check reads the last line of standard output and wants
+    exactly ``ok`` and ``device`` {platform, kind, count} there (the
+    first submission of PR 21 was refused for carrying the whole report
+    on that line).  The explicit dry run takes the same exit path."""
+    out = subprocess.run(
+        [sys.executable, str(REPO / "chip_smoke.py"), "--cpu-dry-run"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=str(REPO),
+        capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    *_, report, verdict = out.stdout.splitlines()
+    assert json.loads(verdict) == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    phases = json.loads(report)["report"]["phases"]
+    assert sorted(phases) == ["kernels", "serve", "train"]
+    assert phases["train"]["compile_s"] is None      # no CPU time reported
 
 
 def test_spawn_refuses_several_processes_on_a_tpu_host(monkeypatch):
