@@ -35,6 +35,8 @@ from apex_tpu.amp import policy as policy_lib
 from apex_tpu.amp import scaler as scaler_lib
 from apex_tpu.amp.policy import Properties
 from apex_tpu.amp.scaler import LossScaler, LossScaleState
+from apex_tpu.utils.profiling import (
+    AMP_CAST, AMP_OPTIMIZER_STEP, AMP_REDUCE, AMP_SCALER_UPDATE, AMP_UNSCALE)
 
 # Default name fragments identifying normalization params kept in fp32 under
 # keep_batchnorm_fp32 (reference skips _BatchNorm modules during the O2 cast,
@@ -291,12 +293,14 @@ class Amp:
         — both device arrays; nothing here syncs to the host.
         """
         if reduce_fn is not None:
-            grads = reduce_fn(grads)
+            with jax.named_scope(AMP_REDUCE):
+                grads = reduce_fn(grads)
 
         if not self.properties.enabled:
-            updates, opt_state = self.tx.update(grads, state.opt_state,
-                                                state.master_params)
-            master = optax.apply_updates(state.master_params, updates)
+            with jax.named_scope(AMP_OPTIMIZER_STEP):
+                updates, opt_state = self.tx.update(grads, state.opt_state,
+                                                    state.master_params)
+                master = optax.apply_updates(state.master_params, updates)
             return (AmpState(master, opt_state, state.scaler_states,
                              state.step + 1, state.fp8_state),
                     {"overflow": jnp.asarray(False),
@@ -312,12 +316,11 @@ class Amp:
             # subsumes the reference's arg-0 check with no caller
             # cooperation (see unscale_gradients for the strict arg-0
             # per-loss policy).
-            finite = scaler_lib.all_finite(grads_unscaled)
+            with jax.named_scope(AMP_UNSCALE):
+                finite = scaler_lib.all_finite(grads_unscaled)
         else:
             grads_unscaled, finite = self.scaler.unscale(grads, sstate)
-        for ax in (finite_axes or ()):
-            # AND across ranks sharing the step decision (min of {0,1})
-            finite = jax.lax.pmin(finite.astype(jnp.int32), ax).astype(bool)
+        finite = self._all_ranks_finite(finite, finite_axes)
         state, overflow = self.update_scaler(state, loss_id, finite)
         new_state = self.step_if(state, grads_unscaled, overflow)
         new_sstate = new_state.scaler_states[loss_id]
@@ -328,6 +331,16 @@ class Amp:
             # overflow found the scale already at (or shrank it to) the
             # min_loss_scale floor (scaler.pinned_at_floor)
             "pinned_at_floor": self.scaler.pinned_at_floor(new_sstate)}
+
+    @staticmethod
+    @jax.named_scope(AMP_UNSCALE)
+    def _all_ranks_finite(finite: jax.Array,
+                          finite_axes: Optional[Sequence[str]]) -> jax.Array:
+        """AND of the finite flag across the ranks that share the step
+        decision (min of {0,1}); part of the finite check's scope."""
+        for ax in (finite_axes or ()):
+            finite = jax.lax.pmin(finite.astype(jnp.int32), ax).astype(bool)
+        return finite
 
     # ------------------------------------------------------------------
     # composable pieces for multi-loss / multi-optimizer topologies
@@ -356,8 +369,9 @@ class Amp:
         """Run scaler ``loss_id``'s post-backward transition
         (``update_scale``, ``scaler.py:190-210``) without stepping.
         Returns ``(state_with_new_scaler, overflow)``."""
-        new_sstate, overflow = self.scaler.update(
-            state.scaler_states[loss_id], grads_finite)
+        with jax.named_scope(AMP_SCALER_UPDATE):
+            new_sstate, overflow = self.scaler.update(
+                state.scaler_states[loss_id], grads_finite)
         scaler_states = tuple(
             new_sstate if i == loss_id else s
             for i, s in enumerate(state.scaler_states))
@@ -370,19 +384,19 @@ class Amp:
         multi-loss/multi-optimizer drivers can route overflow flags across
         optimizers (the reference arms ``skip_step`` on every optimizer a
         ``scale_loss`` context was passed, ``handle.py:131-150``)."""
-        grads_unscaled = jax.tree.map(
-            lambda g, p: g.astype(p.dtype) if hasattr(p, "dtype") else g,
-            grads_unscaled, state.master_params)
-
         def do_step(operand):
             master, opt_state = operand
             updates, new_opt_state = self.tx.update(grads_unscaled, opt_state,
                                                     master)
             return optax.apply_updates(master, updates), new_opt_state
 
-        master, opt_state = jax.lax.cond(
-            skip, lambda op: op, do_step,
-            (state.master_params, state.opt_state))
+        with jax.named_scope(AMP_OPTIMIZER_STEP):
+            grads_unscaled = jax.tree.map(
+                lambda g, p: g.astype(p.dtype) if hasattr(p, "dtype") else g,
+                grads_unscaled, state.master_params)
+            master, opt_state = jax.lax.cond(
+                skip, lambda op: op, do_step,
+                (state.master_params, state.opt_state))
         return AmpState(master, opt_state, state.scaler_states,
                         state.step + 1, state.fp8_state)
 
@@ -443,12 +457,11 @@ class Amp:
         any_overflow = None
         for grads, lid in zip(grads_list, loss_ids):
             if reduce_fn is not None:
-                grads = reduce_fn(grads)
+                with jax.named_scope(AMP_REDUCE):
+                    grads = reduce_fn(grads)
             unscaled, finite = self.unscale_gradients(entry_state, grads,
                                                       loss_id=lid)
-            for ax in (finite_axes or ()):
-                finite = jax.lax.pmin(finite.astype(jnp.int32),
-                                      ax).astype(bool)
+            finite = self._all_ranks_finite(finite, finite_axes)
             state, overflow = self.update_scaler(state, lid, finite)
             total = unscaled if total is None else jax.tree.map(
                 jnp.add, total, unscaled)
@@ -582,7 +595,8 @@ def make_train_step(
 
     def step(state: AmpState, *batch):
         from apex_tpu.parallel.distributed import pvary_params
-        params_c = amp.model_params(state)
+        with jax.named_scope(AMP_CAST):
+            params_c = amp.model_params(state)
         if axis_name is not None:
             params_c = pvary_params(params_c, axis_name)
         fp8_on = amp.properties.enabled and amp.properties.fp8 \

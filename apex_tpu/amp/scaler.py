@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.amp.policy import DYNAMIC
+from apex_tpu.utils.profiling import AMP_SCALER_UPDATE, AMP_UNSCALE
 
 
 class LossScaleState(NamedTuple):
@@ -90,6 +91,7 @@ class LossScaler:
         ``min_loss_scale`` or the 1.0 default :meth:`update` clamps to."""
         return self.min_loss_scale if self.min_loss_scale is not None else 1.0
 
+    @jax.named_scope(AMP_SCALER_UPDATE)
     def pinned_at_floor(self, state: LossScaleState) -> jax.Array:
         """Device-side flag: the dynamic scale sits at its floor, i.e. the
         next overflow CANNOT shrink it further.  ``overflow AND pinned``
@@ -117,7 +119,7 @@ class LossScaler:
         to inf is always seen, matching the fused kernel which checks the
         input values it reads (``multi_tensor_scale_kernel.cu:57-71``).
         """
-        with jax.named_scope("amp_unscale"):
+        with jax.named_scope(AMP_UNSCALE):
             inv = (1.0 / state.loss_scale).astype(jnp.float32)
             finite = all_finite(grads)
             unscaled = jax.tree.map(
@@ -132,13 +134,14 @@ class LossScaler:
         with the inf-check restricted to the *new* grads
         (``scaler.py:149-182``, ``multi_tensor_axpby`` with arg_to_check=0).
         """
-        inv = (1.0 / state.loss_scale).astype(jnp.float32)
-        finite = all_finite(new_grads)
-        out = jax.tree.map(
-            lambda n, s: (n.astype(jnp.float32) * inv
-                          + s.astype(jnp.float32)).astype(out_dtype),
-            new_grads, stashed)
-        return out, finite
+        with jax.named_scope(AMP_UNSCALE):
+            inv = (1.0 / state.loss_scale).astype(jnp.float32)
+            finite = all_finite(new_grads)
+            out = jax.tree.map(
+                lambda n, s: (n.astype(jnp.float32) * inv
+                              + s.astype(jnp.float32)).astype(out_dtype),
+                new_grads, stashed)
+            return out, finite
 
     def update(self, state: LossScaleState,
                grads_finite: jax.Array) -> Tuple[LossScaleState, jax.Array]:

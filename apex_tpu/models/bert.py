@@ -25,6 +25,7 @@ import flax.linen as nn
 from apex_tpu.amp import ops as amp_ops
 from apex_tpu.layers import Dense
 from apex_tpu.normalization import FusedLayerNorm
+from apex_tpu.utils.profiling import MLP, PRETRAINING_LOSS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,9 +149,10 @@ class TransformerLayer(nn.Module):
         a = SelfAttention(c, name="attention")(x, mask)
         x = FusedLayerNorm(c.hidden_size, eps=c.layer_norm_eps,
                            name="attention_ln")(x + a)
-        h = Dense(c.intermediate_size, name="ffn_in")(x)
-        h = nn.gelu(h)
-        h = Dense(c.hidden_size, name="ffn_out")(h)
+        with jax.named_scope(MLP):
+            h = Dense(c.intermediate_size, name="ffn_in")(x)
+            h = nn.gelu(h)
+            h = Dense(c.hidden_size, name="ffn_out")(h)
         return FusedLayerNorm(c.hidden_size, eps=c.layer_norm_eps,
                               name="ffn_ln")(x + h)
 
@@ -228,6 +230,7 @@ class BertForPreTraining(nn.Module):
         return mlm_logits, nsp_logits
 
 
+@jax.named_scope(PRETRAINING_LOSS)
 def pretraining_loss(mlm_logits, nsp_logits, mlm_labels, nsp_labels,
                      mlm_mask):
     """Masked-LM + NSP cross entropy in fp32; ``mlm_mask`` selects the

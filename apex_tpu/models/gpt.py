@@ -27,6 +27,7 @@ import flax.linen as nn
 
 from apex_tpu.layers import Dense
 from apex_tpu.normalization import FusedLayerNorm
+from apex_tpu.utils.profiling import LM_LOSS, MLP
 # Rope math lives in ops (the flash kernel applies it in-kernel); the
 # historical spellings stay importable from here.
 from apex_tpu.ops.rope import (  # noqa: F401  (re-exports)
@@ -136,9 +137,11 @@ class GPTBlock(nn.Module):
         x = x + CausalSelfAttention(c, name="attention")(h, rope_cs)
         h = FusedLayerNorm(c.hidden_size, eps=c.layer_norm_eps,
                            name="ln2")(x)
-        h = Dense(c.intermediate_size, name="ffn_in")(h)
-        h = nn.gelu(h)
-        return x + Dense(c.hidden_size, name="ffn_out")(h)
+        with jax.named_scope(MLP):
+            h = Dense(c.intermediate_size, name="ffn_in")(h)
+            h = nn.gelu(h)
+            h = Dense(c.hidden_size, name="ffn_out")(h)
+        return x + h
 
 
 class _ScanBody(nn.Module):
@@ -205,6 +208,7 @@ class GPTModel(nn.Module):
         return Dense(c.vocab_size, use_bias=False, name="lm_head")(x)
 
 
+@jax.named_scope(LM_LOSS)
 def lm_loss(logits: jax.Array, targets: jax.Array,
             mask: Optional[jax.Array] = None,
             seq_axis_name: Optional[str] = None) -> jax.Array:

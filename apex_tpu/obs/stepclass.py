@@ -28,8 +28,8 @@ Three classifiers, two vocabularies:
   host_gap / other``) from the instructions' ``op_name`` metadata
   scopes: jax AD stamps forward ops ``jvp(...)`` and backward ops
   ``transpose(jvp(...))``; the optimizer/scaler update runs under the
-  overflow-skip ``cond`` and the ``amp_unscale`` scope; collectives
-  classify by opcode.  ``host_gap`` is never returned by the
+  train step's own ``amp_optimizer_step``, ``amp_unscale`` and
+  ``amp_scaler_update`` scopes; collectives classify by opcode.  ``host_gap`` is never returned by the
   classifier — it is the derived residual (measured step wall minus
   attributed op time) the profiler fills in.
 
@@ -42,6 +42,9 @@ from __future__ import annotations
 
 import re
 from typing import Dict, List, Optional, Set
+
+from apex_tpu.utils.profiling import (
+    AMP_OPTIMIZER_STEP, AMP_SCALER_UPDATE, AMP_UNSCALE)
 
 __all__ = [
     "TRAIN_BUCKETS", "DECODE_BUCKETS",
@@ -76,11 +79,14 @@ _COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
                    "collective-broadcast")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 
-#: ``op_name`` metadata scopes that mark the optimizer/scaler update
-#: (the overflow-skip ``cond`` wrapping ``apply_gradients``, the amp
-#: unscale, and the named optimizer kernels).
-OPTIMIZER_SCOPES = ("cond", "amp_unscale", "adam", "lamb", "sgd",
-                    "apply_grad", "optimizer", "larc", "novograd")
+#: ``op_name`` metadata scopes that mark the optimizer/scaler update:
+#: the scopes ``amp.make_train_step`` opens around the update with its
+#: overflow skip, the unscale and the loss-scale transition, and the
+#: named optimizer kernels.  A bare ``cond`` is no marker: any other
+#: ``lax.cond`` in a step would count as the optimizer.
+OPTIMIZER_SCOPES = (AMP_OPTIMIZER_STEP, AMP_UNSCALE, AMP_SCALER_UPDATE,
+                    "adam", "lamb", "sgd", "apply_grad", "optimizer",
+                    "larc", "novograd")
 
 
 def computations(hlo: str) -> dict:
@@ -292,8 +298,9 @@ class TrainStepClassifier:
     - scope contains ``transpose(jvp(`` or ``vjp(`` → ``bwd`` (the AD
       transpose pass);
     - scope hits an optimizer marker (:data:`OPTIMIZER_SCOPES`: the
-      overflow-skip ``cond`` wrapping ``apply_gradients``, the
-      ``amp_unscale`` pass, named optimizer kernels) → ``optimizer``;
+      step's ``amp_optimizer_step``, ``amp_unscale`` and
+      ``amp_scaler_update`` scopes, named optimizer kernels) →
+      ``optimizer``;
     - scope contains ``jvp(`` → ``fwd``;
     - anything else → ``None`` (→ ``other``).
 

@@ -27,6 +27,27 @@ from typing import Callable, List, Optional
 
 import jax
 
+#: The ``jax.named_scope``s a compiled train step carries, in step order:
+#: the O2 cast of the master weights to the compute copy, the gradient
+#: exchange (``ddp_allreduce`` sits inside it), the unscale and finite
+#: check, the loss-scale transition, and the optimizer update with its
+#: overflow skip.  Forward and backward are autodiff's own ``jvp(...)``
+#: and ``transpose(jvp(...))``.  Defined here and nowhere else in the
+#: program; whatever reads a compiled step's ``op_name``s
+#: (:class:`apex_tpu.obs.stepclass.TrainStepClassifier`, the benchmark's
+#: ``scopes.py``) matches these strings.  A scope is metadata on the
+#: instructions it covers and adds no operation.
+(AMP_CAST, AMP_REDUCE, AMP_UNSCALE, AMP_SCALER_UPDATE,
+ AMP_OPTIMIZER_STEP) = TRAIN_STEP_SCOPES = (
+    "amp_cast", "amp_reduce", "amp_unscale", "amp_scaler_update",
+    "amp_optimizer_step")
+
+#: The scopes the models open besides their flax module names: the
+#: feed-forward block (``ffn_in`` -> activation -> ``ffn_out``) and the
+#: two loss functions, each under its own name.
+MLP, LM_LOSS, PRETRAINING_LOSS = MODEL_SCOPES = (
+    "mlp", "lm_loss", "pretraining_loss")
+
 
 @contextlib.contextmanager
 def nvtx_range(name: str):

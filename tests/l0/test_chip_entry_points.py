@@ -19,6 +19,8 @@ from apex_tpu.utils import chip_peaks, compile_cache
 REPO = Path(__file__).resolve().parents[2]
 #: spelled in two halves so that a grep for the option finds its one setter
 CACHE_OPTION = "jax_compilation_" + "cache_dir"
+METADATA_OPTION = "jax_compilation_cache_include_metadata_in_key"
+TRACEBACK_OPTION = "jax_traceback_in_locations_limit"
 
 
 @pytest.fixture
@@ -36,7 +38,7 @@ def test_compile_cache_env_variable_wins_and_code_sets_nothing(
         monkeypatch, cache_updates):
     monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
     assert compile_cache.enable() == "/somewhere/else"
-    assert cache_updates == []
+    assert [u for u in cache_updates if u[0] == CACHE_OPTION] == []
 
 
 def test_compile_cache_default_is_a_fixed_path_in_the_checkout(
@@ -44,16 +46,34 @@ def test_compile_cache_default_is_a_fixed_path_in_the_checkout(
     monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
     first = compile_cache.enable()
     assert first == str(REPO / ".jax_cache") == compile_cache.enable()
-    assert cache_updates == [(CACHE_OPTION, first)] * 2
+    assert [u for u in cache_updates if u[0] == CACHE_OPTION] == \
+        [(CACHE_OPTION, first)] * 2
     assert not first.startswith(tempfile.gettempdir())
     assert str(os.getpid()) not in first
 
 
+@pytest.mark.parametrize("from_env", [None, "/somewhere/else"])
+def test_compile_cache_keeps_metadata_in_its_key(monkeypatch, cache_updates,
+                                                 from_env):
+    """Wherever the cache lives: per-layer metrics read ``op_name`` from
+    the compiled program, and JAX's key strips it unless told not to."""
+    if from_env:
+        monkeypatch.setenv(compile_cache.ENV_VAR, from_env)
+    else:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    compile_cache.enable()
+    assert (METADATA_OPTION, True) in cache_updates
+    assert (TRACEBACK_OPTION, 1) in cache_updates
+    for option in (METADATA_OPTION, TRACEBACK_OPTION):   # they exist
+        assert hasattr(jax.config, option)
+
+
 def test_compile_cache_sets_nothing_on_the_cpu_platform(monkeypatch):
     monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
-    before = getattr(jax.config, CACHE_OPTION)
+    options = (CACHE_OPTION, METADATA_OPTION, TRACEBACK_OPTION)
+    before = [getattr(jax.config, o) for o in options]
     assert compile_cache.enable() is None
-    assert getattr(jax.config, CACHE_OPTION) == before
+    assert [getattr(jax.config, o) for o in options] == before
 
 
 def test_no_other_code_sets_a_compile_cache_directory():

@@ -207,8 +207,11 @@ ENTRY %main (a: f32[8,8]) -> f32[8,8] {
   %fwd.1 = f32[8,8] dot(f32[8,8] %a, f32[8,8] %a), metadata={op_name="jit(step)/jit(main)/jvp(MLP)/dot_general"}
   %bwd.1 = f32[8,8] dot(f32[8,8] %a, f32[8,8] %a), metadata={op_name="jit(step)/jit(main)/transpose(jvp(MLP))/dot_general"}
   %mixed.1 = f32[8,8] fusion(f32[8,8] %a), kind=kLoop, calls=%fused_bwd
-  %opt.1 = f32[8,8] add(f32[8,8] %a, f32[8,8] %a), metadata={op_name="jit(step)/jit(main)/cond/branch_1_fun/add"}
+  %opt.1 = f32[8,8] add(f32[8,8] %a, f32[8,8] %a), metadata={op_name="jit(step)/jit(main)/amp_optimizer_step/cond/branch_1_fun/add"}
   %unscale.1 = f32[8,8] multiply(f32[8,8] %a, f32[8,8] %a), metadata={op_name="jit(step)/jit(main)/amp_unscale/mul"}
+  %scaler.1 = f32[] select(pred[] %p, f32[] %s, f32[] %s), metadata={op_name="jit(step)/jit(main)/amp_scaler_update/select_n"}
+  %cast.1 = bf16[8,8] convert(f32[8,8] %a), metadata={op_name="jit(step)/jit(main)/amp_cast/convert_element_type"}
+  %other-cond.1 = f32[8,8] add(f32[8,8] %a, f32[8,8] %a), metadata={op_name="jit(step)/jit(main)/cond/branch_1_fun/add"}
   %grad-ar = f32[8,8] all-reduce(f32[8,8] %bwd.1), to_apply=%fused_bwd, metadata={op_name="jit(step)/jit(main)/transpose(jvp(MLP))/psum"}
   %plain.1 = f32[8,8] add(f32[8,8] %a, f32[8,8] %a), metadata={op_name="jit(step)/jit(main)/convert_element_type"}
   ROOT %out = f32[8,8] add(f32[8,8] %opt.1, f32[8,8] %plain.1)
@@ -218,19 +221,31 @@ ENTRY %main (a: f32[8,8]) -> f32[8,8] {
 
 def test_train_classifier_fixture():
     """The pinned vocabulary contract: jvp -> fwd, transpose(jvp ->
-    bwd (winning over fwd inside a mixed fusion), cond/amp_unscale ->
+    bwd (winning over fwd inside a mixed fusion), the step's
+    amp_optimizer_step / amp_unscale / amp_scaler_update scopes ->
     optimizer, collective opcode -> collectives (winning over its bwd
-    scope), unscoped -> other, host_gap never classified."""
+    scope), amp_cast and unscoped -> other, host_gap never classified."""
     clf = stepclass.TrainStepClassifier(_TRAIN_HLO)
     assert clf("fwd.1") == "fwd"
     assert clf("bwd.1") == "bwd"
     assert clf("mixed.1") == "bwd"          # precedence is the pin
     assert clf("opt.1") == "optimizer"
     assert clf("unscale.1") == "optimizer"
+    assert clf("scaler.1") == "optimizer"
+    assert clf("cast.1") is None            # the O2 cast is no update
     assert clf("grad-ar") == "collectives"
     assert clf("plain.1") is None           # -> other
     assert "host_gap" not in set(clf.buckets.values())
     assert {"fwd.1", "bwd.1", "mixed.1", "opt.1"} <= clf.step_ops()
+
+
+def test_a_cond_outside_the_optimizer_step_is_not_the_optimizer():
+    """The old rule took any ``cond`` scope segment for the overflow skip;
+    only the one under ``amp_optimizer_step`` is."""
+    assert "cond" not in stepclass.OPTIMIZER_SCOPES
+    clf = stepclass.TrainStepClassifier(_TRAIN_HLO)
+    assert clf("opt.1") == "optimizer"
+    assert clf("other-cond.1") is None
 
 
 def test_train_classifier_on_real_compiled_step():
