@@ -11,11 +11,22 @@ FlashAttention — pattern, not code) mapped onto the TPU:
   innermost; Mosaic's sequential grid makes the k-walk a legal accumulation
   over VMEM scratch (running max ``m``, normalizer ``l``, fp32 ``acc``) —
   the role CUDA shared-memory tiling plays for the GPU kernels;
+- one algorithm at two geometries, chosen from the call's shape by
+  :func:`_geometry` (no knob): a causal call whose head fits VMEM keeps
+  the head's rows *resident* — K and V are one block a head, fetched and
+  rotated once, and each block of q rows meets all its visible keys,
+  none above the diagonal, in one step (one row max and one row sum a
+  row instead of one a block pair); the backward is then one grid step
+  a head that forms its own row sums ``delta`` and writes dq, dk and dv
+  once, in the storage dtype.  Everything else (non-causal, key masks,
+  long contexts, explicit blocks) keeps the grid walk.  A
+  ``jax.named_scope`` (``flash_resident`` / ``flash_grid``) around the
+  kernel calls says which was chosen;
 - score/softmax arithmetic is fp32 regardless of storage dtype (the amp
   blacklist rule for softmax), matmuls ride the MXU with
   ``preferred_element_type=float32``;
 - the backward recomputes probability blocks from the saved logsumexp;
-  by default one fused pass produces dq, dk and dv together (dk/dv
+  on the grid walk one fused pass produces dq, dk and dv together (dk/dv
   accumulate in VMEM scratch, dq lands in per-k-block fp32 partial
   planes summed outside — see ``_fused_bwd_max_bytes``), falling back
   to the classic two-pass scheme (a ``dq`` pass with k innermost, a
@@ -35,8 +46,7 @@ probability recompute and inverse-rotate the dq/dk accumulators at emit
 (the rotation is orthogonal, so ``d(unrotated) = R^T · d(rotated)`` is
 the same lane-rotation with the sine negated).  The rotated q/k never
 exist in HBM — this is what lets the head-major GPT path stay a pure
-reshape end to end (round 3 measured the out-of-kernel rotation
-re-materializing the layout, net -3%).  Tables arrive as full-width
+reshape end to end.  Tables arrive as full-width
 ``(B, L, D)`` pairs (see :func:`apex_tpu.ops.rope.rope_kernel_tables`)
 and are held VMEM-resident per batch when they fit
 (``_ROPE_RESIDENT_MAX_BYTES`` per side) or streamed per block above that.
@@ -46,6 +56,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -58,9 +69,10 @@ _LANES = 128
 #: Minor-dim width for the per-row stats tensors (lse, delta) in HBM.
 #: Full lane width (128) is what jax's TPU flash kernel uses too: narrower
 #: widths save HBM (the stats are per-row scalars) but force Mosaic
-#: relayouts in the backward inner loop — measured on BERT-large L=512:
-#: width 1 → 10.6 seq/s, width 8 → 16.7, width 128 → 24.9.  The footprint
-#: only matters at extreme sequence lengths (2·BH·L·512 bytes).
+#: relayouts in the backward inner loop (widths 1 and 8 were both slower
+#: on the round-3 chip, BERT-large L=512).  The footprint is BH·L·512
+#: bytes a tensor: 1.6 GB of residuals a step in the benchmark's GPT
+#: cell (PERF.md section 7), the next thing to narrow.
 _STATS_W = _LANES
 NEG_INF = -1e30
 
@@ -139,10 +151,72 @@ def _causal_dispatch(causal, live, straddle, update, dead=None):
         update(False)
 
 
+def _fwd_update(q, k, v, bias_row, mask, m_scr, l_scr, acc_scr, *,
+                has_bias):
+    """One online-softmax step: fold the ``(bq, bk)`` scores of ``q``
+    against ``k`` into the running max / normalizer / accumulator
+    scratch.  ``q`` and ``k`` arrive rotated and ``q`` pre-scaled;
+    ``mask`` is the causal mask of a diagonal-straddling pair or
+    ``None``; ``bias_row`` is ``(1, bk)``, read only with ``has_bias``."""
+    # Matmul operands keep their storage dtype: bf16 inputs ride the
+    # MXU at full rate, fp32 inputs keep exact fp32 semantics.
+    # Accumulation is always fp32 (preferred_element_type), and every
+    # softmax/statistics op stays fp32 — the amp fp32-softmax policy
+    # is about the *reduction* precision, not MXU operand storage.
+    # The softmax scale is folded into q by the caller (one (L, d)
+    # pass instead of an (L, L) one here).
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)             # (bq, bk)
+    if has_bias:
+        s = s + bias_row                      # (1, bk) broadcast
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+
+    m_prev = m_scr[...]                        # (bq, LANES) replicated
+    m_cur = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
+    m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
+    corr = jnp.exp(m_prev - m_new)             # (bq, LANES)
+    p = jnp.exp(s - m_new[:, :1])              # (bq, bk)
+    # Masked entries need no explicit zeroing here: s == NEG_INF and
+    # a finite m_new make exp underflow to exactly 0 (causal rows
+    # always see the diagonal, so m_new is finite in every live
+    # block).  Only the bias path can produce fully-masked rows
+    # (m_new == NEG_INF -> exp(0) == 1), so only it re-zeroes.
+    if has_bias:
+        p = jnp.where(bias_row > NEG_INF / 2, p, 0.0)
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
+    l_new = l_scr[...] * corr + jnp.broadcast_to(
+        p.sum(axis=1, keepdims=True), m_prev.shape)
+    # p rides the MXU in the storage dtype (the flash convention: the
+    # probabilities are cast to the value dtype for the PV matmul;
+    # the fp32 accumulator keeps the reduction exact).
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)    # (bq, d)
+    acc_scr[...] = acc_scr[...] * corr[:, :1] + pv
+    m_scr[...] = m_new
+    l_scr[...] = l_new
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, causal, has_bias,
-                rope_mode, block_q, block_k, nk):
-    rope_refs = rest[:_rope_nrefs(rope_mode)]
-    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[_rope_nrefs(rope_mode):]
+                rope_mode, block_q, block_k, nk, resident):
+    """One q block against one K/V block.  Grid walk: the grid's
+    innermost axis walks the k dimension a block at a time, dead and
+    straddling blocks told apart by ``pl.when``.  Resident (causal, no
+    bias, ``block_k`` the whole padded head, one grid step on that
+    axis): the block holds a head's K and V, fetched once a head, K
+    rotated once a head into scratch, and the q block meets all its
+    visible keys — rows ``[0, q_start + block_q)``, nothing above the
+    diagonal — in one update: one row max and one row sum a row where
+    the walk pays them once a block pair (PERF.md, PR 26: on the v5e
+    those cross-lane reductions, not the score tile, are what a pair
+    costs).  The width is static per q block, so the q blocks of a head
+    are branches of the body."""
+    nrope = _rope_nrefs(rope_mode)
+    rope_refs = rest[:nrope]
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[nrope:nrope + 5]
     ik = pl.program_id(2)
     iq = pl.program_id(1)
 
@@ -154,21 +228,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, causal, has_bias,
 
     q_start = iq * block_q
     k_start = ik * block_k
-    # Whole block strictly above the diagonal contributes nothing.
-    live = (not causal) or (k_start <= q_start + block_q - 1)
-    # Only diagonal-straddling blocks need the iota/compare/where mask
-    # work; fully-below-diagonal blocks are entirely visible.  (~60% of
-    # live blocks skip the mask at L=2048 with 512-blocks.)
-    straddle = k_start + block_k - 1 > q_start
 
     def _update(masked):
-        # Matmul operands keep their storage dtype: bf16 inputs ride the
-        # MXU at full rate, fp32 inputs keep exact fp32 semantics.
-        # Accumulation is always fp32 (preferred_element_type), and every
-        # softmax/statistics op stays fp32 — the amp fp32-softmax policy
-        # is about the *reduction* precision, not MXU operand storage.
-        # The softmax scale is folded into q by the caller (one (L, d)
-        # pass instead of an (L, L) one here).
         q = q_ref[0]                              # (bq, d)
         k = k_ref[0]                              # (bk, d)
         if rope_mode:
@@ -176,42 +237,47 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, causal, has_bias,
             ck, sk = _rope_k(rope_refs, rope_mode, k_start, block_k)
             q = _rot(q, cq, sq).astype(q_ref.dtype)
             k = _rot(k, ck, sk).astype(k_ref.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        if has_bias:
-            s = s + bias_ref[0]                   # (1, bk) broadcast
-        if masked:
-            mask = _causal_mask(block_q, block_k, q_start, k_start)
-            s = jnp.where(mask, s, NEG_INF)
+        mask = (_causal_mask(block_q, block_k, q_start, k_start)
+                if masked else None)
+        _fwd_update(q, k, v_ref[0], bias_ref[0], mask, m_scr, l_scr,
+                    acc_scr, has_bias=has_bias)
 
-        m_prev = m_scr[...]                        # (bq, LANES) replicated
-        m_cur = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-        corr = jnp.exp(m_prev - m_new)             # (bq, LANES)
-        p = jnp.exp(s - m_new[:, :1])              # (bq, bk)
-        # Masked entries need no explicit zeroing here: s == NEG_INF and
-        # a finite m_new make exp underflow to exactly 0 (causal rows
-        # always see the diagonal, so m_new is finite in every live
-        # block).  Only the bias path can produce fully-masked rows
-        # (m_new == NEG_INF -> exp(0) == 1), so only it re-zeroes.
-        if has_bias:
-            p = jnp.where(bias_ref[0] > NEG_INF / 2, p, 0.0)
-            if masked:
-                p = jnp.where(mask, p, 0.0)
-        l_new = l_scr[...] * corr + jnp.broadcast_to(
-            p.sum(axis=1, keepdims=True), m_prev.shape)
-        # p rides the MXU in the storage dtype (the flash convention: the
-        # probabilities are cast to the value dtype for the PV matmul;
-        # the fp32 accumulator keeps the reduction exact).
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)    # (bq, d)
-        acc_scr[...] = acc_scr[...] * corr[:, :1] + pv
-        m_scr[...] = m_new
-        l_scr[...] = l_new
+    def _visible_keys_at_once():
+        q = q_ref[0]
+        load_k = lambda rows: k_ref[0, rows, :]
+        if rope_mode:
+            krot_scr, = rest[nrope + 5:]          # (Lp, d) rotated K
+            load_k = lambda rows: krot_scr[rows, :]
 
-    _causal_dispatch(causal, live, straddle, _update)
+            @pl.when(iq == 0)
+            def _rotate_k():
+                for j in range(block_k // block_q):
+                    rows = pl.ds(j * block_q, block_q)
+                    ck, sk = _rope_k(rope_refs, rope_mode, j * block_q,
+                                     block_q)
+                    krot_scr[rows, :] = _rot(k_ref[0, rows, :], ck,
+                                             sk).astype(krot_scr.dtype)
+
+            cq, sq = _rope_q(rope_refs, rope_mode, q_start, block_q)
+            q = _rot(q, cq, sq).astype(q_ref.dtype)
+
+        for i in range(block_k // block_q):
+            @pl.when(iq == i)
+            def _(i=i):
+                w = (i + 1) * block_q
+                _fwd_update(q, load_k(pl.ds(0, w)), v_ref[0, :w, :], None,
+                            _causal_mask(block_q, w, i * block_q, 0),
+                            m_scr, l_scr, acc_scr, has_bias=False)
+
+    if resident:
+        _visible_keys_at_once()
+    else:
+        # Whole block strictly above the diagonal contributes nothing.
+        live = (not causal) or (k_start <= q_start + block_q - 1)
+        # Only diagonal-straddling blocks need the iota/compare/where
+        # mask work; fully-below-diagonal blocks are entirely visible.
+        straddle = k_start + block_k - 1 > q_start
+        _causal_dispatch(causal, live, straddle, _update)
 
     @pl.when(ik == nk - 1)
     def _emit():
@@ -359,6 +425,32 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _bwd_pair(q, k, v, do, lse_col, delta_col, bias_row, *, masked,
+              has_bias, q_start, k_start, block_q, block_k):
+    """One (q rows, k rows) pair of the one-pass backward: p and dp are
+    computed once and feed all three gradients.  Returns the pair's
+    fp32 contributions ``(dq, dk, dv)``, dq and dk with respect to the
+    rotated (and, for q, pre-scaled) operands."""
+    p = _bwd_p(q, k, bias_row, lse_col, masked=masked, has_bias=has_bias,
+               q_start=q_start, k_start=k_start, block_q=block_q,
+               block_k=block_k)
+    dv = jax.lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)          # (bk, d)
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    ds = p * (dp - delta_col)                        # (bq, bk)
+    ds_c = ds.astype(q.dtype)
+    dk = jax.lax.dot_general(
+        ds_c, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dq = jax.lax.dot_general(
+        ds_c, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)          # (bq, d) fp32
+    return dq, dk, dv
+
+
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       bias_ref, *rest, causal, has_bias, rope_mode,
                       block_q, block_k, nq):
@@ -392,24 +484,13 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ck, sk = _rope_k(rope_refs, rope_mode, k_start, block_k)
             q = _rot(q, cq, sq).astype(q_ref.dtype)
             k = _rot(k, ck, sk).astype(k_ref.dtype)
-        p = _bwd_p(q, k, bias_ref[0], lse_ref[0][:, :1], masked=masked,
-                   has_bias=has_bias, q_start=q_start, k_start=k_start,
-                   block_q=block_q, block_k=block_k)
-        do = do_ref[0]
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (bk, d)
-        dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])              # (bq, bk)
-        ds_c = ds.astype(q.dtype)
-        dk_scr[...] += jax.lax.dot_general(
-            ds_c, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dqp = jax.lax.dot_general(
-            ds_c, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (bq, d) fp32
+        dqp, dk, dv = _bwd_pair(
+            q, k, v_ref[0], do_ref[0], lse_ref[0][:, :1],
+            delta_ref[0][:, :1], bias_ref[0], masked=masked,
+            has_bias=has_bias, q_start=q_start, k_start=k_start,
+            block_q=block_q, block_k=block_k)
+        dk_scr[...] += dk
+        dv_scr[...] += dv
         if rope_mode:
             # Rotation is linear, so inverse-rotating each partial plane
             # equals inverse-rotating their sum (done outside otherwise).
@@ -431,6 +512,73 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dk = _rot(dk, ck, -sk)
         dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _bwd_resident_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                         *rest, has_dlse, rope_mode, chunk, n):
+    """The one-pass backward of a causal head whose rows are all in
+    VMEM: one grid step a head.  Why a body of its own beside
+    ``_bwd_fused_kernel``: there the pair walk is the grid and only dk
+    and dv outlive a step, so dq leaves as one fp32 plane per k block
+    and the row sums arrive from outside.  Here each chunk of q rows
+    meets all its visible keys — rows ``[0, (i + 1) * chunk)``, a width
+    static per chunk, so the chunks are unrolled — in one pair, which is
+    ``_bwd_pair`` as there: its dq is whole, inverse-rotated and written
+    once in the storage dtype; dk and dv add up in fp32 scratch over the
+    head and are written once; the row sums ``delta`` come from the
+    resident ``o`` and ``do``; K is rotated once a head."""
+    ndl, nrope = int(has_dlse), _rope_nrefs(rope_mode)
+    dlse_ref = rest[0] if has_dlse else None
+    rope_refs = rest[ndl:ndl + nrope]
+    dq_ref, dk_ref, dv_ref, dk_scr, dv_scr = \
+        rest[ndl + nrope:ndl + nrope + 5]
+    load_k = lambda rows: k_ref[0, rows, :]
+    if rope_mode:
+        krot_scr, = rest[ndl + nrope + 5:]        # (Lp, d) rotated K
+        load_k = lambda rows: krot_scr[rows, :]
+    spans = [pl.ds(i * chunk, chunk) for i in range(n)]
+
+    for j, rows in enumerate(spans):
+        if rope_mode:
+            ck, sk = _rope_k(rope_refs, rope_mode, j * chunk, chunk)
+            krot_scr[rows, :] = _rot(k_ref[0, rows, :], ck,
+                                     sk).astype(krot_scr.dtype)
+        dk_scr[rows, :] = jnp.zeros((chunk, dk_scr.shape[1]), jnp.float32)
+        dv_scr[rows, :] = jnp.zeros((chunk, dv_scr.shape[1]), jnp.float32)
+
+    for i, rows in enumerate(spans):
+        w = (i + 1) * chunk
+        q = q_ref[0, rows, :]
+        if rope_mode:
+            cq, sq = _rope_q(rope_refs, rope_mode, i * chunk, chunk)
+            q = _rot(q, cq, sq).astype(q_ref.dtype)
+        do = do_ref[0, rows, :]
+        delta = jnp.sum(o_ref[0, rows, :].astype(jnp.float32)
+                        * do.astype(jnp.float32), axis=1, keepdims=True)
+        if has_dlse:
+            # ds_ij = p_ij (dp_ij - delta_i + dlse_i): the logsumexp's
+            # cotangent is an offset on the row sums.
+            delta = delta - dlse_ref[0, rows, :][:, :1]
+        dq, dk, dv = _bwd_pair(
+            q, load_k(pl.ds(0, w)), v_ref[0, :w, :], do,
+            lse_ref[0, rows, :][:, :1], delta, None, masked=True,
+            has_bias=False, q_start=i * chunk, k_start=0, block_q=chunk,
+            block_k=w)
+        dk_scr[:w, :] += dk
+        dv_scr[:w, :] += dv
+        if rope_mode:
+            # dq is w.r.t. the ROTATED q; chain through the orthogonal
+            # rotation, R^T = the same lane-rotation with the sine negated.
+            dq = _rot(dq, cq, -sq)
+        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+
+    for j, rows in enumerate(spans):
+        dk = dk_scr[rows, :]
+        if rope_mode:
+            ck, sk = _rope_k(rope_refs, rope_mode, j * chunk, chunk)
+            dk = _rot(dk, ck, -sk)
+        dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, rows, :] = dv_scr[rows, :].astype(dv_ref.dtype)
 
 
 def _rope_inputs(cos_t, sin_t, rope_mode, h, lp, d, block_q, block_k,
@@ -461,13 +609,15 @@ def _delta(of, do_f, dlse_f):
     """Per-row backward offset ``sum(o * do) - dlse`` in fp32, broadcast
     to the ``_STATS_W`` stats width: a cotangent on the logsumexp folds
     into the backward as ``ds_ij = p_ij (dp_ij - delta_i + dlse_i)``
-    (since dlse_i/ds_ij = p_ij); zero-cotangent callers pay nothing.
-    Shared by both backward implementations so the fold stays in one
-    place."""
+    (since dlse_i/ds_ij = p_ij); ``dlse_f`` is ``None`` where the
+    caller dropped the logsumexp.  Shared by the grid-walk backward
+    implementations so the fold stays in one place (the resident kernel
+    forms the same sums from its own ``o`` and ``do``)."""
     bh, lp = of.shape[0], of.shape[1]
     delta = jnp.sum(of.astype(jnp.float32) * do_f.astype(jnp.float32),
                     axis=-1, keepdims=True)                    # (bh, lp, 1)
-    delta = delta - dlse_f[..., None]
+    if dlse_f is not None:
+        delta = delta - dlse_f[..., None]
     return jnp.broadcast_to(delta, (bh, lp, _STATS_W))
 
 
@@ -521,13 +671,52 @@ def _flash_bwd_fused(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f,
     return dq, dk, dv
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("rope_mode", "chunk", "num_heads"))
+def _flash_bwd_resident(qf, kf, vf, of, do_f, lse, cos_t, sin_t, dlse_f, *,
+                        rope_mode, chunk, num_heads):
+    """Causal, bias-free backward with a head's rows resident: grid
+    ``(bh,)``, every operand one ``(1, Lp, ·)`` block.  ``dlse_f`` is
+    ``None`` where the caller dropped the logsumexp; otherwise it rides
+    in at the stats width, as ``lse`` does."""
+    bh, lp, d = qf.shape
+    h = num_heads
+    row = pl.BlockSpec((1, lp, d), lambda bh_: (bh_, 0, 0))
+    stats = pl.BlockSpec((1, lp, _STATS_W), lambda bh_: (bh_, 0, 0))
+    table = pl.BlockSpec((1, lp, d), lambda bh_: (bh_ // h, 0, 0))
+    operands, in_specs = [qf, kf, vf, do_f, of, lse], [row] * 5 + [stats]
+    if dlse_f is not None:
+        operands.append(jnp.broadcast_to(dlse_f[..., None],
+                                         (bh, lp, _STATS_W)))
+        in_specs.append(stats)
+    scratch = [pltpu.VMEM((lp, d), jnp.float32),            # dk
+               pltpu.VMEM((lp, d), jnp.float32)]            # dv
+    if rope_mode:
+        operands += [cos_t, sin_t]
+        in_specs += [table, table]
+        scratch.append(pltpu.VMEM((lp, d), kf.dtype))       # rotated K
+    return pl.pallas_call(
+        functools.partial(_bwd_resident_kernel,
+                          has_dlse=dlse_f is not None, rope_mode=rope_mode,
+                          chunk=chunk, n=lp // chunk),
+        grid=(bh,),
+        in_specs=in_specs,
+        out_specs=[row] * 3,
+        out_shape=[_sds((bh, lp, d), qf.dtype, qf)] * 3,
+        scratch_shapes=scratch,
+        name="flash_bwd_fused",
+        interpret=not on_tpu(),
+    )(*operands)
+
+
 def _fused_bwd_max_bytes() -> int:
-    """HBM budget for the fused backward's (groups, BH, L, d) fp32
-    dq-partials buffer; the gate is its size, not the block count —
-    fused still wins at nk=16 when the buffer fits (gpt-small-tpu
-    L=16384: 805 MB partials, +6% step throughput over two-pass).
-    Above this budget the extra HBM outweighs the saved recompute and
-    the two-pass kernels take over (extreme contexts / big batches).
+    """HBM budget for the grid walk's fused backward's (groups, BH, L,
+    d) fp32 dq-partials buffer; the gate is its size, not the block
+    count — fused still wins at nk=16 when the buffer fits (the smoke's
+    gpt-small-tpu L=16384 runs it with 805 MB of partials).  Above this
+    budget the extra HBM outweighs the saved recompute and the two-pass
+    kernels take over (extreme contexts / big batches).  A resident
+    head (:func:`_geometry`) has no partials and never asks.
 
     ``APEX_TPU_FLASH_FUSED_BWD_MAX_BYTES`` overrides (0 forces the
     two-pass path) so memory-tight configs can steer without
@@ -584,20 +773,32 @@ def _unprep(t, b, l, h, d, layout="blhd"):
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "has_bias", "rope_mode",
-                                    "block_q", "block_k", "num_heads"))
+                                    "block_q", "block_k", "num_heads",
+                                    "resident"))
 def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
-               rope_mode, block_q, block_k, num_heads):
+               rope_mode, block_q, block_k, num_heads, resident):
     bh, lp, d = qf.shape
+    # Resident: K and V are one block a head, its index constant over
+    # the q walk, so fetched once a head; else the grid walks K.
+    if resident:
+        block_k = lp
     nq, nk = lp // block_q, lp // block_k
     grid = (bh, nq, nk)
     h = num_heads
     rope_ops, rope_specs = _rope_inputs(cos_t, sin_t, rope_mode, h, lp, d,
                                         block_q, block_k, q_pos=1, k_pos=2)
+    scratch = [
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
+        pltpu.VMEM((block_q, d), jnp.float32),
+    ]
+    if resident and rope_mode:
+        scratch.append(pltpu.VMEM((lp, d), kf.dtype))      # rotated K
 
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, has_bias=has_bias,
                           rope_mode=rope_mode, block_q=block_q,
-                          block_k=block_k, nk=nk),
+                          block_k=block_k, nk=nk, resident=resident),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, iq, ik: (bh_, iq, 0)),
@@ -617,11 +818,7 @@ def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
             # _STATS_W).
             _sds((bh, lp, _STATS_W), jnp.float32, qf),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         name="flash_fwd",
         interpret=not on_tpu(),
     )(qf, kf, vf, bias, *rope_ops)
@@ -704,14 +901,23 @@ def _flash_bwd(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f, *,
     return dq, dk, dv
 
 
+#: ``jax.named_scope`` around a flash call's kernels, by the geometry
+#: :func:`_geometry` chose at trace time: the counter that says whether
+#: the resident walk engaged, read off any instruction's ``op_name``.
+RESIDENT_SCOPE, GRID_SCOPE = "flash_resident", "flash_grid"
+
+
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14))
 def _flash(q, k, v, bias, cos_t, sin_t, scale, causal, block_q, block_k,
-           has_bias, rope_mode, layout):
-    (out, lse_pub), _ = _flash_core(q, k, v, bias, cos_t, sin_t, scale,
-                                    causal, block_q, block_k, has_bias,
-                                    rope_mode, layout)
-    return out, lse_pub
+           has_bias, rope_mode, layout, resident, with_lse):
+    """``out``, or ``(out, lse)`` with ``with_lse``: a caller that drops
+    the logsumexp says so here, and its backward carries no cotangent
+    for it."""
+    outs, _ = _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal,
+                          block_q, block_k, has_bias, rope_mode, layout,
+                          resident, with_lse)
+    return outs
 
 
 def _lse_public(lse, b, l, h):
@@ -720,7 +926,7 @@ def _lse_public(lse, b, l, h):
 
 
 def _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal, block_q,
-                block_k, has_bias, rope_mode, layout="blhd"):
+                block_k, has_bias, rope_mode, layout, resident, with_lse):
     if layout == "bhld":
         b, h, l, d = q.shape
     else:
@@ -738,25 +944,31 @@ def _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal, block_q,
         pad = ((0, 0), (0, lp - cos_t.shape[1]), (0, 0))
         cos_t = jnp.pad(cos_t, pad)
         sin_t = jnp.pad(sin_t, pad)
-    of, lse = _flash_fwd(qf, kf, vf, bias_p, cos_t, sin_t, causal=causal,
-                         has_bias=has_bias, rope_mode=rope_mode,
-                         block_q=block_q, block_k=block_k, num_heads=h)
-    return ((_unprep(of, b, l, h, d, layout), _lse_public(lse, b, l, h)),
+    with jax.named_scope(RESIDENT_SCOPE if resident else GRID_SCOPE):
+        of, lse = _flash_fwd(qf, kf, vf, bias_p, cos_t, sin_t,
+                             causal=causal, has_bias=has_bias,
+                             rope_mode=rope_mode, block_q=block_q,
+                             block_k=block_k, num_heads=h,
+                             resident=resident)
+    out = _unprep(of, b, l, h, d, layout)
+    return ((out, _lse_public(lse, b, l, h)) if with_lse else out,
             (qf, kf, vf, of, lse, bias_p, cos_t, sin_t))
 
 
 def _flash_fwd_rule(q, k, v, bias, cos_t, sin_t, scale, causal, block_q,
-                    block_k, has_bias, rope_mode, layout):
+                    block_k, has_bias, rope_mode, layout, resident,
+                    with_lse):
     outs, res = _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal,
-                            block_q, block_k, has_bias, rope_mode, layout)
+                            block_q, block_k, has_bias, rope_mode, layout,
+                            resident, with_lse)
     # The saved tables are padded to Lp; the cotangents must match the
     # caller's (unpadded) table shape, so remember it.
     return outs, (res, q.shape, cos_t.shape)
 
 
 def _flash_bwd_rule(scale, causal, block_q, block_k, has_bias, rope_mode,
-                    layout, saved, cotangents):
-    dout, dlse = cotangents
+                    layout, resident, with_lse, saved, cotangents):
+    dout, dlse = cotangents if with_lse else (cotangents, None)
     (qf, kf, vf, of, lse, bias_p, cos_t, sin_t), shape, table_shape = saved
     if layout == "bhld":
         b, h, l, d = shape
@@ -766,17 +978,29 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, has_bias, rope_mode,
     do_f = _pad_bhld(dout, lp, layout)
     # A cotangent on the logsumexp folds into the backward as an offset on
     # delta: ds_ij = p_ij (dp_ij - delta_i + dlse_i), since dlse_i/ds_ij =
-    # p_ij.  Zero-cotangent callers (plain attention) pay nothing.
-    dlse_f = jnp.moveaxis(dlse.astype(jnp.float32), 1, 2).reshape(b * h, l)
-    if lp != l:
-        dlse_f = jnp.pad(dlse_f, ((0, 0), (0, lp - l)))
-    partials_bytes = (lp // block_k) * qf.shape[0] * lp * d * 4
-    bwd = (_flash_bwd_fused if partials_bytes <= _fused_bwd_max_bytes()
-           else _flash_bwd)
-    dqf, dkf, dvf = bwd(qf, kf, vf, of, do_f, lse, bias_p, cos_t, sin_t,
-                        dlse_f, causal=causal, has_bias=has_bias,
-                        rope_mode=rope_mode, block_q=block_q,
-                        block_k=block_k, num_heads=h)
+    # p_ij.  Callers that dropped the logsumexp (plain attention) pay
+    # nothing.
+    dlse_f = None
+    if with_lse:
+        dlse_f = jnp.moveaxis(dlse.astype(jnp.float32), 1, 2).reshape(
+            b * h, l)
+        if lp != l:
+            dlse_f = jnp.pad(dlse_f, ((0, 0), (0, lp - l)))
+    with jax.named_scope(RESIDENT_SCOPE if resident else GRID_SCOPE):
+        if resident:
+            dqf, dkf, dvf = _flash_bwd_resident(
+                qf, kf, vf, of, do_f, lse, cos_t, sin_t, dlse_f,
+                rope_mode=rope_mode, chunk=block_k, num_heads=h)
+        else:
+            partials_bytes = (lp // block_k) * qf.shape[0] * lp * d * 4
+            bwd = (_flash_bwd_fused
+                   if partials_bytes <= _fused_bwd_max_bytes()
+                   else _flash_bwd)
+            dqf, dkf, dvf = bwd(qf, kf, vf, of, do_f, lse, bias_p, cos_t,
+                                sin_t, dlse_f, causal=causal,
+                                has_bias=has_bias, rope_mode=rope_mode,
+                                block_q=block_q, block_k=block_k,
+                                num_heads=h)
     # The kernels differentiate w.r.t. the pre-scaled q: dk comes out
     # exact (ds^T @ q_scaled), dq needs the one deferred scale.  On the
     # rope path the kernels already inverse-rotated at emit, so dq/dk
@@ -832,17 +1056,88 @@ def _jnp_attention(q, k, v, *, causal, kv_mask, scale, return_lse=False):
 
 
 def _default_block(l: int) -> int:
-    """Default q/k block edge by sequence length: 512, growing to 1024 at
-    L >= 2048 where fewer, larger grid steps measure ~18% faster on-chip
-    (per-step overhead and the online-softmax stats updates amortize;
-    B8·H12·L2048·d64 fwd 3.2 -> 2.6 ms, fwd+bwd 9.1 -> 7.5 ms; 2048
-    blocks fail to compile with the fp32 score tile) — but only when the
-    larger block adds no padding: for L not near a multiple of 1024 the
-    padded sequence would grow, and the quadratic extra attention work
-    erases the per-step win."""
+    """Default q/k block edge of the grid walk by sequence length: 512,
+    growing to 1024 at L >= 2048, where fewer, larger grid steps
+    amortize the per-step overhead and the online-softmax stats updates
+    (2048 blocks fail to compile with the fp32 score tile) — but only
+    when the larger block adds no padding: for L not near a multiple of
+    1024 the padded sequence would grow, and the quadratic extra
+    attention work erases the per-step win.  The choice dates from the
+    round-3 chip, at B8·H12·L2048·d64; no cell of ``BENCHMARK.json``
+    runs a grid-walk call at L >= 2048 yet (PERF.md section 7)."""
     if l >= 2048 and _ceil_to(l, 1024) == _ceil_to(l, 512):
         return 1024
     return 512
+
+
+#: Rows of q a resident head scores at a time (against all their visible
+#: keys), widest first.  On the v5e a row of a pair costs about as much
+#: whatever the pair's width, and every q block loads its keys into the
+#: MXU anew, so fewer, taller blocks win as long as they fit: at the
+#: benchmark's shape 512 rows beat 256 and 128 in the forward and tie in
+#: the backward (PERF.md, PR 26).
+_RESIDENT_CHUNKS = (512, 256)
+
+#: What the resident geometry may plan to hold in VMEM: three quarters
+#: of Mosaic's default 16 MiB scoped limit.
+_RESIDENT_VMEM_BYTES = 3 * (16 << 20) // 4
+
+
+class _Geometry(NamedTuple):
+    """How a call walks its head: ``resident`` (K/V one block a head,
+    each ``block`` rows of q against all their visible keys in one
+    step) or the grid walk with ``block`` as the edge of the grid's q
+    and k blocks."""
+    resident: bool
+    block: int
+
+
+def _resident_bytes(lp: int, d: int, itemsize: int, rope: bool,
+                    chunk: int) -> int:
+    """VMEM the resident backward, the larger of the two kernels, needs
+    for a head of ``lp`` rows scored ``chunk`` rows at a time: the
+    head's operands, results and scratch once each (VMEM tiles are 128
+    lanes wide whatever ``d`` is) and one and a half fp32 score tiles
+    of the widest pair.  Fitted to the compiler's own accounting: for
+    heads too long to fit, Mosaic's refusals name 16.4 to 22.8 MB at
+    3072 rows in bf16 where this gives 16.4 to 22.7 (PERF.md, PR 26)."""
+    row = lp * _ceil_to(d, _LANES)
+    rows = (5 + 3) * row * itemsize            # q k v do o; dq dk dv
+    stats = 2 * lp * _STATS_W * 4              # lse and its cotangent
+    tables = 2 * row * (2 if itemsize == 2 else 4) if rope else 0
+    scratch = 2 * row * 4 + (row * itemsize if rope else 0)
+    return rows + stats + tables + scratch + 3 * chunk * lp * 4 // 2
+
+
+def _grid_block(l: int, itemsize: int, rope: bool) -> int:
+    """The grid walk's default block edge: :func:`_default_block`, capped
+    at 512 for fp32 activations with rope tables — the fused backward at
+    1024-blocks already sits near the 16 MB scoped-VMEM cliff in fp32,
+    and the table blocks push it over (16.93 MB on the O0 L2048 train
+    step, round 4)."""
+    block = _default_block(l)
+    return min(block, 512) if rope and itemsize == 4 else block
+
+
+def _geometry(l: int, d: int, itemsize: int, causal: bool, rope: bool,
+              has_bias: bool) -> _Geometry:
+    """The geometry of a call with no explicit blocks, from what the
+    call can see.  Resident when the mask is causal (nothing above the
+    diagonal is then fetched, scored or masked), there is no key bias
+    (its ``(1, block_k)`` lane blocks ride the grid), and the head fits
+    :data:`_RESIDENT_VMEM_BYTES` at one of :data:`_RESIDENT_CHUNKS` —
+    the one that pads the sequence least, the taller on a tie.  Else
+    the grid walk with the blocks it always had: long contexts, any key
+    mask, and every non-causal call."""
+    if causal and not has_bias:
+        fits = [c for c in (min(c, _ceil_to(l, _LANES))
+                            for c in _RESIDENT_CHUNKS)
+                if _resident_bytes(_ceil_to(l, c), d, itemsize, rope, c)
+                <= _RESIDENT_VMEM_BYTES]
+        if fits:
+            return _Geometry(True, min(fits, key=lambda c: (_ceil_to(l, c),
+                                                             -c)))
+    return _Geometry(False, _grid_block(l, itemsize, rope))
 
 
 @functools.lru_cache(maxsize=None)
@@ -887,13 +1182,16 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
     Equivalent to the jnp reference path in :mod:`apex_tpu.attention`
     (scores never materialized; fp32 softmax; masked rows emit zeros).
     ``kv_mask``: optional ``(B, Lk)`` bool key mask (True = attend).
-    ``block_q``/``block_k`` default by sequence length — 512, growing to
-    1024 at L >= 2048 where fewer, larger grid steps measure ~18% faster
-    on-chip (per-step overhead amortizes; 2048 blocks exceed VMEM with
-    the fp32 score block) — and are clamped to the (padded) length,
-    then rounded up to Mosaic tile granularity (``block_q`` to a
-    multiple of 8, ``block_k`` to a multiple of 128 — narrower k blocks
-    miscompile on hardware).
+    With ``block_q``/``block_k`` left ``None`` the geometry comes from
+    the call's own shape (:func:`_geometry`): a causal call without a
+    key mask whose head fits VMEM keeps the head's rows resident and
+    scores each block of q rows against all its visible keys at once;
+    every other call walks the grid with blocks by sequence length —
+    512, growing to 1024 at L >= 2048 (:func:`_default_block`).  Explicit ``block_q``/``block_k``
+    are the caller's grid blocks: clamped to the (padded) length, then
+    rounded up to Mosaic tile granularity (``block_q`` to a multiple of
+    8, ``block_k`` to a multiple of 128 — narrower k blocks miscompile
+    on hardware).
     Cross-attention (``Lq != Lk``) routes to an equivalent jnp path — the
     blockwise kernel packs q and k/v with one shared sequence length.
 
@@ -933,21 +1231,17 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
             return jnp.moveaxis(out, 1, 2)
         return out
     explicit = (block_q, block_k)
+    itemsize = jnp.dtype(q.dtype).itemsize
+    geo = _geometry(l, d_head, itemsize, bool(causal), rope is not None,
+                    kv_mask is not None)
+    # Explicit blocks are the caller's blocks: the grid walks them.
+    resident = geo.resident and explicit == (None, None)
+    default = (geo.block if resident
+               else _grid_block(l, itemsize, rope is not None))
     if block_q is None:
-        block_q = _default_block(l)
+        block_q = default
     if block_k is None:
-        block_k = _default_block(l)
-    if rope is not None and jnp.dtype(q.dtype).itemsize == 4:
-        # fp32 activations + rope tables: the fused backward at
-        # 1024-blocks already sits near the 16 MB scoped-VMEM cliff in
-        # fp32, and the table blocks push it over (measured: 16.93 MB,
-        # +952 KB over the limit, on the O0 L2048 train step).  Cap the
-        # *defaulted* blocks at 512; explicit requests stay the
-        # caller's choice.
-        if explicit[0] is None:
-            block_q = min(block_q, 512)
-        if explicit[1] is None:
-            block_k = min(block_k, 512)
+        block_k = default
     block_q = min(block_q, _ceil_to(l, 128))
     block_k = min(block_k, _ceil_to(l, 128))
     # Mosaic tile granularity: the score tile is (block_q, block_k), so
@@ -995,9 +1289,10 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
                                               d_head, table_dtype)
         lp = _ceil_to(l, math.lcm(int(block_q), int(block_k)))
         per_side = 2 * lp * d_head * cos_t.dtype.itemsize
-        rope_mode = ("resident"
-                     if per_side <= _ROPE_RESIDENT_MAX_BYTES else "stream")
-    out, lse = _flash(q, k, v, bias, cos_t, sin_t, float(scale),
-                      bool(causal), int(block_q), int(block_k), has_bias,
-                      rope_mode, layout)
-    return (out, lse) if return_lse else out
+        # A resident head holds its tables whole (_resident_bytes counts
+        # them); the budget is the grid walk's.
+        rope_mode = ("resident" if resident
+                     or per_side <= _ROPE_RESIDENT_MAX_BYTES else "stream")
+    return _flash(q, k, v, bias, cos_t, sin_t, float(scale), bool(causal),
+                  int(block_q), int(block_k), has_bias, rope_mode, layout,
+                  resident, bool(return_lse))

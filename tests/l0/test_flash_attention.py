@@ -194,6 +194,34 @@ def test_default_blocks_scale_with_length():
     )
     for l, expect in cases:
         assert _default_block(l) == expect, l
+    # The geometry of a call with no explicit blocks, as a pure function
+    # of (l, d, itemsize, causal, rope, has_bias): the benchmark's call
+    # (L 1024, 16 heads of 64, bf16, causal, rope) keeps its head's rows
+    # in VMEM; every non-causal call, a key mask, and a head over the
+    # VMEM budget keep the grid walk with the blocks they always had.
+    from apex_tpu.ops.pallas.flash_attention import _geometry
+    geometry_cases = (
+        ((1024, 64, 2, True, True, False), (True, 512)),
+        ((1024, 128, 2, True, False, False), (True, 512)),
+        ((1000, 64, 2, True, True, False), (True, 512)),
+        ((1024, 64, 4, True, True, False), (True, 512)),
+        # short heads are one chunk; 600 pads to 768 at 256 rows a chunk
+        # and to 1024 at 512
+        ((256, 64, 4, True, False, False), (True, 256)),
+        ((100, 64, 4, True, False, False), (True, 128)),
+        ((600, 64, 2, True, True, False), (True, 256)),
+        ((512, 64, 2, False, False, False), (False, 512)),
+        ((1024, 64, 2, False, True, False), (False, 512)),
+        ((1024, 64, 2, True, True, True), (False, 512)),
+        # over the budget: fp32 at 1536, gpt_small_tpu's 8 x 2048 at
+        # d 128, the smoke's 16384
+        ((1536, 64, 4, True, True, False), (False, 512)),
+        ((2048, 128, 2, True, True, False), (False, 1024)),
+        ((2048, 128, 4, True, True, False), (False, 512)),
+        ((16384, 128, 2, True, True, False), (False, 1024)),
+    )
+    for args, expect in geometry_cases:
+        assert tuple(_geometry(*args)) == expect, args
 
 
 @pytest.mark.skipif(_ON_CPU, reason="interpret-mode 4096^2 attention is "
@@ -315,11 +343,10 @@ class TestRopeFused:
         seen = []
         real = fa._flash
 
-        def spy(q, k, v, bias, cos_t, sin_t, scale, causal, bq, bk,
-                has_bias, rope_mode, layout):
+        def spy(*args):
+            bq, bk, rope_mode = args[8], args[9], args[11]
             seen.append((bq, bk, rope_mode))
-            return real(q, k, v, bias, cos_t, sin_t, scale, causal, bq,
-                        bk, has_bias, rope_mode, layout)
+            return real(*args)
 
         monkeypatch.setattr(fa, "_flash", spy)
         l = 2048
@@ -421,3 +448,224 @@ def test_unequal_blocks_fuzz(bq, bk, causal, use_mask):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=RTOL, atol=ATOL)
     _check_grads(q, k, v, causal, mask, block_q=bq, block_k=bk)
+
+
+class TestResidentHead:
+    """Causal calls whose head fits VMEM keep its rows resident: K and V
+    are fetched once a head, each block of q rows meets all its visible
+    keys (none above the diagonal) in one step, rope is applied once a
+    row, and the backward forms its own row sums and its own dq
+    (``_geometry`` chooses; no knob).  Same mathematics as the grid walk, checked against the jnp
+    oracle."""
+
+    @pytest.fixture
+    def flash_calls(self, monkeypatch):
+        """Every ``_flash`` call's ``(block_q, block_k, resident)``."""
+        from apex_tpu.ops.pallas import flash_attention as fa
+        seen = []
+        real = fa._flash
+
+        def spy(*args):
+            seen.append((args[8], args[9], args[13]))
+            return real(*args)
+
+        monkeypatch.setattr(fa, "_flash", spy)
+        return seen
+
+    @staticmethod
+    def _inputs(l, d, rope, seed=0):
+        from apex_tpu.ops.rope import apply_rope, rope_tables
+        rng = np.random.RandomState(seed)
+        q, k, v = (jnp.asarray(rng.randn(1, l, 2, d).astype(np.float32))
+                   for _ in range(3))
+        if not rope:
+            return (q, k, v), {}, lambda q, k: (q, k)
+        pos = jnp.arange(l)[None, :]
+        cos, sin = rope_tables(pos, d, 10000.0)
+        return ((q, k, v), dict(rope=(cos, sin)),
+                lambda q, k: (apply_rope(q, cos, sin),
+                              apply_rope(k, cos, sin)))
+
+    @pytest.mark.parametrize("layout", ["blhd", "bhld"])
+    @pytest.mark.parametrize("l", [256, 1000, 1024])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("rope", [False, True])
+    def test_forward_and_grads_match_reference(self, flash_calls, rope, d,
+                                               l, layout):
+        (q, k, v), kw, rotate = self._inputs(l, d, rope)
+        to = ((lambda t: jnp.moveaxis(t, 1, 2)) if layout == "bhld"
+              else (lambda t: t))
+
+        def flash(q, k, v):
+            return to(flash_attention(to(q), to(k), to(v), causal=True,
+                                      layout=layout, **kw))
+
+        def ref(q, k, v):
+            return ref_attn(*rotate(q, k), v, causal=True)
+
+        np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                                   np.asarray(ref(q, k, v)),
+                                   rtol=RTOL, atol=ATOL)
+        chunk = min(512, l)
+        assert flash_calls[-1] == (chunk, chunk, True)
+        loss = lambda fn: lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+        gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gf, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=GTOL, atol=GTOL)
+
+    @pytest.mark.parametrize("rope", [False, True])
+    def test_lse_cotangent_reaches_the_in_kernel_row_sums(self, flash_calls,
+                                                          rope):
+        """``return_lse=True`` with a non-zero cotangent on the logsumexp
+        (ring attention's carry): ``ds = p (dp - delta + dlse)`` with
+        ``delta`` formed inside the kernel."""
+        from apex_tpu.ops.pallas.flash_attention import _jnp_attention
+        l, d = 600, 64
+        (q, k, v), kw, rotate = self._inputs(l, d, rope, seed=5)
+        w = jnp.asarray(np.random.RandomState(6).randn(1, l, 2)
+                        .astype(np.float32))
+
+        def loss(fn):
+            def f(q, k, v):
+                out, lse = fn(q, k, v)
+                return jnp.sum(jnp.sin(out)) + jnp.sum(w * lse)
+            return f
+
+        flash = lambda q, k, v: flash_attention(
+            q, k, v, causal=True, return_lse=True, **kw)
+        ref = lambda q, k, v: _jnp_attention(
+            *rotate(q, k), v, causal=True, kv_mask=None,
+            scale=1.0 / d ** 0.5, return_lse=True)
+        out, lse = flash(q, k, v)
+        assert flash_calls[-1] == (256, 256, True)      # 600 pads to 768
+        ro, rl = ref(q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ro),
+                                   rtol=GTOL, atol=GTOL)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(rl),
+                                   rtol=GTOL, atol=GTOL)
+        gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gf, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=GTOL, atol=GTOL)
+
+    def test_the_cells_call_is_resident_in_bf16(self):
+        """The benchmark's call as it is made (bf16, rope, L 1024, heads
+        of 64), budget untouched, against the grid walk on explicit
+        blocks: same mathematics, another summation order."""
+        from apex_tpu.ops.pallas import flash_attention as fa
+        (q, k, v), kw, _ = self._inputs(1024, 64, True, seed=2)
+        q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
+        assert fa._geometry(1024, 64, 2, True, True, False).resident
+
+        def grads(**blocks):
+            def loss(q, k, v):
+                out = flash_attention(q, k, v, causal=True, **kw, **blocks)
+                return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        for a, b in zip(grads(), grads(block_q=512, block_k=512)):
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32),
+                                       rtol=3e-2, atol=3e-2)
+
+    @pytest.mark.parametrize("blocks", [dict(block_q=512),
+                                        dict(block_k=512),
+                                        dict(block_q=256, block_k=256)])
+    def test_explicit_blocks_keep_the_grid_walk(self, flash_calls, blocks):
+        (q, k, v), kw, _ = self._inputs(1024, 64, False)
+        flash_attention(q, k, v, causal=True, **blocks)
+        bq, bk, resident = flash_calls[-1]
+        assert not resident
+        assert (bq, bk) == (blocks.get("block_q", 512),
+                            blocks.get("block_k", 512))
+
+    def test_key_mask_and_non_causal_keep_the_grid_walk(self, flash_calls):
+        (q, k, v), kw, _ = self._inputs(512, 64, False)
+        flash_attention(q, k, v, causal=False)
+        assert flash_calls[-1] == (512, 512, False)
+        flash_attention(q, k, v, causal=True,
+                        kv_mask=jnp.ones((1, 512), bool))
+        assert flash_calls[-1] == (512, 512, False)
+
+
+def _lowered_op_names(fn, *args):
+    """``op_name`` of every instruction of ``fn`` compiled on this
+    platform (interpret mode inlines the kernel body under its scopes)."""
+    from benchmark import trace
+    return list(trace.op_names(
+        jax.jit(fn).lower(*args).compile().as_text()).values())
+
+
+def test_geometry_scope_names_the_walk_in_the_compiled_text():
+    """``flash_resident`` / ``flash_grid``: the trace-time choice of
+    geometry as a ``jax.named_scope`` around the kernel calls, forward
+    and backward; it adds a path segment only, so the benchmark still
+    reads the block ``attention`` from such an ``op_name``."""
+    import sys
+    from pathlib import Path
+    sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
+    from benchmark import scopes
+    from apex_tpu.ops.pallas.flash_attention import (
+        GRID_SCOPE, RESIDENT_SCOPE)
+    assert (RESIDENT_SCOPE, GRID_SCOPE) == ("flash_resident", "flash_grid")
+
+    def grad_of(causal):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=causal).astype(jnp.float32)), argnums=(0, 1, 2))
+
+    q = jnp.ones((1, 1024, 1, 64), jnp.bfloat16)
+    causal = _lowered_op_names(grad_of(True), q, q, q)
+    on_path = lambda names, seg: [n for n in names
+                                  if seg in scopes.segments(n)]
+    assert on_path(causal, RESIDENT_SCOPE) and not on_path(causal,
+                                                             GRID_SCOPE)
+    assert any("transpose(jvp(" in n for n in on_path(causal,
+                                                      RESIDENT_SCOPE))
+    q = jnp.ones((1, 512, 1, 64), jnp.bfloat16)
+    plain = _lowered_op_names(grad_of(False), q, q, q)
+    assert on_path(plain, GRID_SCOPE) and not on_path(plain, RESIDENT_SCOPE)
+    for scope in (RESIDENT_SCOPE, GRID_SCOPE):
+        op_name = (f"jit(step)/transpose(jvp(GPTModel))/block_3/attention/"
+                   f"{scope}/jit(_flash_bwd_fused)/flash_bwd_fused/"
+                   f"pallas_call")
+        assert scopes.block(op_name, "gpt") == "attention"
+        assert scopes.phase("flash_bwd_fused.3", op_name) == "backward"
+
+
+def test_resident_backward_types_under_shard_map(monkeypatch):
+    """The four-chip cell's call: the resident kernels under a
+    data-parallel ``shard_map``.  Their gradients and the zero
+    cotangents of the rope tables have to carry their primals'
+    varying-axes types, with and without a cotangent on the logsumexp
+    (an operand only that backward has).  Trace-only: the CPU tier
+    cannot run Mosaic."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+    from apex_tpu.ops.pallas import flash_attention as fa
+    from apex_tpu.ops.rope import rope_tables
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    q = jnp.ones((4, 1024, 2, 64), jnp.bfloat16)
+    cos, sin = rope_tables(
+        jnp.broadcast_to(jnp.arange(1024)[None, :], (4, 1024)), 64, 10000.0)
+
+    for return_lse in (False, True):
+        def loss(q, cos, sin):
+            def local(q, cos, sin):
+                out = flash_attention(q, q, q, causal=True, rope=(cos, sin),
+                                      return_lse=return_lse)
+                out = sum(jnp.sum(t.astype(jnp.float32)) for t in
+                          (out if return_lse else (out,)))
+                return jax.lax.psum(out, "data")
+            return shard_map(local, mesh=mesh,
+                             in_specs=(P("data"), P("data"), P("data")),
+                             out_specs=P())(q, cos, sin)
+
+        text = jax.jit(jax.grad(loss)).trace(q, cos, sin).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+        assert 'kernel_name = "flash_fwd"' in text
+        assert 'kernel_name = "flash_bwd_fused"' in text
+        assert "flash_resident" in text and "flash_grid" not in text

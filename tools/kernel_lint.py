@@ -183,6 +183,24 @@ def _flash_configs():
         tag = f"b{b} l{l} h{h} d{d} causal={causal}"
         cfgs.append((f"fwd {tag}", fwd, (q, q, q, mask)))
         cfgs.append((f"fwd+bwd {tag}", fwd_bwd, (q, q, q, mask)))
+
+    # the benchmark's call: causal, rope in the kernel, no key mask, so
+    # the head's rows stay resident in VMEM (whole-head K/V blocks, the
+    # dq scratch, one grid step a head in the backward)
+    from apex_tpu.ops.rope import rope_tables
+    b, l, h, d = 2, 1024, 2, 64
+    q = jnp.ones((b, l, h, d), bf16)
+    cos, sin = rope_tables(jnp.broadcast_to(jnp.arange(l)[None, :], (b, l)),
+                           d, 10000.0)
+
+    def resident_fwd_bwd(q, k, v, cos, sin):
+        y, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            rope=(cos, sin)), q, k, v)
+        return vjp(y)
+
+    cfgs.append((f"fwd+bwd b{b} l{l} h{h} d{d} causal rope resident",
+                 resident_fwd_bwd, (q, q, q, cos, sin)))
     return cfgs
 
 
