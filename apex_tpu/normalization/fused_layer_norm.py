@@ -77,6 +77,32 @@ def fused_layer_norm_affine(x: jax.Array,
     return y.astype(x.dtype).reshape(x.shape)
 
 
+def fused_rms_norm_affine(x: jax.Array, weight: jax.Array,
+                          normalized_shape: Union[int, Sequence[int]],
+                          eps: float = 1e-5) -> jax.Array:
+    """Affine RMS norm (upstream ``fused_rms_norm_affine``):
+    ``x * rsqrt(mean(x^2) + eps) * weight``, the mean square in fp32
+    whatever the input's dtype, the result in the input's dtype.  On the
+    Pallas path it is the LayerNorm kernels' ``rms`` mode."""
+    nshape = _normalized_shape(normalized_shape)
+    assert x.shape[len(x.shape) - len(nshape):] == nshape, (
+        f"trailing dims of {x.shape} must equal normalized_shape {nshape}")
+    n2 = 1
+    for d in nshape:
+        n2 *= d
+    n1 = x.size // n2
+
+    from apex_tpu.ops.pallas import layer_norm_kernels as lnk
+    if use_pallas() and lnk.supported(n2, x.dtype):
+        return lnk.rms_norm_fwd_vjp(x.reshape(n1, n2), weight.reshape(n2),
+                                    eps).reshape(x.shape)
+
+    x32 = x.reshape(n1, n2).astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=1, keepdims=True) + eps)
+    y = x32 * inv * weight.reshape(1, n2).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(x.shape)
+
+
 class FusedLayerNorm(nn.Module):
     """Module mirroring ``torch.nn.LayerNorm`` semantics
     (``fused_layer_norm.py:64-160``): ``normalized_shape``, ``eps``,
@@ -98,3 +124,19 @@ class FusedLayerNorm(nn.Module):
         else:
             weight = bias = None
         return fused_layer_norm_affine(x, weight, bias, nshape, self.eps)
+
+
+class FusedRMSNorm(nn.Module):
+    """Upstream ``apex.normalization.FusedRMSNorm``: one gain ``scale``
+    (initialised to 1), no bias, no centring."""
+
+    normalized_shape: Union[int, Sequence[int]]
+    eps: float = 1e-5
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        nshape = _normalized_shape(self.normalized_shape)
+        weight = self.param("scale", nn.initializers.ones, nshape,
+                            self.param_dtype)
+        return fused_rms_norm_affine(x, weight, nshape, self.eps)

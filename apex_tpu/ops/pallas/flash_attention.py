@@ -33,6 +33,10 @@ FlashAttention — pattern, not code) mapped onto the TPU:
   ``dk/dv`` pass with q innermost) when the partials buffer would
   exceed the budget.  No ``(L, L)`` tensor ever hits HBM either way.
 
+Widths: q and k share the head width ``d`` they score at; v, the
+output and their cotangents have v's own width (``vf.shape[2]``), which
+only the second matmul of each pass sees.
+
 Masking: ``kv_mask`` (key padding) arrives as an additive fp32 bias row
 ``(B, L)`` (0 = attend, ``NEG_INF`` = ignore); causal masking is computed
 from block offsets inside the kernel.  Fully-masked query rows produce
@@ -628,6 +632,7 @@ def _flash_bwd_fused(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f,
                      *, causal, has_bias, rope_mode, block_q, block_k,
                      num_heads):
     bh, lp, d = qf.shape
+    dv_ = vf.shape[2]                   # v, o and do keep v's own width
     nq, nk = lp // block_q, lp // block_k
     h = num_heads
     delta = _delta(of, do_f, dlse_f)
@@ -642,8 +647,8 @@ def _flash_bwd_fused(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, ik, iq: (bh_, iq, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh_, ik, iq: (bh_, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, ik, iq: (bh_, ik, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh_, ik, iq: (bh_, iq, 0)),
+            pl.BlockSpec((1, block_k, dv_), lambda bh_, ik, iq: (bh_, ik, 0)),
+            pl.BlockSpec((1, block_q, dv_), lambda bh_, ik, iq: (bh_, iq, 0)),
             pl.BlockSpec((1, block_q, _STATS_W),
                          lambda bh_, ik, iq: (bh_, iq, 0)),
             pl.BlockSpec((1, block_q, _STATS_W),
@@ -655,15 +660,15 @@ def _flash_bwd_fused(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f,
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bh_, ik, iq: (ik, bh_, iq, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh_, ik, iq: (bh_, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, ik, iq: (bh_, ik, 0)),
+            pl.BlockSpec((1, block_k, dv_), lambda bh_, ik, iq: (bh_, ik, 0)),
         ],
         out_shape=[
             _sds((nk, bh, lp, d), jnp.float32, qf),
             _sds((bh, lp, d), qf.dtype, qf),
-            _sds((bh, lp, d), qf.dtype, qf),
+            _sds((bh, lp, dv_), qf.dtype, qf),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv_), jnp.float32)],
         name="flash_bwd_fused",
         interpret=not on_tpu(),
     )(qf, kf, vf, do_f, lse, delta, bias, *rope_ops)
@@ -680,17 +685,20 @@ def _flash_bwd_resident(qf, kf, vf, of, do_f, lse, cos_t, sin_t, dlse_f, *,
     ``None`` where the caller dropped the logsumexp; otherwise it rides
     in at the stats width, as ``lse`` does."""
     bh, lp, d = qf.shape
+    dv_ = vf.shape[2]                   # v, o and do keep v's own width
     h = num_heads
     row = pl.BlockSpec((1, lp, d), lambda bh_: (bh_, 0, 0))
+    vrow = pl.BlockSpec((1, lp, dv_), lambda bh_: (bh_, 0, 0))
     stats = pl.BlockSpec((1, lp, _STATS_W), lambda bh_: (bh_, 0, 0))
     table = pl.BlockSpec((1, lp, d), lambda bh_: (bh_ // h, 0, 0))
-    operands, in_specs = [qf, kf, vf, do_f, of, lse], [row] * 5 + [stats]
+    operands = [qf, kf, vf, do_f, of, lse]
+    in_specs = [row, row, vrow, vrow, vrow, stats]
     if dlse_f is not None:
         operands.append(jnp.broadcast_to(dlse_f[..., None],
                                          (bh, lp, _STATS_W)))
         in_specs.append(stats)
     scratch = [pltpu.VMEM((lp, d), jnp.float32),            # dk
-               pltpu.VMEM((lp, d), jnp.float32)]            # dv
+               pltpu.VMEM((lp, dv_), jnp.float32)]          # dv
     if rope_mode:
         operands += [cos_t, sin_t]
         in_specs += [table, table]
@@ -701,8 +709,9 @@ def _flash_bwd_resident(qf, kf, vf, of, do_f, lse, cos_t, sin_t, dlse_f, *,
                           chunk=chunk, n=lp // chunk),
         grid=(bh,),
         in_specs=in_specs,
-        out_specs=[row] * 3,
-        out_shape=[_sds((bh, lp, d), qf.dtype, qf)] * 3,
+        out_specs=[row, row, vrow],
+        out_shape=[_sds((bh, lp, d), qf.dtype, qf)] * 2
+        + [_sds((bh, lp, dv_), qf.dtype, qf)],
         scratch_shapes=scratch,
         name="flash_bwd_fused",
         interpret=not on_tpu(),
@@ -766,8 +775,8 @@ def _prep(q, k, v, bias, block_q, block_k, layout="blhd"):
             _pad_bhld(v, lp, layout), bias, lp)
 
 
-def _unprep(t, b, l, h, d, layout="blhd"):
-    t = t.reshape(b, h, -1, d)[:, :, :l, :]
+def _unprep(t, b, l, h, layout="blhd"):
+    t = t.reshape(b, h, -1, t.shape[-1])[:, :, :l, :]
     return t if layout == "bhld" else jnp.moveaxis(t, 1, 2)
 
 
@@ -778,6 +787,7 @@ def _unprep(t, b, l, h, d, layout="blhd"):
 def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
                rope_mode, block_q, block_k, num_heads, resident):
     bh, lp, d = qf.shape
+    dv_ = vf.shape[2]                   # v and o keep v's own width
     # Resident: K and V are one block a head, its index constant over
     # the q walk, so fetched once a head; else the grid walks K.
     if resident:
@@ -790,7 +800,7 @@ def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
     scratch = [
         pltpu.VMEM((block_q, _LANES), jnp.float32),
         pltpu.VMEM((block_q, _LANES), jnp.float32),
-        pltpu.VMEM((block_q, d), jnp.float32),
+        pltpu.VMEM((block_q, dv_), jnp.float32),
     ]
     if resident and rope_mode:
         scratch.append(pltpu.VMEM((lp, d), kf.dtype))      # rotated K
@@ -803,17 +813,17 @@ def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, iq, ik: (bh_, iq, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh_, iq, ik: (bh_, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, iq, ik: (bh_, ik, 0)),
+            pl.BlockSpec((1, block_k, dv_), lambda bh_, iq, ik: (bh_, ik, 0)),
             pl.BlockSpec((1, 1, block_k),
                          lambda bh_, iq, ik: (bh_ // h, 0, ik)),
         ] + rope_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh_, iq, ik: (bh_, iq, 0)),
+            pl.BlockSpec((1, block_q, dv_), lambda bh_, iq, ik: (bh_, iq, 0)),
             pl.BlockSpec((1, block_q, _STATS_W),
                          lambda bh_, iq, ik: (bh_, iq, 0)),
         ],
         out_shape=[
-            _sds((bh, lp, d), qf.dtype, qf),
+            _sds((bh, lp, dv_), qf.dtype, qf),
             # logsumexp replicated across the stats minor dim (see
             # _STATS_W).
             _sds((bh, lp, _STATS_W), jnp.float32, qf),
@@ -831,6 +841,7 @@ def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
 def _flash_bwd(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f, *,
                causal, has_bias, rope_mode, block_q, block_k, num_heads):
     bh, lp, d = qf.shape
+    dv_ = vf.shape[2]                   # v, o and do keep v's own width
     nq, nk = lp // block_q, lp // block_k
     h = num_heads
     delta = _delta(of, do_f, dlse_f)
@@ -851,8 +862,8 @@ def _flash_bwd(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f, *,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, iq, ik: (bh_, iq, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh_, iq, ik: (bh_, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, iq, ik: (bh_, ik, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh_, iq, ik: (bh_, iq, 0)),
+            pl.BlockSpec((1, block_k, dv_), lambda bh_, iq, ik: (bh_, ik, 0)),
+            pl.BlockSpec((1, block_q, dv_), lambda bh_, iq, ik: (bh_, iq, 0)),
             pl.BlockSpec((1, block_q, _STATS_W),
                          lambda bh_, iq, ik: (bh_, iq, 0)),
             pl.BlockSpec((1, block_q, _STATS_W),
@@ -876,8 +887,8 @@ def _flash_bwd(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f, *,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, ik, iq: (bh_, iq, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh_, ik, iq: (bh_, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, ik, iq: (bh_, ik, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh_, ik, iq: (bh_, iq, 0)),
+            pl.BlockSpec((1, block_k, dv_), lambda bh_, ik, iq: (bh_, ik, 0)),
+            pl.BlockSpec((1, block_q, dv_), lambda bh_, ik, iq: (bh_, iq, 0)),
             pl.BlockSpec((1, block_q, _STATS_W),
                          lambda bh_, ik, iq: (bh_, iq, 0)),
             pl.BlockSpec((1, block_q, _STATS_W),
@@ -887,14 +898,14 @@ def _flash_bwd(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f, *,
         ] + rope_specs_k,
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh_, ik, iq: (bh_, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, ik, iq: (bh_, ik, 0)),
+            pl.BlockSpec((1, block_k, dv_), lambda bh_, ik, iq: (bh_, ik, 0)),
         ],
         out_shape=[
             _sds((bh, lp, d), qf.dtype, qf),
-            _sds((bh, lp, d), qf.dtype, qf),
+            _sds((bh, lp, dv_), qf.dtype, qf),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv_), jnp.float32)],
         name="flash_bwd_dkv",
         interpret=not on_tpu(),
     )(*common_in, *rope_ops_k)
@@ -950,7 +961,7 @@ def _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal, block_q,
                              rope_mode=rope_mode, block_q=block_q,
                              block_k=block_k, num_heads=h,
                              resident=resident)
-    out = _unprep(of, b, l, h, d, layout)
+    out = _unprep(of, b, l, h, layout)
     return ((out, _lse_public(lse, b, l, h)) if with_lse else out,
             (qf, kf, vf, of, lse, bias_p, cos_t, sin_t))
 
@@ -1005,9 +1016,9 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, has_bias, rope_mode,
     # exact (ds^T @ q_scaled), dq needs the one deferred scale.  On the
     # rope path the kernels already inverse-rotated at emit, so dq/dk
     # are w.r.t. the unrotated inputs here.
-    dq = _unprep(dqf, b, l, h, d, layout) * jnp.asarray(scale, dqf.dtype)
-    dk = _unprep(dkf, b, l, h, d, layout)
-    dv = _unprep(dvf, b, l, h, d, layout)
+    dq = _unprep(dqf, b, l, h, layout) * jnp.asarray(scale, dqf.dtype)
+    dk = _unprep(dkf, b, l, h, layout)
+    dv = _unprep(dvf, b, l, h, layout)
     # The rope tables are position functions (int positions carry no
     # gradient); their zero cotangents DCE under jit.
     return (dq, dk, dv, _zeros_typed_like((b, l), bias_p),
@@ -1093,19 +1104,22 @@ class _Geometry(NamedTuple):
 
 
 def _resident_bytes(lp: int, d: int, itemsize: int, rope: bool,
-                    chunk: int) -> int:
+                    chunk: int, d_v: "int | None" = None) -> int:
     """VMEM the resident backward, the larger of the two kernels, needs
     for a head of ``lp`` rows scored ``chunk`` rows at a time: the
     head's operands, results and scratch once each (VMEM tiles are 128
     lanes wide whatever ``d`` is) and one and a half fp32 score tiles
     of the widest pair.  Fitted to the compiler's own accounting: for
     heads too long to fit, Mosaic's refusals name 16.4 to 22.8 MB at
-    3072 rows in bf16 where this gives 16.4 to 22.7 (PERF.md, PR 26)."""
+    3072 rows in bf16 where this gives 16.4 to 22.7 (PERF.md, PR 26).
+    ``d_v`` is the width of v, o and their cotangents where it is not
+    ``d``."""
     row = lp * _ceil_to(d, _LANES)
-    rows = (5 + 3) * row * itemsize            # q k v do o; dq dk dv
+    vrow = lp * _ceil_to(d_v or d, _LANES)
+    rows = (4 * row + 4 * vrow) * itemsize     # q k dq dk; v do o dv
     stats = 2 * lp * _STATS_W * 4              # lse and its cotangent
     tables = 2 * row * (2 if itemsize == 2 else 4) if rope else 0
-    scratch = 2 * row * 4 + (row * itemsize if rope else 0)
+    scratch = (row + vrow) * 4 + (row * itemsize if rope else 0)
     return rows + stats + tables + scratch + 3 * chunk * lp * 4 // 2
 
 
@@ -1120,7 +1134,7 @@ def _grid_block(l: int, itemsize: int, rope: bool) -> int:
 
 
 def _geometry(l: int, d: int, itemsize: int, causal: bool, rope: bool,
-              has_bias: bool) -> _Geometry:
+              has_bias: bool, d_v: "int | None" = None) -> _Geometry:
     """The geometry of a call with no explicit blocks, from what the
     call can see.  Resident when the mask is causal (nothing above the
     diagonal is then fetched, scored or masked), there is no key bias
@@ -1132,8 +1146,8 @@ def _geometry(l: int, d: int, itemsize: int, causal: bool, rope: bool,
     if causal and not has_bias:
         fits = [c for c in (min(c, _ceil_to(l, _LANES))
                             for c in _RESIDENT_CHUNKS)
-                if _resident_bytes(_ceil_to(l, c), d, itemsize, rope, c)
-                <= _RESIDENT_VMEM_BYTES]
+                if _resident_bytes(_ceil_to(l, c), d, itemsize, rope, c,
+                                   d_v) <= _RESIDENT_VMEM_BYTES]
         if fits:
             return _Geometry(True, min(fits, key=lambda c: (_ceil_to(l, c),
                                                              -c)))
@@ -1158,6 +1172,11 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
                     block_q=None, block_k=None, return_lse=False,
                     layout="blhd", rope=None):
     """Blockwise exact attention, ``(B, L, H, D)`` convention.
+
+    ``v`` may have a head width of its own, ``(B, L, H, Dv)`` (latent
+    attention scores at 192 and sums values at 128): the output and
+    ``dv`` are then ``Dv`` wide, and every kernel streams v, o and do
+    at that width, with no padding to ``D``.
 
     ``layout="bhld"`` instead takes/returns ``(B, H, L, D)`` — the
     transpose-free fast path for models whose projections emit
@@ -1206,6 +1225,9 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
     seq_ax = 2 if layout == "bhld" else 1
     b, l = q.shape[0], q.shape[seq_ax]
     d_head = q.shape[-1]
+    if k.shape[-1] != d_head:
+        raise ValueError(f"q and k score against each other and share "
+                         f"one head width, got {d_head} and {k.shape[-1]}")
     if rope is not None and k.shape[seq_ax] != l:
         raise ValueError("rope requires self-attention (Lq == Lk): q and "
                          "k share one position table")
@@ -1233,7 +1255,7 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
     explicit = (block_q, block_k)
     itemsize = jnp.dtype(q.dtype).itemsize
     geo = _geometry(l, d_head, itemsize, bool(causal), rope is not None,
-                    kv_mask is not None)
+                    kv_mask is not None, v.shape[-1])
     # Explicit blocks are the caller's blocks: the grid walks them.
     resident = geo.resident and explicit == (None, None)
     default = (geo.block if resident

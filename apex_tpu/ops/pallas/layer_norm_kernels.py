@@ -96,14 +96,23 @@ def _bwd_vmem_limit(n2: int, itemsize: int) -> int:
 
 
 def _fwd_kernel(x_ref, w_ref, b_ref, y_ref, mean_ref, inv_ref, *, eps,
-                affine):
+                affine, rms=False):
     x = x_ref[...].astype(jnp.float32)
-    mean = x.mean(axis=1, keepdims=True)
-    xc = x - mean
+    if rms:
+        # RMS mode (upstream FusedRMSNorm on the LayerNorm kernels): no
+        # centring, no bias; the saved mean is nought, so the backward's
+        # ``xhat`` is ``x * inv`` by the same expression.
+        mean = jnp.zeros((x.shape[0], 1), jnp.float32)
+        xc = x
+    else:
+        mean = x.mean(axis=1, keepdims=True)
+        xc = x - mean
     var = (xc * xc).mean(axis=1, keepdims=True)
     inv = jax.lax.rsqrt(var + eps)
     y = xc * inv
-    if affine:
+    if affine and rms:
+        y = y * w_ref[...].astype(jnp.float32)
+    elif affine:
         y = y * w_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
     y_ref[...] = y.astype(y_ref.dtype)
     mean_ref[...] = mean
@@ -111,7 +120,7 @@ def _fwd_kernel(x_ref, w_ref, b_ref, y_ref, mean_ref, inv_ref, *, eps,
 
 
 def _bwd_kernel(dy_ref, x_ref, w_ref, mean_ref, inv_ref,
-                dx_ref, dw_ref, db_ref, *, affine):
+                dx_ref, dw_ref, db_ref, *, affine, rms=False):
     i = pl.program_id(0)
     dy = dy_ref[...].astype(jnp.float32)
     x = x_ref[...].astype(jnp.float32)
@@ -123,9 +132,14 @@ def _bwd_kernel(dy_ref, x_ref, w_ref, mean_ref, inv_ref,
     else:
         wdy = dy
     # grad_input (cuComputeGradInput): dx = inv*(wdy - mean(wdy) - xhat*mean(wdy*xhat))
-    m1 = wdy.mean(axis=1, keepdims=True)
-    m2 = (wdy * xhat).mean(axis=1, keepdims=True)
-    dx_ref[...] = (inv * (wdy - m1 - xhat * m2)).astype(dx_ref.dtype)
+    if rms:
+        # no mean was taken, so none comes back: dx = inv*(wdy - xhat*m2)
+        m2 = (wdy * xhat).mean(axis=1, keepdims=True)
+        dx_ref[...] = (inv * (wdy - xhat * m2)).astype(dx_ref.dtype)
+    else:
+        m1 = wdy.mean(axis=1, keepdims=True)
+        m2 = (wdy * xhat).mean(axis=1, keepdims=True)
+        dx_ref[...] = (inv * (wdy - m1 - xhat * m2)).astype(dx_ref.dtype)
     # γ/β partials accumulated across the sequential grid.
     part_dw = (dy * xhat).sum(axis=0, keepdims=True)
     part_db = dy.sum(axis=0, keepdims=True)
@@ -147,16 +161,16 @@ def _pad_rows(x: jax.Array, rows: int) -> jax.Array:
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("eps", "affine", "block_rows"))
+                   static_argnames=("eps", "affine", "block_rows", "rms"))
 def _forward(x2d, w, b, eps: float, affine: bool,
-             block_rows: "int | None" = None):
+             block_rows: "int | None" = None, rms: bool = False):
     n1, n2 = x2d.shape
     br = fwd_block_rows(n1, n2, x2d.dtype, block_rows)
     grid = -(-n1 // br)   # ragged tail rides the masked last block
     w2 = (w if w is not None else jnp.ones((n2,), jnp.float32)).reshape(1, n2)
     b2 = (b if b is not None else jnp.zeros((n2,), jnp.float32)).reshape(1, n2)
     y, mean, inv = pl.pallas_call(
-        functools.partial(_fwd_kernel, eps=eps, affine=affine),
+        functools.partial(_fwd_kernel, eps=eps, affine=affine, rms=rms),
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((br, n2), lambda i: (i, 0)),
@@ -181,8 +195,8 @@ def _forward(x2d, w, b, eps: float, affine: bool,
     return y, mean, inv
 
 
-@functools.partial(jax.jit, static_argnames=("affine",))
-def _backward(dy, x2d, w, mean, inv, affine: bool):
+@functools.partial(jax.jit, static_argnames=("affine", "rms"))
+def _backward(dy, x2d, w, mean, inv, affine: bool, rms: bool = False):
     n1, n2 = x2d.shape
     dyp = _pad_rows(dy, n1)
     xp = _pad_rows(x2d, n1)
@@ -194,7 +208,7 @@ def _backward(dy, x2d, w, mean, inv, affine: bool):
     grid = rows // _BLOCK_ROWS
     w2 = (w if w is not None else jnp.ones((n2,), jnp.float32)).reshape(1, n2)
     dx, dw, db = pl.pallas_call(
-        functools.partial(_bwd_kernel, affine=affine),
+        functools.partial(_bwd_kernel, affine=affine, rms=rms),
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((_BLOCK_ROWS, n2), lambda i: (i, 0)),
@@ -268,9 +282,39 @@ def _ln_plain_bwd(eps, res, dy):
 _ln_plain.defvjp(_ln_plain_fwd, _ln_plain_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rms_affine(x2d, w, eps):
+    y, _, _ = _forward(x2d, w, None, eps, affine=True, rms=True)
+    return y
+
+
+def _rms_affine_fwd(x2d, w, eps):
+    y, mean, inv = _forward(x2d, w, None, eps, affine=True, rms=True)
+    return y, (x2d, w, mean, inv)
+
+
+def _rms_affine_bwd(eps, res, dy):
+    x2d, w, mean, inv = res
+    dx, dw, _ = _backward(dy, x2d, w, mean, inv, affine=True, rms=True)
+    rows_only = tuple(jax.typeof(dw).vma - jax.typeof(w).vma)
+    if rows_only:
+        dw = jax.lax.psum(dw, rows_only)
+    return dx, dw.astype(w.dtype)
+
+
+_rms_affine.defvjp(_rms_affine_fwd, _rms_affine_bwd)
+
+
 def layer_norm_fwd_vjp(x2d: jax.Array, w: Optional[jax.Array],
                        b: Optional[jax.Array], eps: float) -> jax.Array:
     """Differentiable fused layer norm on a (n1, n2) view."""
     if w is not None:
         return _ln_affine(x2d, w, b, eps)
     return _ln_plain(x2d, eps)
+
+
+def rms_norm_fwd_vjp(x2d: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """Differentiable fused RMS norm on a (n1, n2) view: the LayerNorm
+    kernels in their ``rms`` mode (no mean, no bias), as upstream keeps
+    ``FusedRMSNorm`` beside ``FusedLayerNorm`` on one set of kernels."""
+    return _rms_affine(x2d, w, eps)

@@ -161,3 +161,45 @@ def rope_kernel_tables(cos: jax.Array, sin: jax.Array, b: int, l: int,
     sin_signed = jnp.concatenate([-sin, sin], axis=-1)
     return KernelRopeTables(cos_full.astype(dtype),
                             sin_signed.astype(dtype))
+
+
+def rope_tables_interleaved(positions: jax.Array, rotary_dim: int,
+                            theta: float) -> tuple:
+    """(cos, sin) ``(B, L, 1, rotary_dim)`` for :func:`apply_rope_interleaved`:
+    frequency ``i`` of ``rotary_dim // 2`` sits on lanes ``2i`` and
+    ``2i + 1``, the pair it turns (``rope_interleave`` of the DeepSeek-V3
+    family, GPT-J's layout)."""
+    cos, sin = rope_tables(positions, rotary_dim, theta)
+    return jnp.repeat(cos, 2, axis=-1), jnp.repeat(sin, 2, axis=-1)
+
+
+def _interleaved_rot_matrix(d: int, rotary_dim: int) -> jax.Array:
+    """Constant (D, D) matrix: ``(x @ R)[2i] == -x[2i + 1]`` and
+    ``(x @ R)[2i + 1] == x[2i]`` on the trailing ``rotary_dim`` lanes,
+    nought on the leading ones."""
+    even = d - rotary_dim + 2 * jnp.arange(rotary_dim // 2)
+    r = jnp.zeros((d, d), jnp.float32)
+    r = r.at[even + 1, even].set(-1.0)
+    return r.at[even, even + 1].set(1.0)
+
+
+def apply_rope_interleaved(x: jax.Array, cos: jax.Array,
+                           sin: jax.Array) -> jax.Array:
+    """Partial rotary over interleaved pairs: the trailing
+    ``cos.shape[-1]`` lanes of ``x`` ``(B, L, H, D)`` turn, pair
+    ``(2i, 2i + 1)`` by frequency ``i``; the leading lanes pass
+    unchanged (latent attention turns 64 of a head's 192).  Tables from
+    :func:`rope_tables_interleaved`.
+
+    Spelled like :func:`apply_rope_mxu`: the pair swap is a product with
+    a constant 0/±1 matrix, so the head is neither split nor
+    re-assembled and the layout stays as the projection left it; the
+    tables are padded with ones and noughts over the lanes that pass."""
+    d, rot = x.shape[-1], cos.shape[-1]
+    lead = [(0, 0)] * (cos.ndim - 1) + [(d - rot, 0)]
+    cos = jnp.pad(cos, lead, constant_values=1.0)
+    sin = jnp.pad(sin, lead)
+    xr = jnp.matmul(x, _interleaved_rot_matrix(d, rot).astype(x.dtype),
+                    precision="highest")
+    out = x.astype(jnp.float32) * cos + xr.astype(jnp.float32) * sin
+    return out.astype(x.dtype)

@@ -7,7 +7,13 @@ Reference surface (``apex/parallel/__init__.py``): ``DistributedDataParallel``,
 
 from apex_tpu.optimizers.larc import LARC, larc
 from apex_tpu.parallel import mesh, multiproc
-from apex_tpu.parallel.moe import moe_apply, top1_routing
+from apex_tpu.parallel.moe import (
+    gated_ffn,
+    grouped_matmul,
+    load_balance_loss,
+    moe_apply,
+    route,
+)
 from apex_tpu.parallel.pipeline import (
     pipeline_apply,
     stack_stage_params,
@@ -56,7 +62,7 @@ __all__ = [
     "all_reduce", "all_gather", "broadcast", "reduce_gradients",
     "pvary_params",
     "pipeline_apply", "stack_stage_params",
-    "moe_apply", "top1_routing",
+    "moe_apply", "route", "load_balance_loss", "grouped_matmul", "gated_ffn",
     "SyncBatchNorm", "BatchNorm", "convert_syncbn_model",
     "create_syncbn_process_group",
     "welford_mean_var", "welford_parallel", "batchnorm_forward",

@@ -48,6 +48,20 @@ import jax
 MLP, LM_LOSS, PRETRAINING_LOSS = MODEL_SCOPES = (
     "mlp", "lm_loss", "pretraining_loss")
 
+#: The scopes of the DeepSeek-V3-shaped decoder
+#: (:mod:`apex_tpu.models.deepseek_v3`) and of the expert layer it runs
+#: (:mod:`apex_tpu.parallel.moe`, which opens the first three): the
+#: router (its product, the scores, the choice and the weights); the
+#: sort of (token, expert) pairs, the gathers into expert order and back
+#: and the weighted sum; the grouped products of the experts held; the
+#: shared experts; and latent attention's projections with the latent
+#: norm, the rotary and the assembly of q and k.  ``moe_experts`` and
+#: ``moe_shared`` lie inside ``mlp``; ``mla_project`` inside the
+#: ``attention`` module.
+(MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_SHARED,
+ MLA_PROJECT) = MOE_SCOPES = (
+    "moe_route", "moe_dispatch", "moe_experts", "moe_shared", "mla_project")
+
 
 @contextlib.contextmanager
 def nvtx_range(name: str):

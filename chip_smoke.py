@@ -16,7 +16,10 @@ of 128) with weights made from a seed:
    ``generate()`` on the same prompt and weights.
 3. *kernels*: every production Pallas family is compiled by Mosaic at a
    production-sized shape and compared with its jnp reference within
-   the unit tests' tolerances.
+   the unit tests' tolerances; among them the families of the
+   DeepSeek-V3-shaped block: the LayerNorm kernels in their RMS mode,
+   flash attention at 192/128, the held-experts layer on jax's megablox
+   kernels, and one tiny block whole.
 
 ``--devices 4`` runs phase 1 alone, data-parallel under ``shard_map``
 over a ``("data",)`` mesh of four chips at batch 8 per chip.
@@ -427,6 +430,8 @@ def kernel_cases(shape: Shape):
                                           per_tensor=True),
            (fp32,), 1e-5, 0.0, {})
 
+    yield from latent_moe_cases(shape, normal, uniform)
+
     for features in shape.ln_features:
         for dtype, rtol, atol in ((jnp.bfloat16, 2e-2, 5e-2),
                                   (jnp.float32, 1e-3, 1e-4)):
@@ -443,6 +448,82 @@ def kernel_cases(shape: Shape):
             yield (f"layer_norm_{features}_{jnp.dtype(dtype).name}",
                    {"layer_norm_fwd", "layer_norm_bwd"}, ln, (x, w, b, dy),
                    rtol, atol, {})
+
+
+def latent_moe_cases(shape: Shape, normal, uniform):
+    """The Pallas families of the DeepSeek-V3-shaped block
+    (``apex_tpu.models.deepseek_v3``), each forward and backward: the
+    LayerNorm kernels in their RMS mode, flash attention with q, k at
+    192 lanes and v at 128, the held-experts layer on jax's megablox
+    kernels under a fixed routing, and one tiny block whole (its loss
+    and the norm of its gradient: a token that changes expert between
+    the two kernel selections moves single entries, not these)."""
+    import jax
+    import jax.numpy as jnp
+
+    from apex_tpu.models.deepseek_v3 import (DeepseekV3Block,
+                                             DeepseekV3Config)
+    from apex_tpu.normalization import fused_rms_norm_affine
+    from apex_tpu.attention import attention
+    from apex_tpu.ops.rope import rope_tables_interleaved
+    from apex_tpu.parallel import moe
+
+    rows, seq = shape.ln_rows, shape.seq
+    features = shape.ln_features[-1]
+    x, dy = (normal(rows, features, dtype=jnp.bfloat16) for _ in range(2))
+
+    def rms(x, w, dy):
+        y, vjp = jax.vjp(lambda x, w: fused_rms_norm_affine(
+            x, w, features, 1e-6), x, w)
+        return (y,) + vjp(dy)
+    yield (f"rms_norm_{features}_bfloat16",
+           {"layer_norm_fwd", "layer_norm_bwd"}, rms,
+           (x, 1.0 + 0.1 * normal(features), dy), 2e-2, 5e-2, {})
+
+    q, k = (normal(2, seq, 4, 192, dtype=jnp.bfloat16) for _ in range(2))
+    v, do = (normal(2, seq, 4, 128, dtype=jnp.bfloat16) for _ in range(2))
+
+    def flash(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: attention(
+            q, k, v, causal=True, block_q=min(512, seq),
+            block_k=min(512, seq)), q, k, v)
+        return (out,) + vjp(do)
+    yield ("flash_192_128", {"flash_fwd", "flash_bwd_fused"}, flash,
+           (q, k, v, do), 3e-2, 5e-2, {})
+
+    tokens, d, f, experts, held = 2 * seq, 256, 128, 8, 4
+    p = {"gate": 0.1 * normal(held, d, f), "up": 0.1 * normal(held, d, f),
+         "down": 0.1 * normal(held, f, d)}
+    routing = moe.route(normal(tokens, experts), 2, scoring="sigmoid",
+                        renormalize=True)
+    xt, dyt = normal(tokens, d), normal(tokens, d)
+
+    def held_experts(p, x, weights, dy):
+        y, vjp = jax.vjp(lambda p, x, w: moe.moe_apply(
+            moe.gated_ffn, p, x, routing._replace(weights=w),
+            n_experts=experts, first=2)[0], p, x, weights)
+        return (y,) + vjp(dy)
+    yield ("held_experts", {"gmm", "tgmm"}, held_experts,
+           (p, xt, routing.weights, dyt), 2e-2, 2e-2, {})
+
+    c = DeepseekV3Config(
+        hidden_size=256, num_heads=2, moe_intermediate_size=128,
+        n_routed_experts=experts, n_routed_experts_held=held,
+        num_experts_per_tok=2, kv_lora_rank=128)
+    block = DeepseekV3Block(c, dense=False)
+    xb = normal(1, seq, 256)
+    rope = rope_tables_interleaved(jnp.arange(seq)[None], c.qk_rope_head_dim,
+                                   c.rope_theta)
+    params = block.init(jax.random.PRNGKey(SEED), xb, rope)["params"]
+
+    def one_block(params, x):
+        loss, grads = jax.value_and_grad(lambda p: jnp.mean(jnp.square(
+            block.apply({"params": p}, x, rope)[0])))(params)
+        return loss, jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                                  for g in jax.tree.leaves(grads)))
+    yield ("deepseek_v3_block",
+           {"layer_norm_fwd", "layer_norm_bwd", "flash_fwd", "gmm", "tgmm"},
+           one_block, (params, xb), 3e-2, 1e-6, {})
 
 
 def phase_kernels(shape: Shape, dry: bool):

@@ -8,8 +8,10 @@ parallelism — SURVEY.md §2 "NOT present"): this example trains with
   ``ppermute`` inside one ``lax.scan`` schedule, and the backward pipeline
   falls out of autodiff;
 - ``--mode ep``: a switch top-1 MoE FFN — experts sharded over the mesh,
-  tokens routed through capacity-bounded dispatch/combine einsums around a
-  pair of ``all_to_all`` exchanges, with the load-balancing aux loss.
+  each token's row sorted to the rank that holds its expert and back by
+  a pair of ``all_to_all`` exchanges (no capacity, no dropped token), the
+  experts run as grouped matrix products, with the load-balancing aux
+  loss.
 
 Both run under amp O2 (bf16 compute, fp32 masters, dynamic loss scaling)
 with ``finite_axes`` keeping the overflow-skip decision globally
@@ -94,7 +96,8 @@ def main():
             return getattr(leaf, "ndim", 0) >= 1   # all params stage-stacked
         data_spec = P()
     else:
-        from apex_tpu.parallel import moe_apply
+        from apex_tpu.parallel import (grouped_matmul, load_balance_loss,
+                                       moe_apply, route)
         mesh = Mesh(devices, ("expert",))
         e_local, hidden = 2, 4 * d
         E = n * e_local
@@ -109,9 +112,15 @@ def main():
         axis = "expert"
 
         def loss_fn(p, xb):
-            def ffn(ep, h):
-                return jax.nn.gelu(h @ ep["wi"]) @ ep["wo"]
-            y, aux = moe_apply(ffn, p["experts"], p["router"], xb, "expert")
+            def ffn(ep, rows, group_sizes):
+                h = jax.nn.gelu(grouped_matmul(
+                    rows, ep["wi"].astype(rows.dtype), group_sizes))
+                return grouped_matmul(h, ep["wo"].astype(rows.dtype),
+                                      group_sizes)
+            routing = route(xb @ p["router"].astype(xb.dtype))
+            y, _ = moe_apply(ffn, p["experts"], xb, routing, n_experts=E,
+                             axis_name="expert")
+            aux = jax.lax.pmean(load_balance_loss(routing), "expert")
             y = xb + y
             # target shard for this rank's tokens
             i = jax.lax.axis_index("expert")

@@ -12,7 +12,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 
 from apex_tpu.parallel.pipeline import pipeline_apply, stack_stage_params
-from apex_tpu.parallel.moe import moe_apply, top1_routing
+from apex_tpu.parallel.moe import (grouped_matmul, load_balance_loss,
+                                   moe_apply, route)
 
 D = 8
 
@@ -93,13 +94,28 @@ def expert_fn(p, x):
     return jax.nn.gelu(x @ p["wi"]) @ p["wo"]
 
 
+def grouped_expert_fn(p, rows, group_sizes):
+    """``expert_fn`` over rows sorted by expert, as ``moe_apply`` calls
+    it."""
+    h = jax.nn.gelu(grouped_matmul(rows, p["wi"], group_sizes))
+    return grouped_matmul(h, p["wo"], group_sizes)
+
+
 def make_experts(key, n, d, hidden=16):
     k1, k2 = jax.random.split(key)
     return {"wi": jax.random.normal(k1, (n, d, hidden)) * 0.3,
             "wo": jax.random.normal(k2, (n, hidden, d)) * 0.3}
 
 
+ROUTERS = {"switch_top1": dict(k=1),
+           "softmax_top2": dict(k=2),
+           "sigmoid_top3_renormalised": dict(k=3, scoring="sigmoid",
+                                             renormalize=True, scale=2.0)}
+
+
 class TestMoE:
+    """Experts and tokens both sharded over the ``expert`` axis: the
+    rows travel to the ranks that hold their experts and back."""
     RANKS, E_LOCAL = 4, 2
 
     def setup_method(self, _):
@@ -110,80 +126,100 @@ class TestMoE:
         self.x = jax.random.normal(jax.random.PRNGKey(2),
                                    (self.RANKS * 32, D))
 
-    def reference_shard(self, x_shard, capacity_factor):
-        """Dense single-device evaluation of one rank's token shard with
-        ALL experts local — what the all_to_all plumbing must reproduce."""
-        t_local, d = x_shard.shape
+    def exchanged(self, router, **opts):
         E = self.RANKS * self.E_LOCAL
-        capacity = max(1, int(capacity_factor * t_local / E))
-        logits = x_shard @ self.router
-        dispatch, combine, aux = top1_routing(logits, capacity)
-        sent = jnp.einsum("tec,td->ecd", dispatch, x_shard)
-        out = jax.vmap(expert_fn)(self.experts, sent)
-        y = jnp.einsum("tec,ecd->td", combine, out)
-        return y, aux
 
-    def test_no_drop_matches_per_token_reference(self):
-        """Independent semantics check (no shared routing code): with
-        capacity ample, y[t] == router_prob[t] * expert_fn(params[e_t], x[t])
-        for every token."""
-        mesh = _mesh(self.RANKS, "expert")
-        f = shard_map(
-            lambda ep, rw, x: moe_apply(expert_fn, ep, rw, x, "expert",
-                                        capacity_factor=8.0),
-            mesh=mesh, in_specs=(P("expert"), P(), P("expert")),
-            out_specs=(P("expert"), P()))
-        y, _ = jax.jit(f)(self.experts, self.router, self.x)
+        def layer(ep, rw, x):
+            r = route(x @ rw, **opts)
+            y, stats = moe_apply(grouped_expert_fn, ep, x, r, n_experts=E,
+                                 axis_name="expert")
+            aux = jax.lax.pmean(load_balance_loss(r), "expert")
+            return y, aux, jax.lax.psum(stats["pairs"], "expert")
+
+        f = shard_map(layer, mesh=_mesh(self.RANKS, "expert"),
+                      in_specs=(P("expert"), P(), P("expert")),
+                      out_specs=(P("expert"), P(), P()))
+        return jax.jit(f)(self.experts, router, self.x)
+
+    @pytest.mark.parametrize("name", sorted(ROUTERS))
+    def test_matches_per_token_reference(self, name):
+        """Independent semantics check (no shared routing code): y[t] is
+        the sum over token t's chosen experts of weight * expert_fn(x[t]),
+        for every token, whichever rank holds the expert."""
+        opts = dict(ROUTERS[name])
+        y, _, pairs = self.exchanged(self.router, **opts)
+        assert int(pairs) == self.x.shape[0] * opts["k"]
         logits = self.x @ self.router
-        probs = jax.nn.softmax(logits, axis=-1)
+        scores = (jax.nn.sigmoid(logits) if opts.get("scoring") == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
         for t in range(0, self.x.shape[0], 7):
-            e = int(jnp.argmax(logits[t]))
-            one = jax.tree.map(lambda l: l[e], self.experts)
-            ref = float(probs[t, e]) * expert_fn(one, self.x[t][None, :])[0]
+            ids = np.argsort(-np.asarray(scores[t]),
+                             kind="stable")[:opts["k"]]
+            w = np.asarray(scores[t])[ids]
+            if opts.get("renormalize"):
+                w = w / w.sum()
+            w = w * opts.get("scale", 1.0)
+            ref = sum(float(w[j]) * expert_fn(
+                jax.tree.map(lambda l: l[int(e)], self.experts),
+                self.x[t][None, :])[0] for j, e in enumerate(ids))
             np.testing.assert_allclose(np.asarray(y[t]), np.asarray(ref),
                                        rtol=1e-4, atol=1e-5)
 
-    @pytest.mark.parametrize("capacity_factor", [8.0, 1.0])
-    def test_matches_dense_reference(self, capacity_factor):
-        # cf=8 -> nothing dropped; cf=1 -> capacity drops exercised
-        mesh = _mesh(self.RANKS, "expert")
-        f = shard_map(
-            lambda ep, rw, x: moe_apply(expert_fn, ep, rw, x, "expert",
-                                        capacity_factor=capacity_factor),
-            mesh=mesh, in_specs=(P("expert"), P(), P("expert")),
-            out_specs=(P("expert"), P()))
-        y, aux = jax.jit(f)(self.experts, self.router, self.x)
-
-        shards = self.x.reshape(self.RANKS, -1, D)
-        refs = [self.reference_shard(s, capacity_factor) for s in shards]
-        ref_y = jnp.concatenate([r[0] for r in refs])
-        ref_aux = jnp.mean(jnp.stack([r[1] for r in refs]))
-        np.testing.assert_allclose(np.asarray(y), np.asarray(ref_y),
-                                   rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(float(aux), float(ref_aux), rtol=1e-5)
+    @pytest.mark.parametrize("skew", ["as_drawn", "all_on_one_rank",
+                                      "all_on_one_expert"])
+    def test_matches_the_layer_on_one_device(self, skew):
+        """The exchange reproduces the layer that holds every expert
+        itself, shard of tokens by shard, however uneven the routing: a
+        capacity would drop tokens under the two skews, here none is."""
+        router = self.router
+        if skew == "all_on_one_rank":      # experts 2 and 3, rank 1
+            router = router.at[:, 2:4].add(50.0)
+        if skew == "all_on_one_expert":
+            router = router.at[:, 5].add(50.0)
+        y, aux, pairs = self.exchanged(router, k=2)
+        assert int(pairs) == self.x.shape[0] * 2
+        E = self.RANKS * self.E_LOCAL
+        refs = []
+        for shard in self.x.reshape(self.RANKS, -1, D):
+            r = route(shard @ router, k=2)
+            refs.append((moe_apply(grouped_expert_fn, self.experts, shard,
+                                   r, n_experts=E)[0],
+                         load_balance_loss(r)))
+        np.testing.assert_allclose(
+            np.asarray(y), np.asarray(jnp.concatenate([r[0] for r in refs])),
+            rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(
+            float(aux), float(jnp.mean(jnp.stack([r[1] for r in refs]))),
+            rtol=1e-5)
+        if skew == "all_on_one_expert":
+            assert float(jnp.abs(y).sum(-1).min()) > 0   # no zero row
 
     def test_gradients_flow_to_all_experts(self):
         mesh = _mesh(self.RANKS, "expert")
+        E = self.RANKS * self.E_LOCAL
 
         def loss(ep, rw, x):
-            f = shard_map(
-                lambda ep, rw, x: moe_apply(expert_fn, ep, rw, x, "expert",
-                                            capacity_factor=8.0),
-                mesh=mesh, in_specs=(P("expert"), P(), P("expert")),
-                out_specs=(P("expert"), P()))
+            def layer(ep, rw, x):
+                r = route(x @ rw)
+                y, _ = moe_apply(grouped_expert_fn, ep, x, r, n_experts=E,
+                                 axis_name="expert")
+                return y, jax.lax.pmean(load_balance_loss(r), "expert")
+            f = shard_map(layer, mesh=mesh,
+                          in_specs=(P("expert"), P(), P("expert")),
+                          out_specs=(P("expert"), P()))
             y, aux = f(ep, rw, x)
             return jnp.mean(y ** 2) + 0.01 * aux
 
-        g = jax.jit(jax.grad(loss))(self.experts, self.router, self.x)
+        g = jax.jit(jax.grad(loss, argnums=(0, 1)))(self.experts,
+                                                    self.router, self.x)
         for leaf in jax.tree.leaves(g):
             assert bool(jnp.isfinite(leaf).all())
-        # every expert receives tokens under this router (checked above),
-        # so every expert's weights must receive gradient
+        # every expert receives tokens under this router, so every
+        # expert's weights must receive gradient, and so must the router
         per_expert = jnp.asarray(
-            [float(jnp.abs(g["wi"][e]).max())
-             for e in range(self.RANKS * self.E_LOCAL)])
-        assert int((per_expert > 0).sum()) == self.RANKS * self.E_LOCAL, \
-            per_expert
+            [float(jnp.abs(g[0]["wi"][e]).max()) for e in range(E)])
+        assert int((per_expert > 0).sum()) == E, per_expert
+        assert float(jnp.abs(g[1]).max()) > 0
 
 
 class TestShardedOverflowSkip:

@@ -41,7 +41,7 @@ BASE = {
     "pipeline": {"collective-permute": {"count": 2, "bytes": 256},
                  "all-reduce": {"count": 3, "bytes": 1032}},
     "expert": {"all-reduce": {"count": 4, "bytes": 528},
-               "all-to-all": {"count": 3, "bytes": 3072}},
+               "all-to-all": {"count": 4, "bytes": 12800}},
     "fsdp": {"all-gather": {"count": 1, "bytes": 1024},
              "all-reduce": {"count": 2, "bytes": 1026}},
     "dp_tp_sp_3d": {"collective-permute": {"count": 5, "bytes": 4112},
@@ -54,7 +54,6 @@ CONST_KINDS = [
     ("dp_sp_ring", "all-reduce"),
     ("dp_tp_pjit", "all-reduce"),
     ("pipeline", "collective-permute"),
-    ("expert", "all-to-all"),   # capacity C=1 at both n=8 and n=16
     ("dp_tp_sp_3d", "collective-permute"),
     ("dp_tp_sp_3d", "all-reduce"),
 ]
@@ -65,14 +64,14 @@ def _records(n, *, mutate=None):
     for name, coll in BASE.items():
         c = {k: dict(v) for k, v in coll.items()}
         # the statically-growing laws: fsdp's compute all-gather
-        # (linear in params) and the expert all-to-all capacity formula
-        # (constant until C floors at 1, then linear — the cliff)
+        # (linear in params) and the expert all-to-all's slot of room
+        # rows for every rank (linear in the ranks)
         if name == "fsdp":
             c["all-gather"]["bytes"] = 1024 * n // 8
             c["all-reduce"]["bytes"] = 1026 * n // 8
         if name == "expert":
             c["all-to-all"]["bytes"] = int(
-                3072 * expert_alltoall_scale(n) / expert_alltoall_scale(8))
+                12800 * expert_alltoall_scale(n) / expert_alltoall_scale(8))
         recs[name] = {"name": name, "ok": True, "collectives": c, "n": n}
     if mutate:
         mutate(recs)
@@ -124,22 +123,22 @@ def test_failed_slice_fails_its_laws():
     assert bad, "failed slice record passed its law"
 
 
-def test_expert_capacity_cliff_formula():
-    # E_global*C: C=2 at n=8, floors at 1 from n=16 -> const then linear
-    assert expert_alltoall_scale(8) == 32.0    # 16 experts x C=2
-    assert expert_alltoall_scale(16) == 32.0   # 32 experts x C=1
-    assert expert_alltoall_scale(32) == 64.0
-    assert expert_alltoall_scale(64) == 128.0
-    # the REAL sweep numbers: 3072, 3072, 6144, 12288 bytes
-    # (SCALING_SWEEP.json) — a dispatch layout that silently doubled
-    # pre-cliff volume would violate the formula and fail the law
+def test_expert_exchange_room_formula():
+    # ranks * room, room = T_local * min(k, e_local) = 16: linear in n
+    assert expert_alltoall_scale(8) == 128.0
+    assert expert_alltoall_scale(16) == 256.0
+    assert expert_alltoall_scale(32) == 512.0
+    assert expert_alltoall_scale(64) == 1024.0
+    # the sweep's own numbers at worlds 8 and 16: 12800 and 25600 bytes
+    # (rows and their expert ids out, results back) — a layout that
+    # silently doubled the volume at one world would fail the law
     def wrong(recs):
         recs["expert"]["collectives"]["all-to-all"]["bytes"] *= 2
 
     laws = check_laws(_by_n(mutate_at=16, mutate=wrong))
     bad = [lw for lw in laws
            if lw["slice"] == "expert" and not lw["ok"]]
-    assert bad, "doubled pre-cliff expert all-to-all not caught"
+    assert bad, "doubled world-16 expert all-to-all not caught"
 
 
 def test_derived_executed_volumes_scale():
@@ -174,5 +173,7 @@ def test_world16_child_matches_const_laws():
         got = recs[name]["collectives"][kind]["bytes"]
         want = BASE[name][kind]["bytes"]
         assert got == want, (name, kind, got, want)
-    # and the linear anchor: fsdp all-gather exactly doubles
+    # and the linear anchors: fsdp all-gather and the expert exchange
+    # exactly double
     assert recs["fsdp"]["collectives"]["all-gather"]["bytes"] == 2048
+    assert recs["expert"]["collectives"]["all-to-all"]["bytes"] == 25600

@@ -29,7 +29,7 @@ from apex_tpu import analysis                            # noqa: E402
 from apex_tpu.analysis import determinism, detlint       # noqa: E402
 from apex_tpu.models.generate import (                   # noqa: E402
     greedy_argmax, pin_logits)
-from apex_tpu.parallel.moe import top1_routing           # noqa: E402
+from apex_tpu.parallel.moe import route                  # noqa: E402
 
 
 def _findings(fn, *args):
@@ -178,16 +178,19 @@ def test_prng_reuse_quiet_after_split():
 # the MoE router rides the greedy_argmax form (the fixed raw-argmax site)
 # ---------------------------------------------------------------------------
 
-def test_moe_router_lints_clean():
-    logits = jnp.ones((8, 4), jnp.float32)
-    f = _findings(lambda lg: top1_routing(lg, capacity=4)[0], logits)
+@pytest.mark.parametrize("k,scoring", [(1, "softmax"), (2, "softmax"),
+                                       (6, "sigmoid")])
+def test_moe_router_lints_clean(k, scoring):
+    logits = jnp.ones((8, 8), jnp.float32)
+    f = _findings(lambda lg: route(lg, k, scoring=scoring,
+                                   bias=jnp.zeros((8,))).experts, logits)
     assert "det-tie-argmax" not in _error_ids(f)
 
 
 def test_moe_router_raw_argmax_twin_would_fire():
     """The before-image of the fix: the same router with a raw
-    jnp.argmax tie-break trips the rule, so the greedy_argmax swap in
-    top1_routing is load-bearing, not decorative."""
+    jnp.argmax tie-break trips the rule, so the greedy_argmax rounds in
+    route's top-k are load-bearing, not decorative."""
     def raw_router(lg):
         probs = jax.nn.softmax(lg, axis=-1)
         return jnp.argmax(probs, axis=-1)

@@ -669,3 +669,68 @@ def test_resident_backward_types_under_shard_map(monkeypatch):
         assert 'kernel_name = "flash_fwd"' in text
         assert 'kernel_name = "flash_bwd_fused"' in text
         assert "flash_resident" in text and "flash_grid" not in text
+
+
+# ---------------------------------------------------------------------------
+# v of its own width (latent attention scores at 192 and sums at 128)
+# ---------------------------------------------------------------------------
+
+def _qkv_narrow_v(l, d, dv, dtype=jnp.float32, seed=3):
+    rng = np.random.RandomState(seed)
+    mk = lambda w: jnp.asarray(rng.randn(B, l, H, w).astype(np.float32)
+                               ).astype(dtype)
+    return mk(d), mk(d), mk(dv)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("use_mask", [False, True])
+@pytest.mark.parametrize("walk", ["resident_or_default", "grid_fused",
+                                  "grid_two_pass"])
+def test_v_narrower_than_qk_matches_reference(monkeypatch, causal, use_mask,
+                                              walk):
+    """Forward and all three gradients with q, k at 48 lanes and v at
+    32: the output and dv are 32 wide; every backward (resident, fused
+    grid walk, the dq / dkv pair) streams v, o and do at v's width."""
+    kwargs = {}
+    if walk != "resident_or_default":
+        kwargs = dict(block_q=128, block_k=128)
+    if walk == "grid_two_pass":
+        monkeypatch.setenv("APEX_TPU_FLASH_FUSED_BWD_MAX_BYTES", "0")
+    l = 320                           # pads to 384: the key bias path too
+    q, k, v = _qkv_narrow_v(l, 48, 32)
+    mask = None
+    if use_mask:
+        mask = jnp.arange(l)[None, :] < jnp.asarray([[l - 37], [l]])
+    out = flash_attention(q, k, v, causal=causal, kv_mask=mask, **kwargs)
+    assert out.shape == (B, l, H, 32)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref_attn(q, k, v, causal, mask)),
+                               rtol=RTOL, atol=ATOL)
+    _check_grads(q, k, v, causal, mask, **kwargs)
+
+
+def test_v_narrower_in_bf16_and_head_major_layout():
+    q, k, v = _qkv_narrow_v(256, 64, 32, jnp.bfloat16)
+    want = ref_attn(q, k, v, causal=True)
+    got = flash_attention(*(jnp.moveaxis(t, 1, 2) for t in (q, k, v)),
+                          causal=True, layout="bhld")
+    assert got.shape == (B, H, 256, 32) and got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(jnp.moveaxis(got, 1, 2), np.float32),
+        np.asarray(want, np.float32), rtol=3e-2, atol=3e-2)
+
+
+def test_q_and_k_must_share_a_width():
+    q, k, v = _qkv_narrow_v(128, 48, 32)
+    with pytest.raises(ValueError, match="share one head width"):
+        flash_attention(q, v, v)
+
+
+def test_dispatcher_takes_v_of_its_own_width():
+    from apex_tpu.attention import attention
+    q, k, v = _qkv_narrow_v(128, 48, 32)
+    for impl in ("flash", "jnp"):
+        out = attention(q, k, v, causal=True, impl=impl)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(ref_attn(q, k, v, True)),
+                                   rtol=RTOL, atol=ATOL)

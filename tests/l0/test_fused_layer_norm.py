@@ -138,3 +138,83 @@ def test_kernel_backward_types_with_replicated_weight_under_shard_map(
         w, b, x).lower(lowering_platforms=("tpu",)).as_text()
     assert 'kernel_name = "layer_norm_bwd"' in text
     assert "all_reduce" in text
+
+
+# ---------------------------------------------------------------------------
+# FusedRMSNorm: the LayerNorm kernels in their rms mode
+# ---------------------------------------------------------------------------
+
+def ref_rms_norm(x, w, eps):
+    x32 = x.astype(jnp.float32)
+    return (x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+            * w.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("mode", ["jnp", "pallas"])
+@pytest.mark.parametrize("shape", [(16, 32, 256), (8, 100), (3, 130, 128)])
+def test_rms_forward_matches_reference(monkeypatch, mode, shape):
+    from apex_tpu.normalization import fused_rms_norm_affine
+    monkeypatch.setenv("APEX_TPU_KERNELS", mode)
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(*shape).astype(np.float32)) + 0.5
+    w = jnp.asarray(rng.rand(shape[-1]).astype(np.float32))
+    y = fused_rms_norm_affine(x, w, shape[-1], 1e-6)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(ref_rms_norm(x, w, 1e-6)),
+                               rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("mode", ["jnp", "pallas"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rms_gradients_match_reference(monkeypatch, mode, dtype):
+    """dx and the gain's gradient through the kernel's backward in rms
+    mode (no mean comes back) against autodiff of the jnp formula; the
+    mean of x is far from nought so that a centred norm would differ."""
+    from apex_tpu.normalization import fused_rms_norm_affine
+    monkeypatch.setenv("APEX_TPU_KERNELS", mode)
+    rng = np.random.RandomState(1)
+    x = (jnp.asarray(rng.randn(6, 50, 256).astype(np.float32)) + 1.0
+         ).astype(dtype)
+    w = jnp.asarray(1.0 + 0.1 * rng.randn(256).astype(np.float32))
+    t = jnp.asarray(rng.randn(6, 50, 256).astype(np.float32))
+
+    def loss(fn):
+        return lambda x, w: jnp.sum(fn(x, w).astype(jnp.float32) * t)
+
+    got = jax.grad(loss(lambda x, w: fused_rms_norm_affine(x, w, 256, 1e-6)),
+                   argnums=(0, 1))(x, w)
+    want = jax.grad(loss(lambda x, w: ref_rms_norm(x, w, 1e-6).astype(dtype)),
+                    argnums=(0, 1))(x, w)
+    tol = 1e-4 if dtype == jnp.float32 else 3e-2
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol * 10)
+
+
+def test_rms_module_has_one_gain_and_no_bias():
+    from apex_tpu.normalization import FusedRMSNorm
+    m = FusedRMSNorm(128, eps=1e-6)
+    x = jnp.ones((2, 4, 128)) * 3.0
+    params = m.init(jax.random.PRNGKey(0), x)["params"]
+    assert set(params) == {"scale"} and params["scale"].shape == (128,)
+    np.testing.assert_allclose(np.asarray(m.apply({"params": params}, x)),
+                               1.0, rtol=1e-5)
+
+
+def test_rms_is_a_mode_of_the_layer_norm_kernels(monkeypatch):
+    """RMS is a mode of ``layer_norm_fwd`` / ``layer_norm_bwd``, not a
+    kernel of its own: forward and backward name those two kernels and
+    no other."""
+    import re
+    from apex_tpu.normalization import fused_rms_norm_affine
+    monkeypatch.setenv("APEX_TPU_KERNELS", "pallas")
+    x, w = jnp.ones((64, 256)), jnp.ones((256,))
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda x, w: jnp.sum(fused_rms_norm_affine(x, w, 256, 1e-6)),
+        argnums=(0, 1)))(x, w))
+    names = set(re.findall(r"name=(\w+)\n\s+out_avals", text))
+    assert {"layer_norm_fwd", "layer_norm_bwd"} <= set(
+        re.findall(r"layer_norm_\w+", text))
+    assert not {n for n in names if not n.startswith("layer_norm_")}, names

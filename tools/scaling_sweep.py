@@ -28,12 +28,12 @@ volumes obey exact laws:
   constant; executed volume derived as ``static x (M + S - 1)`` per the
   GPipe schedule (M = S microbatches).
 - ``expert`` (experts = 2n, constant per-device tokens): ``all-to-all``
-  bytes follow the capacity formula ``E_global * C * d`` with
-  ``C = max(1, ceil(cf * T_local / E_global))`` — constant while the
-  per-expert capacity is above its floor, then **linear in expert
-  count** once ``C`` hits 1 (here at n >= 16): the capacity-quantization
-  cliff, the reason production MoE scales tokens-per-device with the
-  expert count.  The sweep asserts the formula, cliff included.
+  bytes follow the exchange buffer ``ranks * room * d`` with ``room =
+  T_local * min(k, e_local)``, the most pairs one rank's tokens can send
+  another (no capacity, no dropped token): **linear in the number of
+  ranks** from the start — what a static-shaped exchange that drops
+  nothing pays, and the reason production MoE bounds how many ranks a
+  token may reach.  The sweep asserts the formula.
 - ``fsdp`` (hidden = 16n, constant shard): the compute all-gather
   reconstitutes the FULL parameter, so its bytes grow **linearly with
   n** — ZeRO-3's bandwidth cost — while grad reduction stays constant
@@ -84,15 +84,12 @@ def sweep_topology(n: int) -> dict:
 
 def expert_alltoall_scale(n: int) -> float:
     """Analytic per-device all-to-all buffer volume of the expert slice,
-    up to a constant factor: ``E_global * C`` with the slice's
-    ``T_local=16, e_local=2, capacity_factor=2`` (see
-    ``apex_tpu/parallel/moe.py:84`` and the module docstring's
-    capacity-cliff note)."""
-    import math
-    t_local, e_local, cf = 16, 2, 2.0
-    e_global = e_local * n
-    cap = max(1, math.ceil(cf * t_local / e_global))
-    return float(e_global * cap)
+    up to a constant factor: ``ranks * room`` with the slice's
+    ``T_local=16, e_local=2, k=1`` (``apex_tpu/parallel/moe.py``
+    ``_exchanged``: a slot of ``room = T_local * min(k, e_local)`` rows
+    for every rank)."""
+    t_local, e_local, k = 16, 2, 1
+    return float(n * t_local * min(k, e_local))
 
 
 def child_main(n: int) -> None:
@@ -248,10 +245,9 @@ def check_laws(by_n: dict) -> list:
     law("pipe executed volume ~ 2S-1", "pipeline", "collective-permute",
         lambda n: 2 * n - 1, LINEAR_RTOL,
         derived_fn=lambda n, b: b * (2 * n - 1))
-    # expert parallelism: the capacity formula — constant until the
-    # per-expert capacity floors at 1, then linear in expert count
-    # (the capacity-quantization cliff; module docstring)
-    law("expert all-to-all ~ E*C capacity formula", "expert",
+    # expert parallelism: a slot of room rows for every rank, so the
+    # exchange grows with the ranks (module docstring)
+    law("expert all-to-all ~ ranks * room", "expert",
         "all-to-all", expert_alltoall_scale, LINEAR_RTOL)
     # fsdp: the compute all-gather reconstitutes the FULL (growing)
     # parameter — the one law that is linear in the static audit itself
