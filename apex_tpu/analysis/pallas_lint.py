@@ -139,6 +139,7 @@ class KernelCall:
     aliases: Tuple[Tuple[int, int], ...]   # (input idx, output idx)
     body: Any                       # the kernel body jaxpr
     num_index_operands: int
+    vmem_limit: Optional[int] = None   # the call's own vmem_limit_bytes
 
 
 def _sub_jaxprs(value):
@@ -225,7 +226,8 @@ def describe_call(eqn) -> KernelCall:
         name=name, grid=grid, semantics=sem, operands=operands,
         num_inputs=n_in, num_outputs=int(gm.num_outputs),
         scratch=scratch, aliases=aliases, body=body,
-        num_index_operands=n_idx)
+        num_index_operands=n_idx,
+        vmem_limit=getattr(mosaic, "vmem_limit_bytes", None))
 
 
 def extract_pallas_calls(closed_jaxpr) -> List[KernelCall]:
@@ -619,15 +621,22 @@ def lint_call(call: KernelCall,
             continue
         working += scr.nbytes
         detail.append(f"scratch[{i}] {_fmt_bytes(scr.nbytes)}")
-    ceiling = int(budget_bytes) if budget_bytes is not None \
-        else vmem_ceiling()
+    # A call that asks Mosaic for a scoped limit of its own is held to
+    # what it asked for, not to the default every other call gets.
+    if budget_bytes is not None:
+        ceiling, origin = int(budget_bytes), "the caller's budget"
+    elif call.vmem_limit is not None:
+        ceiling, origin = int(call.vmem_limit), "the call's own " \
+            "vmem_limit_bytes"
+    else:
+        ceiling, origin = vmem_ceiling(), "2x the " \
+            "APEX_TPU_VMEM_BUDGET_MB streaming budget"
     if working > ceiling:
         f(Finding(
             PASS_NAME, "error",
             f"{call.name}: per-grid-step VMEM working set "
             f"{_fmt_bytes(working)} exceeds the ceiling "
-            f"{_fmt_bytes(ceiling)} (2x the "
-            f"APEX_TPU_VMEM_BUDGET_MB streaming budget) — "
+            f"{_fmt_bytes(ceiling)} ({origin}) — "
             f"{'; '.join(detail)}",
             op="pallas-vmem-overflow", bytes=working))
 

@@ -11,15 +11,19 @@ FlashAttention — pattern, not code) mapped onto the TPU:
   innermost; Mosaic's sequential grid makes the k-walk a legal accumulation
   over VMEM scratch (running max ``m``, normalizer ``l``, fp32 ``acc``) —
   the role CUDA shared-memory tiling plays for the GPU kernels;
-- one algorithm at two geometries, chosen from the call's shape by
-  :func:`_geometry` (no knob): a causal call whose head fits VMEM keeps
-  the head's rows *resident* — K and V are one block a head, fetched and
-  rotated once, and each block of q rows meets all its visible keys,
-  none above the diagonal, in one step (one row max and one row sum a
-  row instead of one a block pair); the backward is then one grid step
-  a head that forms its own row sums ``delta`` and writes dq, dk and dv
-  once, in the storage dtype.  Everything else (non-causal, key masks,
-  long contexts, explicit blocks) keeps the grid walk.  A
+- one algorithm at two geometries, chosen from the call's shape and the
+  chip's VMEM by :func:`_geometry` (no knob): a causal call whose head
+  fits VMEM keeps the head's rows *resident* — K and V are one block a
+  head, fetched and rotated once, and each block of q rows meets all
+  its visible keys, none above the diagonal, in one step (one row max
+  and one row sum a row instead of one a block pair); the backward is
+  then one grid step a head that forms its own row sums ``delta`` and
+  writes dq, dk and dv once, in the storage dtype.  A head over
+  Mosaic's default scoped-VMEM limit asks for a limit of its own; one
+  longer than a span of keys (2048) keeps K, V and the fp32 dk/dv sums
+  resident while its q rows walk the grid and meet their keys a span
+  at a time.  Everything else (non-causal, key masks, heads over the
+  chip's VMEM, explicit blocks) keeps the grid walk.  A
   ``jax.named_scope`` (``flash_resident`` / ``flash_grid``) around the
   kernel calls says which was chosen;
 - score/softmax arithmetic is fp32 regardless of storage dtype (the amp
@@ -205,7 +209,7 @@ def _fwd_update(q, k, v, bias_row, mask, m_scr, l_scr, acc_scr, *,
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, causal, has_bias,
-                rope_mode, block_q, block_k, nk, resident):
+                rope_mode, block_q, block_k, nk, resident, span=None):
     """One q block against one K/V block.  Grid walk: the grid's
     innermost axis walks the k dimension a block at a time, dead and
     straddling blocks told apart by ``pl.when``.  Resident (causal, no
@@ -217,7 +221,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, causal, has_bias,
     the walk pays them once a block pair (PERF.md, PR 26: on the v5e
     those cross-lane reductions, not the score tile, are what a pair
     costs).  The width is static per q block, so the q blocks of a head
-    are branches of the body."""
+    are branches of the body.  A long head (``span``) meets its keys a
+    span at a time, online: a loop over the whole spans under the q
+    block, then the keys that end at its diagonal, the only masked ones
+    (PERF.md, PR 28: one pair of 4096 keys or more runs several times
+    slower than its spans)."""
     nrope = _rope_nrefs(rope_mode)
     rope_refs = rest[:nrope]
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest[nrope:nrope + 5]
@@ -265,13 +273,33 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, *rest, causal, has_bias,
             cq, sq = _rope_q(rope_refs, rope_mode, q_start, block_q)
             q = _rot(q, cq, sq).astype(q_ref.dtype)
 
-        for i in range(block_k // block_q):
-            @pl.when(iq == i)
-            def _(i=i):
-                w = (i + 1) * block_q
-                _fwd_update(q, load_k(pl.ds(0, w)), v_ref[0, :w, :], None,
-                            _causal_mask(block_q, w, i * block_q, 0),
-                            m_scr, l_scr, acc_scr, has_bias=False)
+        def visit(rows, diagonal=None):
+            # A span that ends at the chunk's diagonal says where its
+            # first row stands among the span's keys.
+            _fwd_update(q, load_k(rows), v_ref[0, rows, :], None,
+                        None if diagonal is None else _causal_mask(
+                            block_q, rows.size, diagonal, 0),
+                        m_scr, l_scr, acc_scr, has_bias=False)
+
+        # Chunk iq = a * r + b meets a whole spans of keys, all visible
+        # (a loop, one body), then (b + 1) * block_q keys that end at
+        # its diagonal (static per b).  Without a span, r is the head:
+        # no loop, one pair a chunk.
+        r = (span or block_k) // block_q
+        a, start = 0, 0
+        if span:
+            a = iq // r
+            start = pl.multiple_of(a * span, span)
+
+            @pl.loop(0, a)
+            def _(j):
+                visit(pl.ds(pl.multiple_of(j * span, span), span))
+
+        for b in range(r):
+            @pl.when((iq % r if span else iq) == b)
+            def _(b=b):
+                visit(pl.ds(start, (b + 1) * block_q),
+                      diagonal=b * block_q)
 
     if resident:
         _visible_keys_at_once()
@@ -518,6 +546,17 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _resident_bwd_refs(rest, has_dlse, rope_mode):
+    """What follows ``lse_ref`` in the resident backward kernels' refs:
+    ``(dlse_ref, rope_refs, dq_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+    krot_scr)``, ``dlse_ref`` and the rotated-K scratch ``None`` where
+    the call has none."""
+    ndl, nrope = int(has_dlse), _rope_nrefs(rope_mode)
+    outs = rest[ndl + nrope:ndl + nrope + 5]
+    return (rest[0] if has_dlse else None, rest[ndl:ndl + nrope], *outs,
+            rest[ndl + nrope + 5] if rope_mode else None)
+
+
 def _bwd_resident_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                          *rest, has_dlse, rope_mode, chunk, n):
     """The one-pass backward of a causal head whose rows are all in
@@ -531,15 +570,10 @@ def _bwd_resident_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     once in the storage dtype; dk and dv add up in fp32 scratch over the
     head and are written once; the row sums ``delta`` come from the
     resident ``o`` and ``do``; K is rotated once a head."""
-    ndl, nrope = int(has_dlse), _rope_nrefs(rope_mode)
-    dlse_ref = rest[0] if has_dlse else None
-    rope_refs = rest[ndl:ndl + nrope]
-    dq_ref, dk_ref, dv_ref, dk_scr, dv_scr = \
-        rest[ndl + nrope:ndl + nrope + 5]
-    load_k = lambda rows: k_ref[0, rows, :]
-    if rope_mode:
-        krot_scr, = rest[ndl + nrope + 5:]        # (Lp, d) rotated K
-        load_k = lambda rows: krot_scr[rows, :]
+    (dlse_ref, rope_refs, dq_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+     krot_scr) = _resident_bwd_refs(rest, has_dlse, rope_mode)
+    load_k = ((lambda rows: krot_scr[rows, :]) if rope_mode
+              else (lambda rows: k_ref[0, rows, :]))
     spans = [pl.ds(i * chunk, chunk) for i in range(n)]
 
     for j, rows in enumerate(spans):
@@ -583,6 +617,98 @@ def _bwd_resident_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
             dk = _rot(dk, ck, -sk)
         dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
         dv_ref[0, rows, :] = dv_scr[rows, :].astype(dv_ref.dtype)
+
+
+def _bwd_qwalk_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
+                      has_dlse, rope_mode, chunk, n, span):
+    """The one-pass backward of a causal head too long for one grid
+    step (:func:`_bwd_resident_kernel`): K, V and the fp32 dk/dv sums
+    stay in VMEM for the head while q, do, o, lse and dq move by chunk
+    — grid ``(bh, n)``, the K/V and dk/dv block indices constant over
+    the q walk.  Chunk ``iq = a * r + b`` (``r`` chunks a span) meets
+    its visible keys in spans: ``a`` whole spans, all visible (a loop,
+    one body), then the ``(b + 1) * chunk`` keys that end at its
+    diagonal (static per ``b``); each is ``_bwd_pair``.  Its dq is
+    whole when its spans end, inverse-rotated and written once in the
+    storage dtype; dk and dv add up in fp32 scratch over the head and
+    are written at the head's last chunk; the row sums ``delta`` come
+    from the chunk's own ``o`` and ``do``; K is rotated once a head."""
+    (dlse_ref, rope_refs, dq_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+     krot_scr) = _resident_bwd_refs(rest, has_dlse, rope_mode)
+    load_k = ((lambda rows: krot_scr[rows, :]) if rope_mode
+              else (lambda rows: k_ref[0, rows, :]))
+    iq = pl.program_id(1)
+
+    @pl.when(iq == 0)
+    def _init():
+        @pl.loop(0, n)
+        def _(j):
+            start = pl.multiple_of(j * chunk, chunk)
+            rows = pl.ds(start, chunk)
+            if rope_mode:
+                ck, sk = _rope_k(rope_refs, rope_mode, start, chunk)
+                krot_scr[rows, :] = _rot(k_ref[0, rows, :], ck,
+                                         sk).astype(krot_scr.dtype)
+            dk_scr[rows, :] = jnp.zeros((chunk, dk_scr.shape[1]),
+                                        jnp.float32)
+            dv_scr[rows, :] = jnp.zeros((chunk, dv_scr.shape[1]),
+                                        jnp.float32)
+
+    q = q_ref[0]
+    if rope_mode:
+        cq, sq = _rope_q(rope_refs, rope_mode, iq * chunk, chunk)
+        q = _rot(q, cq, sq).astype(q_ref.dtype)
+    do = do_ref[0]
+    delta = jnp.sum(o_ref[0].astype(jnp.float32) * do.astype(jnp.float32),
+                    axis=1, keepdims=True)
+    if has_dlse:
+        # ds_ij = p_ij (dp_ij - delta_i + dlse_i): the logsumexp's
+        # cotangent is an offset on the row sums.
+        delta = delta - dlse_ref[0][:, :1]
+    lse_col = lse_ref[0][:, :1]
+
+    def visit(rows, dq, diagonal=None):
+        # A span that ends at the chunk's diagonal says where its first
+        # row stands among the span's keys; a whole span has no mask.
+        dq_s, dk, dv = _bwd_pair(
+            q, load_k(rows), v_ref[0, rows, :], do, lse_col, delta, None,
+            masked=diagonal is not None, has_bias=False, q_start=diagonal,
+            k_start=0, block_q=chunk, block_k=rows.size)
+        dk_scr[rows, :] += dk
+        dv_scr[rows, :] += dv
+        return dq + dq_s
+
+    r = span // chunk
+    a = iq // r
+    dq = jax.lax.fori_loop(
+        0, a, lambda j, dq: visit(
+            pl.ds(pl.multiple_of(j * span, span), span), dq),
+        jnp.zeros(q.shape, jnp.float32))
+
+    for b in range(r):
+        @pl.when(iq % r == b)
+        def _(b=b):
+            dq_b = visit(pl.ds(pl.multiple_of(a * span, span),
+                               (b + 1) * chunk), dq, diagonal=b * chunk)
+            if rope_mode:
+                # dq is w.r.t. the ROTATED q; chain through the
+                # orthogonal rotation, R^T = the same lane-rotation with
+                # the sine negated.
+                dq_b = _rot(dq_b, cq, -sq)
+            dq_ref[0] = dq_b.astype(dq_ref.dtype)
+
+    @pl.when(iq == n - 1)
+    def _emit():
+        @pl.loop(0, n)
+        def _(j):
+            start = pl.multiple_of(j * chunk, chunk)
+            rows = pl.ds(start, chunk)
+            dk = dk_scr[rows, :]
+            if rope_mode:
+                ck, sk = _rope_k(rope_refs, rope_mode, start, chunk)
+                dk = _rot(dk, ck, -sk)
+            dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_scr[rows, :].astype(dv_ref.dtype)
 
 
 def _rope_inputs(cos_t, sin_t, rope_mode, h, lp, d, block_q, block_k,
@@ -676,23 +802,51 @@ def _flash_bwd_fused(qf, kf, vf, of, do_f, lse, bias, cos_t, sin_t, dlse_f,
     return dq, dk, dv
 
 
+def _compiler_params(vmem_limit):
+    """``compiler_params`` of a call: nothing for a call under Mosaic's
+    default scoped-VMEM limit, its own limit for one above it."""
+    if vmem_limit is None:
+        return {}
+    return dict(compiler_params=pltpu.CompilerParams(
+        vmem_limit_bytes=int(vmem_limit)))
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("rope_mode", "chunk", "num_heads"))
+                   static_argnames=("rope_mode", "chunk", "num_heads",
+                                    "span", "vmem_limit"))
 def _flash_bwd_resident(qf, kf, vf, of, do_f, lse, cos_t, sin_t, dlse_f, *,
-                        rope_mode, chunk, num_heads):
-    """Causal, bias-free backward with a head's rows resident: grid
-    ``(bh,)``, every operand one ``(1, Lp, ·)`` block.  ``dlse_f`` is
-    ``None`` where the caller dropped the logsumexp; otherwise it rides
-    in at the stats width, as ``lse`` does."""
+                        rope_mode, chunk, num_heads, span=None,
+                        vmem_limit=None):
+    """Causal, bias-free backward with a head's K and V resident.
+    Without a ``span`` all of the head's rows are: grid ``(bh,)``, every
+    operand one ``(1, Lp, ·)`` block.  With one, q and what goes with
+    its rows walk the grid ``chunk`` rows at a time: grid ``(bh, Lp /
+    chunk)``.  ``dlse_f`` is ``None`` where the caller dropped the
+    logsumexp; otherwise it rides in at the stats width, as ``lse``
+    does."""
     bh, lp, d = qf.shape
     dv_ = vf.shape[2]                   # v, o and do keep v's own width
     h = num_heads
-    row = pl.BlockSpec((1, lp, d), lambda bh_: (bh_, 0, 0))
-    vrow = pl.BlockSpec((1, lp, dv_), lambda bh_: (bh_, 0, 0))
-    stats = pl.BlockSpec((1, lp, _STATS_W), lambda bh_: (bh_, 0, 0))
-    table = pl.BlockSpec((1, lp, d), lambda bh_: (bh_ // h, 0, 0))
+    static = dict(has_dlse=dlse_f is not None, rope_mode=rope_mode,
+                  chunk=chunk, n=lp // chunk)
+    if span is None:
+        kernel = functools.partial(_bwd_resident_kernel, **static)
+        grid, rows = (bh,), lp
+        at = lambda index: lambda bh_: index(bh_, 0)
+    else:
+        kernel = functools.partial(_bwd_qwalk_kernel, span=span, **static)
+        grid, rows = (bh, lp // chunk), chunk
+        at = lambda index: index
+    by_chunk = at(lambda bh_, iq: (bh_, iq, 0))
+    by_head = at(lambda bh_, iq: (bh_, 0, 0))
+    qrow = pl.BlockSpec((1, rows, d), by_chunk)
+    qvrow = pl.BlockSpec((1, rows, dv_), by_chunk)
+    stats = pl.BlockSpec((1, rows, _STATS_W), by_chunk)
+    row = pl.BlockSpec((1, lp, d), by_head)
+    vrow = pl.BlockSpec((1, lp, dv_), by_head)
+    table = pl.BlockSpec((1, lp, d), at(lambda bh_, iq: (bh_ // h, 0, 0)))
     operands = [qf, kf, vf, do_f, of, lse]
-    in_specs = [row, row, vrow, vrow, vrow, stats]
+    in_specs = [qrow, row, vrow, qvrow, qvrow, stats]
     if dlse_f is not None:
         operands.append(jnp.broadcast_to(dlse_f[..., None],
                                          (bh, lp, _STATS_W)))
@@ -704,28 +858,29 @@ def _flash_bwd_resident(qf, kf, vf, of, do_f, lse, cos_t, sin_t, dlse_f, *,
         in_specs += [table, table]
         scratch.append(pltpu.VMEM((lp, d), kf.dtype))       # rotated K
     return pl.pallas_call(
-        functools.partial(_bwd_resident_kernel,
-                          has_dlse=dlse_f is not None, rope_mode=rope_mode,
-                          chunk=chunk, n=lp // chunk),
-        grid=(bh,),
+        kernel,
+        grid=grid,
         in_specs=in_specs,
-        out_specs=[row, row, vrow],
+        out_specs=[qrow, row, vrow],
         out_shape=[_sds((bh, lp, d), qf.dtype, qf)] * 2
         + [_sds((bh, lp, dv_), qf.dtype, qf)],
         scratch_shapes=scratch,
         name="flash_bwd_fused",
         interpret=not on_tpu(),
+        **_compiler_params(vmem_limit),
     )(*operands)
 
 
 def _fused_bwd_max_bytes() -> int:
     """HBM budget for the grid walk's fused backward's (groups, BH, L,
     d) fp32 dq-partials buffer; the gate is its size, not the block
-    count — fused still wins at nk=16 when the buffer fits (the smoke's
-    gpt-small-tpu L=16384 runs it with 805 MB of partials).  Above this
+    count — fused still wins at nk=16 when the buffer fits.  Above this
     budget the extra HBM outweighs the saved recompute and the two-pass
     kernels take over (extreme contexts / big batches).  A resident
-    head (:func:`_geometry`) has no partials and never asks.
+    head (:func:`_geometry`) has no partials and never asks: since PR
+    28 that is every causal, mask-free head that fits the chip's VMEM
+    (16384 rows of 128 in bf16 do), so the grid walk's backwards serve
+    non-causal calls, key masks, explicit blocks and longer heads.
 
     ``APEX_TPU_FLASH_FUSED_BWD_MAX_BYTES`` overrides (0 forces the
     two-pass path) so memory-tight configs can steer without
@@ -783,9 +938,10 @@ def _unprep(t, b, l, h, layout="blhd"):
 @functools.partial(jax.jit,
                    static_argnames=("causal", "has_bias", "rope_mode",
                                     "block_q", "block_k", "num_heads",
-                                    "resident"))
+                                    "resident", "span", "vmem_limit"))
 def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
-               rope_mode, block_q, block_k, num_heads, resident):
+               rope_mode, block_q, block_k, num_heads, resident, span=None,
+               vmem_limit=None):
     bh, lp, d = qf.shape
     dv_ = vf.shape[2]                   # v and o keep v's own width
     # Resident: K and V are one block a head, its index constant over
@@ -808,7 +964,8 @@ def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, has_bias=has_bias,
                           rope_mode=rope_mode, block_q=block_q,
-                          block_k=block_k, nk=nk, resident=resident),
+                          block_k=block_k, nk=nk, resident=resident,
+                          span=span),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh_, iq, ik: (bh_, iq, 0)),
@@ -831,6 +988,7 @@ def _flash_fwd(qf, kf, vf, bias, cos_t, sin_t, *, causal, has_bias,
         scratch_shapes=scratch,
         name="flash_fwd",
         interpret=not on_tpu(),
+        **_compiler_params(vmem_limit),
     )(qf, kf, vf, bias, *rope_ops)
     return o, lse
 
@@ -919,15 +1077,18 @@ RESIDENT_SCOPE, GRID_SCOPE = "flash_resident", "flash_grid"
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14))
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12, 13, 14, 15,
+                                    16))
 def _flash(q, k, v, bias, cos_t, sin_t, scale, causal, block_q, block_k,
-           has_bias, rope_mode, layout, resident, with_lse):
+           has_bias, rope_mode, layout, resident, with_lse, span,
+           vmem_limit):
     """``out``, or ``(out, lse)`` with ``with_lse``: a caller that drops
     the logsumexp says so here, and its backward carries no cotangent
-    for it."""
+    for it.  ``span`` and ``vmem_limit`` are a long resident head's
+    (:class:`_Geometry`), ``None`` for every other call."""
     outs, _ = _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal,
                           block_q, block_k, has_bias, rope_mode, layout,
-                          resident, with_lse)
+                          resident, with_lse, span, vmem_limit)
     return outs
 
 
@@ -937,7 +1098,8 @@ def _lse_public(lse, b, l, h):
 
 
 def _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal, block_q,
-                block_k, has_bias, rope_mode, layout, resident, with_lse):
+                block_k, has_bias, rope_mode, layout, resident, with_lse,
+                span, vmem_limit):
     if layout == "bhld":
         b, h, l, d = q.shape
     else:
@@ -960,7 +1122,8 @@ def _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal, block_q,
                              causal=causal, has_bias=has_bias,
                              rope_mode=rope_mode, block_q=block_q,
                              block_k=block_k, num_heads=h,
-                             resident=resident)
+                             resident=resident, span=span,
+                             vmem_limit=vmem_limit)
     out = _unprep(of, b, l, h, layout)
     return ((out, _lse_public(lse, b, l, h)) if with_lse else out,
             (qf, kf, vf, of, lse, bias_p, cos_t, sin_t))
@@ -968,17 +1131,18 @@ def _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal, block_q,
 
 def _flash_fwd_rule(q, k, v, bias, cos_t, sin_t, scale, causal, block_q,
                     block_k, has_bias, rope_mode, layout, resident,
-                    with_lse):
+                    with_lse, span, vmem_limit):
     outs, res = _flash_core(q, k, v, bias, cos_t, sin_t, scale, causal,
                             block_q, block_k, has_bias, rope_mode, layout,
-                            resident, with_lse)
+                            resident, with_lse, span, vmem_limit)
     # The saved tables are padded to Lp; the cotangents must match the
     # caller's (unpadded) table shape, so remember it.
     return outs, (res, q.shape, cos_t.shape)
 
 
 def _flash_bwd_rule(scale, causal, block_q, block_k, has_bias, rope_mode,
-                    layout, resident, with_lse, saved, cotangents):
+                    layout, resident, with_lse, span, vmem_limit, saved,
+                    cotangents):
     dout, dlse = cotangents if with_lse else (cotangents, None)
     (qf, kf, vf, of, lse, bias_p, cos_t, sin_t), shape, table_shape = saved
     if layout == "bhld":
@@ -1001,7 +1165,8 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, has_bias, rope_mode,
         if resident:
             dqf, dkf, dvf = _flash_bwd_resident(
                 qf, kf, vf, of, do_f, lse, cos_t, sin_t, dlse_f,
-                rope_mode=rope_mode, chunk=block_k, num_heads=h)
+                rope_mode=rope_mode, chunk=block_k, num_heads=h, span=span,
+                vmem_limit=vmem_limit)
         else:
             partials_bytes = (lp // block_k) * qf.shape[0] * lp * d * 4
             bwd = (_flash_bwd_fused
@@ -1074,8 +1239,11 @@ def _default_block(l: int) -> int:
     when the larger block adds no padding: for L not near a multiple of
     1024 the padded sequence would grow, and the quadratic extra
     attention work erases the per-step win.  The choice dates from the
-    round-3 chip, at B8·H12·L2048·d64; no cell of ``BENCHMARK.json``
-    runs a grid-walk call at L >= 2048 yet (PERF.md section 7)."""
+    round-3 chip, at B8·H12·L2048·d64, causal.  Causal mask-free heads
+    of that length are resident since PR 28 (:func:`_geometry`); what
+    still walks the grid at L >= 2048 is non-causal, masked or longer
+    than the chip's VMEM holds, and no cell of ``BENCHMARK.json`` runs
+    such a call (PERF.md section 7)."""
     if l >= 2048 and _ceil_to(l, 1024) == _ceil_to(l, 512):
         return 1024
     return 512
@@ -1085,22 +1253,50 @@ def _default_block(l: int) -> int:
 #: keys), widest first.  On the v5e a row of a pair costs about as much
 #: whatever the pair's width, and every q block loads its keys into the
 #: MXU anew, so fewer, taller blocks win as long as they fit: at the
-#: benchmark's shape 512 rows beat 256 and 128 in the forward and tie in
-#: the backward (PERF.md, PR 26).
+#: gpt2 cells' shape 512 rows beat 256 and 128 in the forward and tie in
+#: the backward (PERF.md, PR 26); at 8192 rows of 192/128 likewise, and
+#: 1024 rows lose in the backward (PERF.md, PR 28).
 _RESIDENT_CHUNKS = (512, 256)
 
-#: What the resident geometry may plan to hold in VMEM: three quarters
-#: of Mosaic's default 16 MiB scoped limit.
+#: What a resident head may plan to hold in VMEM with no word to the
+#: compiler: three quarters of Mosaic's default 16 MiB scoped limit.  A
+#: head under it compiles with the parameters it always had; one over
+#: it asks for its own limit (:func:`_geometry`).
 _RESIDENT_VMEM_BYTES = 3 * (16 << 20) // 4
+
+#: Keys a chunk of a resident head meets at a time.  A head no longer
+#: than this keeps all its rows in VMEM and meets a chunk's visible keys
+#: in one pair; a longer one walks q on the grid and meets them in spans
+#: of this many.  On the v5e a pair's cost per key is flat to 2048 keys
+#: and several times that from 4096 (PERF.md, PR 28, has the table).
+_LONG_HEAD_SPAN = 2048
+
+#: VMEM of a TPU v5e core, for where no chip answers: CPU tests and a
+#: compile for a described chip see one deterministic geometry.
+_FALLBACK_VMEM_CAPACITY = 128 << 20
+
+
+def _vmem_capacity() -> int:
+    """VMEM of the chip this call is traced for, read from the device;
+    :data:`_FALLBACK_VMEM_CAPACITY` where the device is no TPU."""
+    try:
+        return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+    except ValueError:
+        return _FALLBACK_VMEM_CAPACITY
 
 
 class _Geometry(NamedTuple):
     """How a call walks its head: ``resident`` (K/V one block a head,
     each ``block`` rows of q against all their visible keys in one
     step) or the grid walk with ``block`` as the edge of the grid's q
-    and k blocks."""
+    and k blocks.  A resident head over Mosaic's default scoped-VMEM
+    limit also says what it asks the compiler for (``vmem_limit``,
+    bytes) and, where a chunk's visible keys are too many for one pair,
+    how many it meets at a time (``span``)."""
     resident: bool
     block: int
+    span: "int | None" = None
+    vmem_limit: "int | None" = None
 
 
 def _resident_bytes(lp: int, d: int, itemsize: int, rope: bool,
@@ -1123,6 +1319,27 @@ def _resident_bytes(lp: int, d: int, itemsize: int, rope: bool,
     return rows + stats + tables + scratch + 3 * chunk * lp * 4 // 2
 
 
+def _long_head_bytes(lp: int, d: int, itemsize: int, rope: bool,
+                     chunk: int, span: int,
+                     d_v: "int | None" = None) -> int:
+    """VMEM the backward of a long resident head needs, q walked
+    ``chunk`` rows at a time on the grid: K, V, dk and dv as the
+    pipeline's double-buffered blocks, the fp32 dk/dv sums (and the
+    rotated K) as scratch, the chunk's streams, and three fp32 score
+    tiles of the widest pair.  Fitted to the compiler's own accounting:
+    at 8192 rows of 192/128 in bf16, 512-row chunks and 2048-key spans
+    Mosaic names 48.9 MB where this gives 50 (PERF.md, PR 28)."""
+    wide, vwide = _ceil_to(d, _LANES), _ceil_to(d_v or d, _LANES)
+    row, vrow = lp * wide, lp * vwide
+    held = 2 * 2 * (row + vrow) * itemsize     # k v dk dv, two buffers
+    scratch = (row + vrow) * 4 + (row * itemsize if rope else 0)
+    tables = 2 * 2 * row * (2 if itemsize == 2 else 4) if rope else 0
+    streams = 2 * 2 * chunk * ((wide + vwide) * itemsize   # q dq; do o
+                               + _STATS_W * 4)             # lse, dlse
+    tiles = 3 * chunk * span * 4
+    return held + scratch + tables + streams + tiles
+
+
 def _grid_block(l: int, itemsize: int, rope: bool) -> int:
     """The grid walk's default block edge: :func:`_default_block`, capped
     at 512 for fp32 activations with rope tables — the fused backward at
@@ -1136,21 +1353,36 @@ def _grid_block(l: int, itemsize: int, rope: bool) -> int:
 def _geometry(l: int, d: int, itemsize: int, causal: bool, rope: bool,
               has_bias: bool, d_v: "int | None" = None) -> _Geometry:
     """The geometry of a call with no explicit blocks, from what the
-    call can see.  Resident when the mask is causal (nothing above the
-    diagonal is then fetched, scored or masked), there is no key bias
-    (its ``(1, block_k)`` lane blocks ride the grid), and the head fits
-    :data:`_RESIDENT_VMEM_BYTES` at one of :data:`_RESIDENT_CHUNKS` —
-    the one that pads the sequence least, the taller on a tie.  Else
-    the grid walk with the blocks it always had: long contexts, any key
-    mask, and every non-causal call."""
+    call can see and the VMEM its chip has.  Resident when the mask is
+    causal (nothing above the diagonal is then fetched, scored or
+    masked), there is no key bias (its ``(1, block_k)`` lane blocks
+    ride the grid), and the head fits at one of
+    :data:`_RESIDENT_CHUNKS` — the one that pads the sequence least,
+    the taller on a tie: under :data:`_RESIDENT_VMEM_BYTES` with the
+    compiler's defaults, else under seven eighths of the chip's VMEM
+    with a scoped limit of its own, the estimate and a quarter — all
+    its rows if they are no more than :data:`_LONG_HEAD_SPAN`, else K,
+    V and the dk/dv sums, with q walking the grid and meeting its keys
+    a span at a time.  Else the grid walk with the blocks it always
+    had: heads over that, any key mask, and every non-causal call."""
     if causal and not has_bias:
-        fits = [c for c in (min(c, _ceil_to(l, _LANES))
-                            for c in _RESIDENT_CHUNKS)
-                if _resident_bytes(_ceil_to(l, c), d, itemsize, rope, c,
-                                   d_v) <= _RESIDENT_VMEM_BYTES]
-        if fits:
-            return _Geometry(True, min(fits, key=lambda c: (_ceil_to(l, c),
-                                                             -c)))
+        chunks = sorted({min(c, _ceil_to(l, _LANES))
+                         for c in _RESIDENT_CHUNKS},
+                        key=lambda c: (_ceil_to(l, c), -c))
+        for c in chunks:
+            if _resident_bytes(_ceil_to(l, c), d, itemsize, rope, c,
+                               d_v) <= _RESIDENT_VMEM_BYTES:
+                return _Geometry(True, c)
+        for c in chunks:
+            lp = _ceil_to(l, c)
+            if lp <= _LONG_HEAD_SPAN:
+                span = None
+                need = _resident_bytes(lp, d, itemsize, rope, c, d_v)
+            else:
+                span = _LONG_HEAD_SPAN
+                need = _long_head_bytes(lp, d, itemsize, rope, c, span, d_v)
+            if 5 * need // 4 <= 7 * _vmem_capacity() // 8:
+                return _Geometry(True, c, span, 5 * need // 4)
     return _Geometry(False, _grid_block(l, itemsize, rope))
 
 
@@ -1317,4 +1549,6 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
                      or per_side <= _ROPE_RESIDENT_MAX_BYTES else "stream")
     return _flash(q, k, v, bias, cos_t, sin_t, float(scale), bool(causal),
                   int(block_q), int(block_k), has_bias, rope_mode, layout,
-                  resident, bool(return_lse))
+                  resident, bool(return_lse),
+                  geo.span if resident else None,
+                  geo.vmem_limit if resident else None)

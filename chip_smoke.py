@@ -88,15 +88,17 @@ class Shape:
     optimizer_elems: int      # flat fp32 elements of the optimizer cases
     ln_rows: int
     ln_features: tuple
+    long_seq: int             # rows of the long flash head
 
 
 FULL = Shape(batch=8, seq=2048, steps=TRAIN_STEPS,
              max_final_loss=MAX_FINAL_LOSS, slots=8, block=16, prefill=512,
              prefill_chunk=128, new_tokens=128, optimizer_elems=1 << 24,
-             ln_rows=8 * 2048, ln_features=(768, 1024, 4096))
+             ln_rows=8 * 2048, ln_features=(768, 1024, 4096), long_seq=8192)
 DRY = Shape(batch=8, seq=64, steps=60, max_final_loss=1.0, slots=2,
             block=4, prefill=16, prefill_chunk=8, new_tokens=8,
-            optimizer_elems=1 << 15, ln_rows=48, ln_features=(128, 256))
+            optimizer_elems=1 << 15, ln_rows=48, ln_features=(128, 256),
+            long_seq=128)
 
 
 def token_cycle(vocab_size: int) -> np.ndarray:
@@ -134,6 +136,8 @@ def phase_train(cfg, shape: Shape, n_devices: int, dry: bool):
     from apex_tpu.analysis import spmd
     from apex_tpu.models.gpt import GPTModel, lm_loss
     from apex_tpu.ops import mosaic_kernels
+    from apex_tpu.ops.pallas.flash_attention import (GRID_SCOPE,
+                                                     RESIDENT_SCOPE)
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.parallel import DistributedDataParallel
 
@@ -176,12 +180,16 @@ def phase_train(cfg, shape: Shape, n_devices: int, dry: bool):
     print(f"train: compiled ({timed(dry, compile_s)} s); Mosaic kernels "
           f"in the step: {kernels}", flush=True)
     if not dry:
-        flash_bwd = {"flash_bwd_fused"}, {"flash_bwd_dq", "flash_bwd_dkv"}
-        missing = {"flash_fwd", "layer_norm_fwd", "layer_norm_bwd"} \
-            - set(kernels)
-        check(not missing and any(b <= set(kernels) for b in flash_bwd),
-              f"train step HLO lacks Mosaic kernels: missing {missing}, "
-              f"flash backward needs one of {flash_bwd}; found {kernels}")
+        # 2048 causal rows of 128 are a resident head since PR 28 (under
+        # a scoped-VMEM limit of their own): the one-pass backward under
+        # the scope that says so, no dq / dkv pair and no grid walk
+        missing = {"flash_fwd", "flash_bwd_fused", "layer_norm_fwd",
+                   "layer_norm_bwd"} - set(kernels)
+        walks = [s for s in (RESIDENT_SCOPE, GRID_SCOPE) if s in hlo]
+        check(not missing and walks == [RESIDENT_SCOPE],
+              f"train step HLO lacks Mosaic kernels: missing {missing}; "
+              f"found {kernels}; its flash calls run under {walks}, not "
+              f"{RESIDENT_SCOPE} alone")
 
     all_reduce_groups = None
     if n_devices > 1:
@@ -342,7 +350,9 @@ def kernel_cases(shape: Shape):
     """``(name, expected kernels, fn, args, rtol, atol, env)`` per
     family: ``fn(*args)`` is traced once as the environment selects
     (Pallas) and once under ``APEX_TPU_KERNELS=jnp``.  Tolerances are
-    the unit tests'."""
+    the unit tests'.  Beside kernel names, ``expected`` may hold the
+    scope a flash call has to run under (``flash_resident`` /
+    ``flash_grid``)."""
     import jax
     import jax.numpy as jnp
 
@@ -465,6 +475,7 @@ def latent_moe_cases(shape: Shape, normal, uniform):
                                              DeepseekV3Config)
     from apex_tpu.normalization import fused_rms_norm_affine
     from apex_tpu.attention import attention
+    from apex_tpu.ops.pallas.flash_attention import RESIDENT_SCOPE
     from apex_tpu.ops.rope import rope_tables_interleaved
     from apex_tpu.parallel import moe
 
@@ -489,6 +500,21 @@ def latent_moe_cases(shape: Shape, normal, uniform):
             block_k=min(512, seq)), q, k, v)
         return (out,) + vjp(do)
     yield ("flash_192_128", {"flash_fwd", "flash_bwd_fused"}, flash,
+           (q, k, v, do), 3e-2, 5e-2, {})
+
+    # the same widths at the length of the benchmark's kanana cell, blocks
+    # left to the call: a head over Mosaic's default scoped-VMEM limit
+    # stays resident under one of its own and walks q on the grid
+    rows = shape.long_seq
+    q, k = (normal(1, rows, 2, 192, dtype=jnp.bfloat16) for _ in range(2))
+    v, do = (normal(1, rows, 2, 128, dtype=jnp.bfloat16) for _ in range(2))
+
+    def flash_long(q, k, v, do):
+        out, vjp = jax.vjp(lambda q, k, v: attention(
+            q, k, v, causal=True), q, k, v)
+        return (out,) + vjp(do)
+    yield ("flash_192_128_long_head",
+           {"flash_fwd", "flash_bwd_fused", RESIDENT_SCOPE}, flash_long,
            (q, k, v, do), 3e-2, 5e-2, {})
 
     tokens, d, f, experts, held = 2 * seq, 256, 128, 8, 4
@@ -531,6 +557,8 @@ def phase_kernels(shape: Shape, dry: bool):
     import jax.numpy as jnp
 
     from apex_tpu.ops import mosaic_kernels
+    from apex_tpu.ops.pallas.flash_attention import (GRID_SCOPE,
+                                                     RESIDENT_SCOPE)
 
     @jax.jit
     def worst(got, want, rtol, atol):
@@ -554,7 +582,9 @@ def phase_kernels(shape: Shape, dry: bool):
             with environ(**env):
                 compiled = jax.jit(
                     lambda *a: fn(*a)).lower(*args).compile()
-            found = mosaic_kernels(compiled.as_text())
+            text = compiled.as_text()
+            found = mosaic_kernels(text) + [
+                s for s in (RESIDENT_SCOPE, GRID_SCOPE) if s in text]
             got = compiled(*args)
             with environ(APEX_TPU_KERNELS="jnp"):
                 want = jax.jit(lambda *a: fn(*a))(*args)

@@ -195,12 +195,17 @@ def test_default_blocks_scale_with_length():
     for l, expect in cases:
         assert _default_block(l) == expect, l
     # The geometry of a call with no explicit blocks, as a pure function
-    # of (l, d, itemsize, causal, rope, has_bias): the benchmark's call
+    # of (l, d, itemsize, causal, rope, has_bias[, d_v]) and the chip's
+    # VMEM (off the chip: the v5e's 128 MiB): the benchmark's gpt2 call
     # (L 1024, 16 heads of 64, bf16, causal, rope) keeps its head's rows
-    # in VMEM; every non-causal call, a key mask, and a head over the
-    # VMEM budget keep the grid walk with the blocks they always had.
+    # in VMEM with the compiler's defaults; a head over Mosaic's default
+    # scoped limit keeps them there under a limit of its own (the kanana
+    # cell's 8192 rows of 192/128 among them), its chunks meeting their
+    # keys in spans once the head is longer than one; every non-causal
+    # call, a key mask, and a head over the chip's VMEM keep the grid
+    # walk with the blocks they always had.
     from apex_tpu.ops.pallas.flash_attention import _geometry
-    geometry_cases = (
+    default_limit_cases = (
         ((1024, 64, 2, True, True, False), (True, 512)),
         ((1024, 128, 2, True, False, False), (True, 512)),
         ((1000, 64, 2, True, True, False), (True, 512)),
@@ -213,15 +218,28 @@ def test_default_blocks_scale_with_length():
         ((512, 64, 2, False, False, False), (False, 512)),
         ((1024, 64, 2, False, True, False), (False, 512)),
         ((1024, 64, 2, True, True, True), (False, 512)),
-        # over the budget: fp32 at 1536, gpt_small_tpu's 8 x 2048 at
-        # d 128, the smoke's 16384
-        ((1536, 64, 4, True, True, False), (False, 512)),
-        ((2048, 128, 2, True, True, False), (False, 1024)),
-        ((2048, 128, 4, True, True, False), (False, 512)),
-        ((16384, 128, 2, True, True, False), (False, 1024)),
+        # non-causal, masked or over the chip's VMEM at any length
+        ((8192, 192, 2, False, False, False, 128), (False, 1024)),
+        ((8192, 192, 2, True, False, True, 128), (False, 1024)),
+        ((32768, 128, 2, True, False, False), (False, 1024)),
+        ((32768, 128, 4, True, True, False), (False, 512)),
     )
-    for args, expect in geometry_cases:
-        assert tuple(_geometry(*args)) == expect, args
+    for args, expect in default_limit_cases:
+        assert tuple(_geometry(*args)) == expect + (None, None), args
+    raised_limit_cases = (
+        # the kanana cell's call
+        ((8192, 192, 2, True, False, False, 128), (True, 512, 2048)),
+        # fp32 at 1536, gpt_small_tpu's 8 x 2048 at d 128, the smoke's
+        # 16384: over the default limit, under the chip's VMEM
+        ((1536, 64, 4, True, True, False), (True, 512, None)),
+        ((2048, 128, 2, True, True, False), (True, 512, None)),
+        ((2048, 128, 4, True, True, False), (True, 512, None)),
+        ((16384, 128, 2, True, True, False), (True, 512, 2048)),
+    )
+    for args, expect in raised_limit_cases:
+        geo = _geometry(*args)
+        assert tuple(geo)[:3] == expect, args
+        assert 16 << 20 < geo.vmem_limit <= 7 * (128 << 20) // 8, args
 
 
 @pytest.mark.skipif(_ON_CPU, reason="interpret-mode 4096^2 attention is "
@@ -338,7 +356,9 @@ class TestRopeFused:
     def test_fp32_defaults_capped_at_512(self, monkeypatch):
         """fp32 + rope caps *defaulted* blocks at 512 (1024-blocks blow
         the scoped-VMEM limit in the fused backward — measured on the
-        O0 L2048 train step); explicit requests pass through."""
+        O0 L2048 train step); explicit requests pass through.  The
+        grid walk's defaults: a causal head of 2048 rows is resident
+        since PR 28, so the calls here are not causal."""
         from apex_tpu.ops.pallas import flash_attention as fa
         seen = []
         real = fa._flash
@@ -356,15 +376,15 @@ class TestRopeFused:
         pos = jnp.broadcast_to(jnp.arange(l)[None, :], (1, l))
         from apex_tpu.ops.rope import rope_tables
         cos, sin = rope_tables(pos, D, 10000.0)
-        fa.flash_attention(q, k, v, causal=True, rope=(cos, sin))
+        fa.flash_attention(q, k, v, causal=False, rope=(cos, sin))
         assert seen[-1][:2] == (512, 512)
         # bf16 keeps the length-scaled default
         fa.flash_attention(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
-                           v.astype(jnp.bfloat16), causal=True,
+                           v.astype(jnp.bfloat16), causal=False,
                            rope=(cos, sin))
         assert seen[-1][:2] == (1024, 1024)
         # no rope: fp32 keeps the 1024 default (unchanged behavior)
-        fa.flash_attention(q, k, v, causal=True)
+        fa.flash_attention(q, k, v, causal=False)
         assert seen[-1][:2] == (1024, 1024)
         assert seen[-1][2] is None
 
@@ -473,11 +493,11 @@ class TestResidentHead:
         return seen
 
     @staticmethod
-    def _inputs(l, d, rope, seed=0):
+    def _inputs(l, d, rope, seed=0, dv=None):
         from apex_tpu.ops.rope import apply_rope, rope_tables
         rng = np.random.RandomState(seed)
-        q, k, v = (jnp.asarray(rng.randn(1, l, 2, d).astype(np.float32))
-                   for _ in range(3))
+        q, k, v = (jnp.asarray(rng.randn(1, l, 2, w).astype(np.float32))
+                   for w in (d, d, dv or d))
         if not rope:
             return (q, k, v), {}, lambda q, k: (q, k)
         pos = jnp.arange(l)[None, :]
@@ -589,6 +609,127 @@ class TestResidentHead:
         flash_attention(q, k, v, causal=True,
                         kv_mask=jnp.ones((1, 512), bool))
         assert flash_calls[-1] == (512, 512, False)
+
+
+class TestLongHead:
+    """A causal head over Mosaic's default scoped-VMEM limit stays
+    resident under a limit of its own (``_geometry`` reads the chip's
+    VMEM): K, V and the fp32 dk/dv sums stay in VMEM for the head, q
+    walks the grid by chunk, and a chunk meets its visible keys in
+    spans.  The interpreter cannot run 8192 rows, so the budget of the
+    default limit is patched to nothing and the span to one chunk or
+    two: every call here takes the raised-limit branch, loop and all."""
+
+    @pytest.fixture
+    def long_head_calls(self, monkeypatch):
+        """Every ``_flash`` call's ``(block_q, resident, span,
+        vmem_limit)`` with the default-limit budget at zero and 512-key
+        spans."""
+        from apex_tpu.ops.pallas import flash_attention as fa
+        monkeypatch.setattr(fa, "_RESIDENT_VMEM_BYTES", 0)
+        monkeypatch.setattr(fa, "_LONG_HEAD_SPAN", 512)
+        seen = []
+        real = fa._flash
+
+        def spy(*args):
+            seen.append((args[8], args[13], args[15], args[16]))
+            return real(*args)
+
+        monkeypatch.setattr(fa, "_flash", spy)
+        return seen
+
+    # 1024 rows: two 512-row chunks, the second one whole span and its
+    # diagonal; 1280 rows pad least at 256-row chunks: five of them, two
+    # a span, so up to two turns of the loop and both static tails
+    @pytest.mark.parametrize("with_lse", [False, True])
+    @pytest.mark.parametrize("rope", [False, True])
+    @pytest.mark.parametrize("l,d,dv,chunk", [(1024, 192, 128, 512),
+                                              (1024, 64, 64, 512),
+                                              (1280, 64, 64, 256)])
+    def test_forward_and_grads_match_reference(self, long_head_calls, l, d,
+                                               dv, chunk, rope, with_lse):
+        from apex_tpu.ops.pallas import flash_attention as fa
+        (q, k, v), kw, rotate = TestResidentHead._inputs(l, d, rope, dv=dv)
+        w = jnp.asarray(np.random.RandomState(6).randn(1, l, 2)
+                        .astype(np.float32))
+
+        def loss(fn):
+            def f(q, k, v):
+                out, lse = fn(q, k, v)
+                return (jnp.sum(jnp.sin(out))
+                        + (jnp.sum(w * lse) if with_lse else 0.0))
+            return f
+
+        def flash(q, k, v):
+            out = flash_attention(q, k, v, causal=True,
+                                  return_lse=with_lse, **kw)
+            return out if with_lse else (out, None)
+
+        ref = lambda q, k, v: fa._jnp_attention(
+            *rotate(q, k), v, causal=True, kv_mask=None,
+            scale=1.0 / d ** 0.5, return_lse=True)
+        out, lse = flash(q, k, v)
+        block, resident, span, limit = long_head_calls[-1]
+        assert (block, resident, span) == (chunk, True, 512)
+        assert limit == 5 * fa._long_head_bytes(
+            l, d, 4, rope, chunk, 512, dv) // 4
+        ro, rl = ref(q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ro),
+                                   rtol=GTOL, atol=GTOL)
+        if with_lse:
+            np.testing.assert_allclose(np.asarray(lse), np.asarray(rl),
+                                       rtol=GTOL, atol=GTOL)
+        gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gf, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=GTOL, atol=GTOL)
+
+    @staticmethod
+    def _kernel_limits(l, d, dv):
+        """``vmem_limit_bytes`` of the forward and backward kernels of a
+        causal bf16 call, read off the traced ``pallas_call``s."""
+        from apex_tpu.analysis import pallas_lint
+        q = jnp.ones((1, l, 1, d), jnp.bfloat16)
+        v = jnp.ones((1, l, 1, dv), jnp.bfloat16)
+        grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))
+        return {c.name: c.vmem_limit for c in
+                pallas_lint.extract_pallas_calls(
+                    jax.make_jaxpr(grad)(q, q, v))}
+
+    def test_only_a_head_over_the_default_limit_sets_its_own(self):
+        """The gpt2 cells' call compiles with the parameters it always
+        had; the kanana cell's asks for its own estimate and a quarter."""
+        from apex_tpu.ops.pallas import flash_attention as fa
+        assert self._kernel_limits(1024, 64, 64) == {
+            "flash_fwd": None, "flash_bwd_fused": None}
+        limit = 5 * fa._long_head_bytes(8192, 192, 2, False, 512, 2048,
+                                        128) // 4
+        assert 48 << 20 < limit < 80 << 20
+        assert self._kernel_limits(8192, 192, 128) == {
+            "flash_fwd": limit, "flash_bwd_fused": limit}
+        # 2048 rows of 256 are over the default limit and no longer than
+        # a span: all rows resident, as under it, at their own estimate
+        limit = 5 * fa._resident_bytes(2048, 256, 2, False, 512) // 4
+        assert 16 << 20 < limit < 32 << 20
+        assert self._kernel_limits(2048, 256, 256) == {
+            "flash_fwd": limit, "flash_bwd_fused": limit}
+
+    def test_a_chip_with_less_vmem_keeps_the_grid_walk(self, monkeypatch):
+        """The geometry reads the chip: with 16 MiB of VMEM (a v4) the
+        kanana cell's head walks the grid, the gpt2 cells' stays."""
+        from apex_tpu.ops.pallas import flash_attention as fa
+        monkeypatch.setattr(fa, "_vmem_capacity", lambda: 16 << 20)
+        assert tuple(fa._geometry(8192, 192, 2, True, False, False, 128)) \
+            == (False, 1024, None, None)
+        assert tuple(fa._geometry(1024, 64, 2, True, True, False)) \
+            == (True, 512, None, None)
+        assert set(self._kernel_limits(8192, 192, 128).values()) == {None}
+
+    def test_off_the_chip_the_capacity_is_the_v5es(self):
+        from apex_tpu.ops.pallas import flash_attention as fa
+        assert fa._vmem_capacity() == 128 << 20
 
 
 def _lowered_op_names(fn, *args):

@@ -43,7 +43,7 @@ _X = jnp.ones((4 * 8, 128), jnp.float32)
 
 
 def _call(out_shape, in_map, out_map, grid, sem, kern=_copy_k,
-          scratch=(), **kw):
+          scratch=(), vmem_limit_bytes=None, **kw):
     """One-input one-output 8x128-block pallas_call fixture factory."""
     return pl.pallas_call(
         kern,
@@ -52,7 +52,8 @@ def _call(out_shape, in_map, out_map, grid, sem, kern=_copy_k,
         out_specs=pl.BlockSpec((8, 128), out_map),
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         scratch_shapes=list(scratch),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=sem),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=sem, vmem_limit_bytes=vmem_limit_bytes),
         interpret=True, **kw)(_X)
 
 
@@ -148,6 +149,20 @@ def test_vmem_overflow_fires_on_giant_scratch():
                 (4,), ("arbitrary",), kern=scratch_k,
                 scratch=[pltpu.VMEM((4096, 4096), jnp.float32)])  # 64 MiB
     assert "pallas-vmem-overflow" in _error_ids(rep)
+
+
+@pytest.mark.parametrize("limit_mib,overflows", [(96, False), (48, True)])
+def test_vmem_ceiling_is_the_calls_own_limit(limit_mib, overflows):
+    """A call that sets ``vmem_limit_bytes`` is held to what it asked
+    Mosaic for: the 64 MiB scratch that overflows the default ceiling
+    passes under 96 MiB and still overflows 48."""
+    def scratch_k(x_ref, o_ref, s_ref):
+        o_ref[...] = x_ref[...]
+    rep = _lint((4 * 8, 128), lambda i: (i, 0), lambda i: (i, 0),
+                (4,), ("arbitrary",), kern=scratch_k,
+                scratch=[pltpu.VMEM((4096, 4096), jnp.float32)],
+                vmem_limit_bytes=limit_mib << 20)
+    assert ("pallas-vmem-overflow" in _error_ids(rep)) == overflows
 
 
 def test_vmem_clean_twin_small_scratch():

@@ -201,6 +201,20 @@ def _flash_configs():
 
     cfgs.append((f"fwd+bwd b{b} l{l} h{h} d{d} causal rope resident",
                  resident_fwd_bwd, (q, q, q, cos, sin)))
+
+    # a long head (the kanana cell's call: 8192 rows, q and k at 192, v
+    # at 128): resident under a scoped-VMEM limit of its own, which is
+    # the ceiling its working set is held to
+    q = jnp.ones((1, 8192, 2, 192), bf16)
+    v = jnp.ones((1, 8192, 2, 128), bf16)
+
+    def long_head_fwd_bwd(q, k, v):
+        y, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal=True), q, k, v)
+        return vjp(y)
+
+    cfgs.append(("fwd+bwd b1 l8192 h2 d192/128 causal long head",
+                 long_head_fwd_bwd, (q, q, v)))
     return cfgs
 
 
