@@ -5,12 +5,7 @@ expectation they imply.
 floating-point work and bytes accessed — the *static* half of a
 roofline: from ``flops`` and ``bytes`` alone the arithmetic intensity
 and the best-case utilization of a given chip follow, before anything
-runs.  This pass records those numbers per lane, and the artifact
-audit (:func:`audit_floor_artifacts`) cross-checks the committed
-bench-gate floors against the same physics: a published floor that
-sits ABOVE the cost-model ceiling, or a measured number above it, is a
-lint error — the gate was calibrated against an impossible bar, and
-every future round would either trip it or (worse) trust it.
+runs.  This pass records those numbers per lane.
 
 Finding codes (``op`` field):
 
@@ -19,29 +14,15 @@ Finding codes (``op`` field):
 ``hbm-bytes``          info: cost-model bytes accessed
 ``roofline``           info: intensity + static ceiling utilization
                        (needs ``peak_flops`` / ``peak_hbm_bytes_per_s``)
-``floor-above-ceiling``  error: a committed floor exceeds the physical
-                       ceiling (roofline fraction / MFU > 1)
-``measured-above-ceiling``  error: a committed measurement exceeds the
-                       ceiling (bandwidth above HBM peak, MFU above 1,
-                       HFU below MFU)
 =====================  ==================================================
 """
 
 from __future__ import annotations
 
-import glob
-import json
-import os
-import re
 from typing import Dict, List, Optional
 
 from apex_tpu.analysis.core import PassContext, register_pass
 from apex_tpu.analysis.report import Finding
-
-#: measured numbers get this much slack over a hard ceiling before the
-#: audit calls them impossible: timer jitter and bytes-model rounding
-#: are real, sustained >5% over physics is not.
-MEASURE_TOLERANCE = 0.05
 
 
 def cost_table(compiled) -> Optional[Dict[str, float]]:
@@ -128,168 +109,6 @@ def cost_pass(ctx: PassContext,
             f"{exp['bound']}-bound, ceiling utilization "
             f"{exp['ceiling_util']:.3f} — any committed MFU floor for "
             f"this lane must sit under that",
-            op="roofline"))
-    return findings
-
-
-# ---------------------------------------------------------------------------
-# committed-artifact calibration audit
-
-
-def _rounds_desc(search_dir: str, pattern: str) -> List[str]:
-    rounds = []
-    for path in glob.glob(os.path.join(search_dir, pattern)):
-        m = re.search(r"_r(\d+)\.json$", path)
-        if m:
-            rounds.append((int(m.group(1)), path))
-    return [p for _, p in sorted(rounds, reverse=True)]
-
-
-def _load(path: str) -> Optional[dict]:
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-        return doc if isinstance(doc, dict) else None
-    except (OSError, ValueError):
-        return None
-
-
-def audit_kernel_artifact(doc: dict, name: str,
-                          floors: Optional[Dict[str, float]] = None,
-                          ) -> List[Finding]:
-    """Physics audit of one KERNELBENCH document: measured bandwidth
-    must sit under the recorded HBM peak, roofline fractions under 1,
-    and any published per-kernel floor under the ceiling too."""
-    findings: List[Finding] = []
-    peak_gbps = doc.get("hbm_gbps_peak")
-    for kname, rec in (doc.get("kernels") or {}).items():
-        if not isinstance(rec, dict):
-            continue
-        gbps, frac = rec.get("gbps"), rec.get("roofline_frac")
-        if peak_gbps and gbps and gbps > peak_gbps * (1 + MEASURE_TOLERANCE):
-            findings.append(Finding(
-                "cost", "error",
-                f"{name}: kernel {kname} records {gbps} GB/s, above "
-                f"the {peak_gbps} GB/s HBM peak — the bytes model or "
-                f"the peak table is miscalibrated",
-                op="measured-above-ceiling"))
-        if frac and frac > 1 + MEASURE_TOLERANCE:
-            findings.append(Finding(
-                "cost", "error",
-                f"{name}: kernel {kname} records roofline fraction "
-                f"{frac} > 1 — impossible; the gate memory is "
-                f"miscalibrated", op="measured-above-ceiling"))
-    for kname, floor in (floors or {}).items():
-        if floor > 1.0:
-            findings.append(Finding(
-                "cost", "error",
-                f"published roofline-fraction floor {floor} for kernel "
-                f"{kname} exceeds the cost-model ceiling (1.0) — no "
-                f"measurement can ever pass it",
-                op="floor-above-ceiling"))
-    return findings
-
-
-def audit_bench_artifact(doc: dict, name: str,
-                         mfu_floors: Optional[Dict[str, float]] = None,
-                         ) -> List[Finding]:
-    """Physics audit of one BENCH document: measured MFU ≤ 1, HFU ≥
-    MFU (hardware flops include rematerialization, never less than
-    model flops), and published MFU floors under the ceiling."""
-    findings: List[Finding] = []
-    configs = (doc.get("configs")
-               or (doc.get("parsed") or {}).get("configs") or {})
-    for cname, rec in configs.items():
-        if not isinstance(rec, dict):
-            continue
-        mfu, hfu = rec.get("mfu"), rec.get("hfu")
-        if mfu and mfu > 1 + MEASURE_TOLERANCE:
-            findings.append(Finding(
-                "cost", "error",
-                f"{name}: config {cname} records MFU {mfu} > 1 — "
-                f"impossible; flops model miscalibrated",
-                op="measured-above-ceiling"))
-        # hfu is not None, not truthiness: a recorded hfu of exactly
-        # 0.0 (broken hardware-flops counter) is the very case this
-        # audit exists for
-        if mfu and hfu is not None and hfu < mfu * (1 - MEASURE_TOLERANCE):
-            findings.append(Finding(
-                "cost", "error",
-                f"{name}: config {cname} records HFU {hfu} below MFU "
-                f"{mfu} — hardware flops can never undercut model "
-                f"flops; one of the two counters is wrong",
-                op="measured-above-ceiling"))
-    for cname, floor in (mfu_floors or {}).items():
-        if floor > 1.0:
-            findings.append(Finding(
-                "cost", "error",
-                f"published MFU floor {floor} for config {cname} "
-                f"exceeds the ceiling (1.0)",
-                op="floor-above-ceiling"))
-    return findings
-
-
-def audit_floor_artifacts(search_dir: str,
-                          kernel_floors: Optional[Dict[str, float]] = None,
-                          mfu_floors: Optional[Dict[str, float]] = None,
-                          ) -> List[Finding]:
-    """Cross-check the newest committed ``KERNELBENCH_r*.json`` and
-    ``BENCH_r*.json`` against the cost-model ceilings (see the module
-    docstring).  Measurements in the artifacts are always audited;
-    the published FLOOR tables are audited only when passed in —
-    this module deliberately never imports ``bench``/``tools``, so
-    callers supply their own tables (``bench.check_floor_calibration``
-    and ``tools/graph_lint.py`` both do)."""
-    findings: List[Finding] = []
-    # the floor tables are artifact-INDEPENDENT physics: a published
-    # floor above the ceiling must fail even when no artifact file
-    # loads (a corrupt newest round must never launder an impossible
-    # floor through a clean verdict)
-    findings += audit_kernel_artifact({}, "published floors",
-                                      floors=kernel_floors)
-    findings += audit_bench_artifact({}, "published floors",
-                                     mfu_floors=mfu_floors)
-    kpath = next(iter(_rounds_desc(search_dir, "KERNELBENCH_r*.json")),
-                 None)
-    if kpath:
-        doc = _load(kpath)
-        if doc is not None:
-            findings += audit_kernel_artifact(doc,
-                                              os.path.basename(kpath))
-        else:
-            findings.append(Finding(
-                "cost", "warning",
-                f"{os.path.basename(kpath)} is unreadable — kernel "
-                f"measurements NOT audited this round",
-                op="roofline"))
-    # newest BENCH round whose measured configs survived the artifact
-    # wrapper (older rounds keep the parsed block; a truncated tail
-    # records nothing auditable)
-    bpath = None
-    bench_rounds = _rounds_desc(search_dir, "BENCH_r*.json")
-    for cand in bench_rounds:
-        doc = _load(cand)
-        if doc is None:
-            continue
-        if (doc.get("configs")
-                or (doc.get("parsed") or {}).get("configs")):
-            findings += audit_bench_artifact(doc,
-                                             os.path.basename(cand))
-            bpath = cand
-            break
-    if bench_rounds and bpath is None:
-        findings.append(Finding(
-            "cost", "warning",
-            f"no readable BENCH_r*.json with measured configs (newest "
-            f"{os.path.basename(bench_rounds[0])}) — MFU measurements "
-            f"NOT audited this round", op="roofline"))
-    if not findings:
-        findings.append(Finding(
-            "cost", "info",
-            f"gate calibration audit: committed floors and "
-            f"measurements sit under the cost-model ceilings "
-            f"({os.path.basename(kpath) if kpath else 'no KERNELBENCH'}"
-            f", {os.path.basename(bpath) if bpath else 'no BENCH'})",
             op="roofline"))
     return findings
 

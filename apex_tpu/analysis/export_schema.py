@@ -12,9 +12,7 @@ gating lint verdict and a passing bitwise round trip (a contradictory
 verdict is schema-invalid, not just wrong), a refused lane must name
 the documented finding id that refused it, and the serve cold-start
 block's ``ok`` must agree with its own numbers against the
-``load_ratio <= COLD_START_RATIO_MAX`` gate ``bench.py`` reads from
-this artifact (bench and the artifact can never disagree: bench
-SOURCES the number here).
+``load_ratio <= COLD_START_RATIO_MAX`` bar.
 
 This module is deliberately **stdlib-only** (no jax import):
 ``gate_hygiene`` loads it directly by file path the same way it loads
@@ -139,8 +137,7 @@ def validate_export(doc) -> List[str]:
     cs = doc.get("cold_start")
     if not isinstance(cs, dict):
         problems.append("missing/invalid 'cold_start' object (the "
-                        "serve-lane compile-vs-load numbers bench.py "
-                        "sources)")
+                        "serve-lane compile-vs-load numbers)")
     else:
         lane = cs.get("lane")
         if not isinstance(lane, str) or not lane:

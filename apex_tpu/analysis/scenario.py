@@ -106,8 +106,7 @@ def _num(x) -> bool:
 
 def _check_prefix_block(name: str, prefix, problems: List[str]):
     """Validate one optional per-cell prefix-sharing block: the hit
-    rate must RE-DERIVE from the recorded probe/hit counts (the
-    PREFIXCACHE_r*.json discipline at cell granularity)."""
+    rate must RE-DERIVE from the recorded probe/hit counts."""
     if not isinstance(prefix, dict) or \
             not isinstance(prefix.get("probes"), int) or \
             not isinstance(prefix.get("hits"), int) or \

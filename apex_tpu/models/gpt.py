@@ -250,10 +250,10 @@ def train_toy_lm(cfg=None, steps: int = 50, period: int = 16):
     periodic token stream, in the bf16 O2 serving layout, plus the
     ``(8, 64)`` int32 training ids its prompts should come from.
 
-    The shared fixture behind every test/bench/tool that needs a
+    The shared fixture behind every test/tool that needs a
     model with REAL argmax margins (``tests/l0/test_serve_spec.py``,
     ``tests/l0/test_quant.py``'s tolerance checks,
-    ``bench.bench_serve_spec``, ``tools/serve_scenarios.py``): a
+    ``tools/serve_scenarios.py``): a
     random-init model's near-uniform logits put ulp/quantization
     noise above the margins — measuring tie-breaking, not the thing
     under test — and make speculative acceptance structurally

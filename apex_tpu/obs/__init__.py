@@ -14,8 +14,7 @@ instead of re-implemented inside each:
   the HLO metadata *and* captured xplanes, and span wall-durations
   feed the registry's histograms;
 - :mod:`apex_tpu.obs.xplane` — the xplane / chrome-trace parsing
-  library (extracted from ``tools/profile_step.py``; all profile
-  tools import it), with device-time aggregation, step markers, and
+  library (all profile tools import it), with device-time aggregation, step markers, and
   named-bucket attribution for ``tools/profile_decode.py``;
 - :mod:`apex_tpu.obs.reqtrace` — per-request lifecycle traces across
   the serving fleet (request ids minted at router admission, a closed
@@ -27,7 +26,7 @@ instead of re-implemented inside each:
   incident records ship as their validated ``flight`` field);
 - :mod:`apex_tpu.obs.fleet` — fleet-level registry merging (counter
   sums, bucket-union histogram quantiles, per-replica gauge tables) —
-  the ONE implementation ``bench.py`` and the serving tools share;
+  the ONE implementation the serving tools share;
 - :mod:`apex_tpu.obs.stepclass` — the shared compiled-HLO op
   classifiers (decode / serve-decode seven-bucket vocabulary, the
   pinned fwd/bwd/optimizer/collectives/host_gap train vocabulary) the

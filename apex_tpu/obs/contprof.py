@@ -1,9 +1,7 @@
 """Always-on continuous profiler + online op-level drift sentinel.
 
 Every profiling surface before this module was OFFLINE:
-``tools/profile_decode.py`` / ``tools/profile_step.py`` judge a
-capture after the fact, and the PR-13 timeline judges committed
-artifacts across rounds.  The live fleet's only online signals were
+``tools/profile_decode.py`` judges a capture after the fact.  The live fleet's only online signals were
 scalar metrics and SLO burn rates — an op-level regression (a new
 materialized copy, a fusion break, a collective gone sync) stayed
 invisible until the next offline round.  This module is the runtime

@@ -5,11 +5,9 @@ The disaggregated fleet (PR 10) runs one registry per engine — the
 prefill worker and every decode replica each own their counters,
 gauges and ``serve_decode_step_seconds`` histogram.  A fleet-level
 answer ("what is the fleet's decode p99?", "how many tokens did the
-fleet emit?") is a MERGE of those registries, and until this module
-the merge math lived as a private helper inside ``bench.py``
-(``_merged_decode_quantile``) that a production scrape could not
-import — exactly the private-percentile drift PR 7 killed for the
-single-engine case.  This module is that merge as a public API:
+fleet emit?") is a MERGE of those registries.  This module is that
+merge as a public API a production scrape can import — no private
+percentile math, as PR 7 settled for the single-engine case:
 
 - **counters sum** — ``serve_tokens_total`` over a fleet is the sum of
   every replica's counter (each emission increments exactly one
@@ -24,12 +22,11 @@ single-engine case.  This module is that merge as a public API:
   (:func:`gauge_table`), which is also what the router's admission
   control actually wants to look at.
 
-``bench.py``'s disagg config and ``tools/serve_disagg.py``'s artifact
-read their fleet percentiles through :func:`merged_quantile`, and
-``tools/trace_report.py`` sums its fleet token accounting through
-:func:`merge_registries` — bench, the committed artifacts, and a
-production scrape can never disagree on the merge math because there
-is exactly one copy of it.
+Fleet percentiles are read through :func:`merged_quantile`;
+``tools/trace_report.py`` sums its fleet token accounting, and the
+exposition endpoint its fleet view, through :func:`merge_registries` —
+the committed artifacts and a production scrape can never disagree on
+the merge math because there is exactly one copy of it.
 """
 
 from __future__ import annotations

@@ -32,8 +32,8 @@ step's *outputs*.
 Histograms are fixed-bucket (device-friendly: an ``observe`` is a
 ``searchsorted``, never a growing reservoir) and quantiles are
 interpolated from the cumulated bucket counts the way Prometheus's
-``histogram_quantile`` does — ``bench.py`` and the serve engine read
-p50/p99 through :meth:`Histogram.quantile` so the two can never
+``histogram_quantile`` does — the serve engine and the serving tools
+read p50/p99 through :meth:`Histogram.quantile` so they can never
 disagree on percentile math.
 
 Exports: :meth:`Registry.snapshot` (JSON document — the ``export``
@@ -210,7 +210,7 @@ class Histogram(_Instrument):
 
     def state(self) -> Tuple[np.ndarray, float, int, float]:
         """Opaque snapshot for windowed reads (``quantile(q,
-        since=state)`` — how ``bench.py`` isolates one offered-load
+        since=state)`` — how a caller isolates one offered-load
         level on a long-lived engine)."""
         return (self.counts.copy(), self.sum, self.count, self._max)
 

@@ -5,8 +5,8 @@ classifier built from the compiled HLO text — instruction name →
 named bucket, shape/metadata markers deciding the bucket.  Until this
 module the classifiers were private tool code: the decode shape
 classifier lived inside ``tools/profile_decode.py`` and the train
-tool (``tools/profile_step.py``) had no op-level vocabulary at all,
-only raw ``hlo_category`` tables.  The continuous profiler
+step had no op-level vocabulary at all, only raw ``hlo_category``
+tables.  The continuous profiler
 (:mod:`apex_tpu.obs.contprof`) runs the SAME bucketing online, inside
 the serving and training loops — so the classifiers move here, behind
 a library API the offline tools now import (private copies deleted,

@@ -1,12 +1,10 @@
 """xplane / chrome-trace attribution library.
 
-One parser for every profile-reading tool in the repo.  Three tools
-(``tools/profile_step.py``, ``tools/conv_attrib.py``,
-``tools/fusion_roofline.py``) each carried a copy of the xplane
-protobuf walk; this module is that walk extracted behind a library API
-so the "profile one step and act on the top hotspot" loop —
-and now ``tools/profile_decode.py``'s bucketed decode attribution —
-share one implementation whose behavior is pinned by a fixture test.
+One parser for every profile-reading tool in the repo: the xplane
+protobuf walk behind a library API, so ``tools/conv_attrib.py``'s
+per-layer attribution and ``tools/profile_decode.py``'s bucketed decode
+attribution share one implementation whose behavior is pinned by a
+fixture test.
 
 Sources, in preference order:
 
@@ -125,9 +123,8 @@ def op_times(logdir: str) -> OpTimes:
     planes = load_planes(logdir)
     if not planes:
         if _xplane_pb2() is None:
-            # the historical profile_step warning: the JSON export is
-            # LOSSY (op events can be missing for large programs) —
-            # a silent fallback would print confident tables off an
+            # the JSON export is LOSSY (op events can be missing for
+            # large programs) — a silent fallback would print confident tables off an
             # incomplete capture
             print("warning: xplane proto unavailable; falling back to "
                   "the lossy chrome-trace JSON parser (install "

@@ -57,9 +57,7 @@ ADAM_PAD = STREAM_TILE_ROWS * STREAM_LANES
 def adam_geometry(n: int, *, with_copy: bool,
                   block_rows: "int | None" = None) -> geometry.StreamGeometry:
     """Resolved streaming geometry for :func:`packed_adam` at ``n``
-    elements — THE function the kernel, its tests, and
-    ``tools/kernel_bench.py`` share, so the artifact records exactly the
-    shape the kernel ran."""
+    elements — THE function the kernel and its tests share."""
     rows = n // _ADAM_LANES
     # 4 fp32 reads (p, m, v, g) + 3 fp32 writes + optional half writeback
     row_bytes = _ADAM_LANES * (7 * 4 + (2 if with_copy else 0))

@@ -3,7 +3,7 @@
 The idea: ``apex_tpu.amp.scaler.all_finite`` lowers to ~one
 reduce-to-scalar fusion per gradient leaf (~50 for gpt-small), and a
 profile of the d=64 train step shows an ``is-finite_reduce_fusion.*`` +
-``cond`` bucket worth ~16% of device time (``D64_DECOMPOSE_r05.json``).
+``cond`` bucket worth ~16% of device time (a round-5 decomposition).
 This module packs the leaves per dtype into one flat buffer and checks
 them with ONE Pallas pass (the read-only half of ``_scale_kernel``'s
 in-pass overflow flag, reference ``multi_tensor_scale_kernel.cu:57-71``)

@@ -3,14 +3,13 @@
 Every optimizer/norm kernel in this package is an elementwise or
 row-reduction pass whose roofline is HBM bandwidth, and the knob that
 decides how close it gets is the ROW-BLOCK geometry: how many rows of
-the 2-D flat-buffer view one grid step streams through VMEM.  Round 5
-measured the fused Adam kernel gaining +23% going from 8-row to 32-row
-blocks on v5e (KERNELBENCH_r05 vs the 8-row floor; fewer grid steps
-amortize per-step DMA setup), while the LAMB kernels — pinned to one
-(8, 128) chunk tile per step — sat at 0.13-0.17 of peak on the same
-chip where mt_axpby's (512, 128) blocks reached 0.81.  This module
-generalizes that measurement into one selector all streaming kernels
-share, instead of each kernel hard-coding its own magic block.
+the 2-D flat-buffer view one grid step streams through VMEM.  Fewer
+grid steps amortize the per-step DMA setup: larger blocks replaced the
+fused Adam kernel's 8-row blocks and the LAMB kernels' single (8, 128)
+chunk tile per step (the gain is unmeasured on this benchmark, whose
+cells run Adam as XLA fusions).  This module is the one selector all
+streaming kernels share, instead of each kernel hard-coding its own
+magic block.
 
 Two selection surfaces:
 
@@ -30,8 +29,8 @@ Two selection surfaces:
 The VMEM budget is half of Mosaic's default 16 MiB scoped limit (the
 other half belongs to the kernel body's own working set),
 overridable via ``APEX_TPU_VMEM_BUDGET_MB`` for experiments; per-call
-geometry overrides (the ``block_rows=`` / ``chunks_per_block=`` kwargs
-on the kernels) are what ``tools/kernel_bench.py --autotune`` sweeps.
+geometry overrides are the ``block_rows=`` / ``chunks_per_block=``
+kwargs on the kernels.
 
 Selection never changes element math — blocks partition the same rows
 with the same per-chunk scalars — so the L1 conformance contract
@@ -147,9 +146,7 @@ def pad_table(t: jax.Array, slots: int) -> jax.Array:
 
 
 class StreamGeometry(NamedTuple):
-    """Resolved geometry of one streaming pallas_call — recorded by
-    ``tools/kernel_bench.py`` per kernel so every artifact states the
-    shape it measured."""
+    """Resolved geometry of one streaming pallas_call."""
 
     block_rows: int      # rows per grid step (chunks_per_block * chunk rows
                          # for chunk-tabled kernels)

@@ -18,9 +18,8 @@ the plain-lr fallback when either norm is zero).  All arithmetic is fp32.
 
 Memory movement (round 6 retune): one grid step streams
 ``chunks_per_block`` chunks (shared selector,
-:mod:`apex_tpu.ops.pallas.geometry`) instead of a single (8, 128) tile —
-the geometry that left the stages at 0.13–0.17 of HBM peak while
-mt_axpby's big blocks hit 0.81 on the same chip (KERNELBENCH_r05).  The
+:mod:`apex_tpu.ops.pallas.geometry`) instead of a single (8, 128) tile
+(unmeasured on this benchmark: no cell runs LAMB).  The
 chunk sub-blocks are statically unrolled so each keeps its own SMEM
 table scalars, and ragged chunk counts ride Mosaic's masked last block
 (the scalar tables are padded to the grid so the dead tail indexes real
@@ -88,8 +87,7 @@ def stage1_geometry(n: int, chunk_size: int,
                     chunks_per_block: "int | None" = None
                     ) -> geometry.StreamGeometry:
     """Stage-1 streaming geometry (7 fp32 streams: g+p+m+v in,
-    u+m+v out) — shared by the kernel, its tests, and
-    ``tools/kernel_bench.py``."""
+    u+m+v out) — shared by the kernel and its tests."""
     return geometry.chunked_geometry(n, chunk_size,
                                      row_bytes=_LANES * 4 * 7,
                                      lanes=_LANES,

@@ -239,8 +239,7 @@ class DecodeReplica:
             "serve_decode_step_seconds")
         #: histogram window mark taken after the replica's FIRST
         #: decode step (the compile): the p99 the router ranks and
-        #: exports is steady-state, exactly how bench.py windows the
-        #: same histogram — a compile outlier must not steer
+        #: exports is steady-state — a compile outlier must not steer
         #: admissions away from a fresh replica for its first 100
         #: steps
         self._p99_window = None

@@ -157,7 +157,7 @@ class SlotScheduler:
         self.allocator = BlockAllocator(num_blocks)
         #: cross-request prefix sharing (see module docstring); the
         #: probe/hit counters below are host bookkeeping the prefix
-        #: gauges and the PREFIXCACHE artifact re-derive from
+        #: gauges re-derive from
         self.prefix_cache = prefix_cache
         self.prefix_probes = 0
         self.prefix_hits = 0

@@ -88,8 +88,7 @@ class FleetSlices:
             len(m.devices.ravel()) for m in self.decode)
 
     def describe(self) -> dict:
-        """JSON-friendly slice table (the SERVE_DISAGG artifact's
-        ``topology`` block cites it)."""
+        """JSON-friendly slice table."""
         return {
             "prefill": [d.id for d in self.prefill.devices.ravel()],
             "decode": [[d.id for d in m.devices.ravel()]

@@ -1,6 +1,7 @@
 """Published peak rates of the chips this repo has run on — the one
-table behind every MFU, bandwidth fraction and roofline share
-(``bench.py``, ``tools/kernel_bench.py``, ``tools/fusion_roofline.py``).
+table behind the bandwidth fractions and roofline shares of the tools
+(``tools/conv_attrib.py``; the benchmark keeps its own copy,
+``benchmark/peaks.py``, which only a ``benchmark`` issue can merge).
 
 Keyed by ``jax.devices()[0].device_kind`` exactly as the chip reports
 it.  A device that is not in the table is an error, never a default: a

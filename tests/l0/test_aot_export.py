@@ -530,36 +530,3 @@ def test_committed_export_artifact_validates():
     assert doc["cold_start"]["lane"] == "serve_step"
     assert doc["cold_start"]["ok"] is True
     assert doc["cold_start"]["load_ratio"] <= 0.5
-
-
-# ---------------------------------------------------------------------------
-# bench sources the cold-start gate from the artifact
-# ---------------------------------------------------------------------------
-
-def test_bench_cold_start_gate_reads_artifact(tmp_path):
-    import bench
-    # no artifact → nothing to gate
-    assert bench.check_export_cold_start(str(tmp_path)) is None
-    # a passing artifact → ok, numbers surfaced verbatim
-    doc = _valid_export_doc()
-    (tmp_path / "EXPORT_r01.json").write_text(json.dumps(doc))
-    out = bench.check_export_cold_start(str(tmp_path))
-    assert out["ok"] is True and out["load_ratio"] == 0.03
-    assert out["artifact"] == "EXPORT_r01.json"
-    # the newest round wins, and a violating ratio fails the gate
-    # even when the artifact CLAIMS ok (bench re-derives the verdict)
-    bad = _valid_export_doc()
-    bad["cold_start"].update(load_ratio=0.9, ok=True)
-    (tmp_path / "EXPORT_r02.json").write_text(json.dumps(bad))
-    out2 = bench.check_export_cold_start(str(tmp_path))
-    assert out2["artifact"] == "EXPORT_r02.json"
-    assert out2["ok"] is False
-    # ...and the absolute gate trips through gate_exit_code with or
-    # without a --compare baseline
-    rc = bench.gate_exit_code({"ok": True, "export_cold_start": out2},
-                              compare_given=False)
-    assert rc == 2
-    rc_ok = bench.gate_exit_code({"ok": True,
-                                  "export_cold_start": out},
-                                 compare_given=False)
-    assert rc_ok == 0

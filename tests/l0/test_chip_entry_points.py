@@ -4,6 +4,7 @@ refusals, and the reader of Mosaic kernel names."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 import tempfile
@@ -84,6 +85,24 @@ def test_no_other_code_sets_a_compile_cache_directory():
         for p in REPO.glob(pattern)
         if CACHE_OPTION in p.read_text()]
     assert setters == ["apex_tpu/utils/compile_cache.py"]
+
+
+def test_the_benchmark_is_the_one_under_benchmark():
+    """``python3 -m benchmark.run`` is the measurement and
+    ``chip_smoke.py`` the bring-up check: no other entry point stands in
+    the root, and nothing imports the harnesses that once stood beside
+    them."""
+    assert sorted(p.name for p in REPO.glob("*.py")) == [
+        "__graft_entry__.py", "chip_smoke.py", "setup.py"]
+    gone = re.compile(
+        r"^\s*(?:import|from)\s+(?:tools\.)?(?:bench|kernel_bench|"
+        r"bench_variance|perf_timeline|fusion_roofline)\b", re.M)
+    importers = [
+        str(p.relative_to(REPO))
+        for top in ("apex_tpu", "tools", "tests", "examples")
+        for p in (REPO / top).rglob("*.py")
+        if gone.search(p.read_text())]
+    assert importers == []
 
 
 def test_peak_table_resolves_the_kind_the_chip_reports():

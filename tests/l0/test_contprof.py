@@ -250,8 +250,7 @@ def test_a_cond_outside_the_optimizer_step_is_not_the_optimizer():
 
 def test_train_classifier_on_real_compiled_step():
     """The real amp mlp train step classifies non-trivially: forward,
-    backward, AND optimizer ops all present (the graph_lint lowering
-    profile_step's --train-buckets lane uses)."""
+    backward, AND optimizer ops all present (on graph_lint's lowering)."""
     sys.path.insert(0, str(REPO / "tools"))
     import graph_lint
     step, args, _ = graph_lint.build_train_step("mlp", opt_level="O2")
@@ -593,21 +592,6 @@ def test_committed_obs_r03_contprof_lane():
     assert cp["overhead_pct"] <= 1.0
     assert "serve_step_contprof" in doc["syncs"]["lanes"]
     assert doc["syncs"]["clean"] is True
-
-
-# ---------------------------------------------------------------------------
-# timeline adapter
-# ---------------------------------------------------------------------------
-
-def test_timeline_adapter_ingests_profile_drift():
-    from apex_tpu.analysis import timeline
-    assert "PROFILE_DRIFT" in timeline.ADAPTERS
-    rows = timeline.ADAPTERS["PROFILE_DRIFT"](_valid_doc(), {})
-    by = {(c, m): v for c, m, v in rows}
-    assert by[("clean", "drifts")] == 0.0
-    assert by[("seeded", "drifts")] == 1.0
-    assert by[("seeded", "windows")] == 3.0
-    assert ("seeded:last_window", "kv_read") in by
 
 
 # ---------------------------------------------------------------------------

@@ -371,27 +371,3 @@ def test_graph_lint_refuses_detlint_with_families(tmp_path):
 def test_graph_lint_refuses_detlint_with_budget(tmp_path):
     _refuses(["--emit-json", str(tmp_path / "DETLINT_r09.json"),
               "--passes", "determinism", "--memory-budget", "1.0"])
-
-
-def test_kernel_bench_refuses_detlint_name(tmp_path):
-    import kernel_bench
-    out = str(tmp_path / "DETLINT_r09.json")
-    with pytest.raises(SystemExit) as e:
-        kernel_bench.main(["--out", out, "--tiny"])
-    assert e.value.code == 2
-    assert not Path(out).exists()
-
-
-# ---------------------------------------------------------------------------
-# the timeline ingests the family (a committed round can't go unseen)
-# ---------------------------------------------------------------------------
-
-def test_timeline_adapter_ingests_detlint():
-    from apex_tpu.analysis import timeline
-    assert "DETLINT" in timeline.ADAPTERS
-    rows = timeline.ADAPTERS["DETLINT"](_load_artifact(), None)
-    metrics = {(c, m) for c, m, _v in rows}
-    assert ("lane:decode_b1", "lint_clean") in metrics
-    assert ("pair:decode_b1|decode_b8", "cleared") in metrics
-    assert ("gate", "lanes_clean_frac") in metrics
-    assert ("gate", "pairs_ok_frac") in metrics

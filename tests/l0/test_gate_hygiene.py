@@ -2,8 +2,7 @@
 
 The repo-level test IS the tier-1 wiring (VERDICT r5 weak #7): a round
 whose gate-baseline artifacts are modified-but-uncommitted fails the
-suite, so the ladder/kernel-gate memory can never drift silently past a
-green tier-1.  The unit tests pin the verdict classes on throwaway git
+suite, so the gate memory can never drift silently past a green tier-1.  The unit tests pin the verdict classes on throwaway git
 repos.
 """
 
@@ -45,20 +44,20 @@ def test_clean_repo_passes(tmp_repo):
 
 
 def test_modified_baseline_fails(tmp_repo):
-    (tmp_repo / "BENCH_LADDER_BASELINES.json").write_text('{"drift": 1}')
+    (tmp_repo / "SCALING_SWEEP.json").write_text('{"drift": 1}')
     verdict = gate_hygiene.check(str(tmp_repo))
     assert not verdict["ok"]
-    assert verdict["dirty"] == ["BENCH_LADDER_BASELINES.json"]
+    assert verdict["dirty"] == ["SCALING_SWEEP.json"]
     assert gate_hygiene.main(["--repo", str(tmp_repo)]) == 1
 
 
 def test_untracked_round_artifact_fails(tmp_repo):
-    (tmp_repo / "KERNELBENCH_r06.json").write_text("{}")
+    (tmp_repo / "DETLINT_r06.json").write_text("{}")
     verdict = gate_hygiene.check(str(tmp_repo))
     assert not verdict["ok"]
-    assert verdict["untracked"] == ["KERNELBENCH_r06.json"]
+    assert verdict["untracked"] == ["DETLINT_r06.json"]
     # ...and committing it restores green
-    _git(tmp_repo, "add", "KERNELBENCH_r06.json")
+    _git(tmp_repo, "add", "DETLINT_r06.json")
     _git(tmp_repo, "commit", "-q", "-m", "r06 artifact")
     assert gate_hygiene.check(str(tmp_repo))["ok"]
 
@@ -78,7 +77,7 @@ def test_non_repo_records_skip(tmp_path):
 
 def test_non_gate_files_ignored(tmp_repo):
     (tmp_repo / "scratch.json").write_text("{}")
-    (tmp_repo / "KERNELBENCH.json").write_text("{}")  # un-numbered out
+    (tmp_repo / "DETLINT.json").write_text("{}")  # un-numbered out
     assert gate_hygiene.check(str(tmp_repo))["ok"]
 
 
@@ -118,7 +117,7 @@ def test_valid_incident_passes_schema(tmp_repo):
 
 def test_uncommitted_incident_artifact_fails(tmp_repo):
     """A fresh INCIDENT_rN.json is round evidence the moment it exists —
-    parked-but-untracked must fail like the KERNELBENCH artifacts do."""
+    parked-but-untracked must fail like every round artifact."""
     _incidents_module(tmp_repo)
     (tmp_repo / "INCIDENT_r08_new.json").write_text(json.dumps({
         "status": "recovered", "utc": "2026-08-03T00:00:00Z",
@@ -188,7 +187,7 @@ def test_valid_memlint_passes_schema(tmp_repo):
 
 def test_uncommitted_memlint_artifact_fails(tmp_repo):
     """A fresh MEMLINT_rN.json is gate memory the moment it exists —
-    parked-but-untracked must fail like the KERNELBENCH artifacts do."""
+    parked-but-untracked must fail like every round artifact."""
     _memlint_module(tmp_repo)
     (tmp_repo / "MEMLINT_r05_new.json").write_text(
         json.dumps(_valid_memlint()))
@@ -466,116 +465,6 @@ def test_repo_export_validates():
     """The committed EXPORT artifact is the schema's reference
     instance; it must stay valid."""
     assert gate_hygiene._validate_exports(str(REPO)) == []
-
-
-# ---------------------------------------------------------------------------
-# ISSUE 11: SERVE_DISAGG_r*.json is gate memory too
-# ---------------------------------------------------------------------------
-
-def _valid_serve_disagg():
-    return {
-        "round": 1, "platform": "cpu",
-        "config": {"model": "gpt_tiny", "concurrency": 16,
-                   "prefill": 64, "new_tokens": 16, "block_size": 4},
-        "topology": {"n_devices": 16, "transfer": "ship",
-                     "prefill_devices": [0],
-                     "replica_devices": [[1], [2]]},
-        "mono": {"num_slots": 16, "tok_s": 2000.0, "p50_ms": 8.0,
-                 "p99_ms": 12.0, "steps": 14, "retraces": 1},
-        "disagg": {"slots_per_replica": 8, "n_replicas": 2,
-                   "tok_s": 1600.0, "p50_ms": 4.0, "p99_ms": 6.0,
-                   "per_replica": [{"steps": 14, "p50_ms": 4.0,
-                                    "p99_ms": 6.0}] * 2,
-                   "kv_transfer_bytes": 655488, "shipments": 16,
-                   "reroutes": 0},
-        "chaos": {"killed_replica": 0, "rerouted": 2,
-                  "bitwise_ok": True},
-        "gate": {"p99_ok": True, "ok": True},
-    }
-
-
-def test_committed_serve_disagg_validated_against_schema(tmp_repo):
-    _analysis_module(tmp_repo, "serve_disagg")
-    (tmp_repo / "SERVE_DISAGG_r07_bad.json").write_text('{"round": 7}')
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "bad serve-disagg")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert not verdict["ok"]
-    assert any("SERVE_DISAGG_r07_bad.json" in p
-               for p in verdict["invalid_serve_disaggs"])
-    assert gate_hygiene.main(["--repo", str(tmp_repo)]) == 1
-
-
-def test_serve_disagg_contradictory_verdict_fails_hygiene(tmp_repo):
-    """The p99 gate verdict must be derivable from its own numbers: a
-    record claiming p99_ok while disagg p99 exceeds mono p99 fails
-    hygiene — the A/B cannot rot into an unearned 'ok'."""
-    _analysis_module(tmp_repo, "serve_disagg")
-    doc = _valid_serve_disagg()
-    doc["disagg"]["p99_ms"] = 20.0      # over mono's 12.0, gate says ok
-    (tmp_repo / "SERVE_DISAGG_r08_lie.json").write_text(json.dumps(doc))
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "contradictory serve-disagg")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert any("CONTRADICTORY" in p
-               for p in verdict["invalid_serve_disaggs"])
-
-
-def test_serve_disagg_overlapping_slices_fail_hygiene(tmp_repo):
-    """Disjointness is the topology's whole claim: shared devices
-    between the prefill slice and a decode replica are schema-invalid
-    (overlap fakes the disaggregation)."""
-    _analysis_module(tmp_repo, "serve_disagg")
-    doc = _valid_serve_disagg()
-    doc["topology"]["replica_devices"] = [[0], [2]]   # 0 = prefill dev
-    (tmp_repo / "SERVE_DISAGG_r09_overlap.json").write_text(
-        json.dumps(doc))
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "overlapping slices")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert any("OVERLAP" in p for p in verdict["invalid_serve_disaggs"])
-
-
-def test_serve_disagg_chaos_failure_breaks_ok(tmp_repo):
-    """gate.ok over a failed chaos drill is contradictory: the fleet
-    gate includes the failure semantics, not just the latency win."""
-    _analysis_module(tmp_repo, "serve_disagg")
-    doc = _valid_serve_disagg()
-    doc["chaos"]["bitwise_ok"] = False   # gate.ok still True
-    (tmp_repo / "SERVE_DISAGG_r10_chaos.json").write_text(
-        json.dumps(doc))
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "chaos contradiction")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert any("CONTRADICTORY" in p
-               for p in verdict["invalid_serve_disaggs"])
-
-
-def test_valid_serve_disagg_passes_and_untracked_fails(tmp_repo):
-    _analysis_module(tmp_repo, "serve_disagg")
-    (tmp_repo / "SERVE_DISAGG_r11_ok.json").write_text(
-        json.dumps(_valid_serve_disagg()))
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert not verdict["ok"]
-    assert verdict["untracked"] == ["SERVE_DISAGG_r11_ok.json"]
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "good serve-disagg")
-    assert gate_hygiene.check(str(tmp_repo))["ok"]
-
-
-def test_repo_serve_disagg_validates():
-    """The committed SERVE_DISAGG artifact is the schema's reference
-    instance; it must stay valid (and its gate must HOLD — the c16
-    acceptance bar rides this assertion)."""
-    assert gate_hygiene._validate_serve_disaggs(str(REPO)) == []
-    arts = sorted(REPO.glob("SERVE_DISAGG_r*.json"))
-    assert arts, "the disagg gate artifact must be committed"
-    doc = json.loads(arts[-1].read_text())
-    assert doc["gate"]["ok"] is True
-    assert doc["disagg"]["p99_ms"] <= doc["mono"]["p99_ms"]
-    assert doc["chaos"]["bitwise_ok"] is True
-    assert doc["topology"]["n_devices"] >= 16
-    assert doc["config"]["concurrency"] >= 16
 
 
 def test_real_committed_convergence_artifacts_validate():
@@ -883,146 +772,6 @@ def test_repo_trace_validates():
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 14: recorded-variance + perf-timeline artifacts are gate memory
-# ---------------------------------------------------------------------------
-
-def _valid_variance():
-    vals = [1.0, 1.1, 0.9, 1.05, 0.95]
-    mean = sum(vals) / len(vals)
-    std = (sum((v - mean) ** 2 for v in vals) / (len(vals) - 1)) ** 0.5
-    return {
-        "platform": "tpu", "device_kind": "v5e", "tiny": False,
-        "round": 7,
-        "entries": {"kernel:fused_adam": {
-            "metric": "ms_per_step", "n": 5, "values": vals,
-            "mean": round(mean, 6), "min": 0.9, "max": 1.1,
-            "std": round(std, 6),
-            "rel_spread": round((1.1 - 0.9) / mean, 4)}},
-    }
-
-
-def test_committed_variance_validated_against_schema(tmp_repo):
-    _analysis_module(tmp_repo, "variance")
-    (tmp_repo / "BENCH_VARIANCE_r07.json").write_text('{"tiny": 1}')
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "bad variance")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert not verdict["ok"]
-    assert any("BENCH_VARIANCE_r07.json" in p
-               for p in verdict["invalid_variances"])
-    assert gate_hygiene.main(["--repo", str(tmp_repo)]) == 1
-
-
-def test_variance_summary_must_derive_from_samples(tmp_repo):
-    """A typed-in spread wide enough to excuse a floor drop is
-    rejected: mean/std/rel_spread must re-derive from the recorded
-    values."""
-    _analysis_module(tmp_repo, "variance")
-    doc = _valid_variance()
-    doc["entries"]["kernel:fused_adam"]["rel_spread"] = 0.9
-    (tmp_repo / "BENCH_VARIANCE_r08.json").write_text(json.dumps(doc))
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "typed-in spread")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert any("CONTRADICTORY" in p and "rel_spread" in p
-               for p in verdict["invalid_variances"])
-
-
-def test_valid_variance_passes_and_untracked_fails(tmp_repo):
-    _analysis_module(tmp_repo, "variance")
-    (tmp_repo / "BENCH_VARIANCE_r09.json").write_text(
-        json.dumps(_valid_variance()))
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert not verdict["ok"]        # parked-but-untracked
-    assert verdict["untracked"] == ["BENCH_VARIANCE_r09.json"]
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "variance round")
-    assert gate_hygiene.check(str(tmp_repo))["ok"]
-
-
-def _valid_timeline(tmp_repo):
-    """A minimal internally-consistent timeline covering the tmp
-    repo's committed round artifacts (none beyond what the caller
-    adds)."""
-    coverage = {}
-    series = {}
-    sys.path.insert(0, str(REPO))
-    from apex_tpu.analysis import timeline as tl
-    for name in sorted(p.name for p in tmp_repo.glob("*_r*.json")):
-        parsed = tl.parse_artifact_name(name)
-        if parsed is None or parsed[0] == "TIMELINE":
-            continue
-        coverage.setdefault(parsed[0],
-                            {"files": [], "rows": 0})["files"].append(
-            name)
-    series["BENCH|c|tok_s"] = {
-        "family": "BENCH", "config": "c", "metric": "tok_s",
-        "points": [{"round": 1, "value": 100.0, "commit": None}]}
-    return {"round": 1, "head": None,
-            "bands": {"default": 0.03, "per_series": {}},
-            "series": series, "regressions": [],
-            "coverage": coverage or {"BENCH": {"files": [],
-                                               "rows": 0}},
-            "gate": {"regressions": 0, "ok": True}}
-
-
-def test_committed_timeline_validated_against_schema(tmp_repo):
-    _analysis_module(tmp_repo, "timeline")
-    (tmp_repo / "TIMELINE_r07.json").write_text('{"round": "x"}')
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "bad timeline")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert not verdict["ok"]
-    assert any("TIMELINE_r07.json" in p
-               for p in verdict["invalid_timelines"])
-
-
-def test_newest_timeline_held_to_coverage_completeness(tmp_repo):
-    """The staleness lint: a new committed round artifact the newest
-    timeline never ingested fails hygiene — the timeline must be
-    regenerated in the same round that adds gate artifacts."""
-    _analysis_module(tmp_repo, "timeline")
-    doc = _valid_timeline(tmp_repo)
-    (tmp_repo / "TIMELINE_r08.json").write_text(json.dumps(doc))
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "timeline round")
-    assert gate_hygiene.check(str(tmp_repo))["ok"]
-    # a new artifact lands without a timeline refresh -> STALE
-    (tmp_repo / "KERNELBENCH_r33.json").write_text("{}")
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "new round artifact")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert not verdict["ok"]
-    assert any("STALE" in p and "KERNELBENCH_r33" in p
-               for p in verdict["invalid_timelines"])
-    # refreshing the timeline restores green (only the NEWEST round
-    # is held to the checkout; the old round stays internally valid)
-    doc2 = _valid_timeline(tmp_repo)
-    doc2["round"] = 9
-    (tmp_repo / "TIMELINE_r09.json").write_text(json.dumps(doc2))
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "refreshed timeline")
-    assert gate_hygiene.check(str(tmp_repo))["ok"]
-
-
-def test_repo_variance_and_timeline_validate():
-    """The committed BENCH_VARIANCE_r01 + TIMELINE_r01 are the
-    schemas' reference instances: valid against this checkout, the
-    timeline covering every committed family, its regression table
-    carrying the two known tpu-heads drops."""
-    assert gate_hygiene._validate_variances(str(REPO)) == []
-    assert gate_hygiene._validate_timelines(str(REPO)) == []
-    arts = sorted(REPO.glob("TIMELINE_r*.json"))
-    assert arts, "the timeline gate artifact must be committed"
-    doc = json.loads(arts[-1].read_text())
-    assert {r["series"] for r in doc["regressions"]} == {
-        "BENCH|gpt_small_tpu_heads_o2|tok_s",
-        "BENCH|bert_large_tpu_heads_lamb_o2|seq_s"}
-    assert sorted(REPO.glob("BENCH_VARIANCE_r*.json")), \
-        "the variance gate artifact must be committed"
-
-
-# ---------------------------------------------------------------------------
 # PROFILE_DRIFT_r*.json — the continuous-profile drift artifacts
 # ---------------------------------------------------------------------------
 
@@ -1181,131 +930,6 @@ def test_repo_fleetlint_validates():
     assert gate_hygiene._validate_fleetlints(str(REPO)) == []
     assert sorted(REPO.glob("FLEETLINT_r*.json")), \
         "the fleet SPMD gate artifact must be committed"
-
-
-# ---------------------------------------------------------------------------
-# ISSUE 17: PREFIXCACHE_r*.json — cross-request prefix sharing is gate memory
-# ---------------------------------------------------------------------------
-
-def _valid_prefixcache():
-    # spans: one cold miss, two partial hits, one full-prompt CoW match
-    # (dispatched floored at 1 — the CoW rewrite re-runs one token)
-    spans = [
-        {"uid": "q0", "prompt_len": 16, "matched": 0, "dispatched": 16},
-        {"uid": "q1", "prompt_len": 16, "matched": 8, "dispatched": 8},
-        {"uid": "q2", "prompt_len": 16, "matched": 8, "dispatched": 8},
-        {"uid": "q3", "prompt_len": 16, "matched": 16, "dispatched": 1},
-    ]
-    return {
-        "round": 1, "platform": "cpu",
-        "config": {"model": "gpt_tiny", "concurrency": 4,
-                   "system_prompt_tokens": 8, "prefill": 16,
-                   "new_tokens": 4, "block_size": 4},
-        "sharing": {
-            "prefill_chunks": 5, "prefill_tokens_dispatched": 33,
-            "admitted_requests": 4, "peak_live_blocks": 10,
-            "admitted_requests_per_block": 0.4,
-            "p50_ms": 1.9, "p99_ms": 3.2, "retraces": 1,
-            "prefix": {"probes": 4, "hits": 3, "hit_rate": 0.75,
-                       "hit_tokens": 31, "cow_copies": 1,
-                       "shared_blocks_peak": 4, "cached_evictions": 0,
-                       "requests": spans}},
-        "baseline": {
-            "prefill_chunks": 8, "prefill_tokens_dispatched": 64,
-            "admitted_requests": 4, "peak_live_blocks": 16,
-            "admitted_requests_per_block": 0.25,
-            "p50_ms": 1.8, "p99_ms": 3.1, "retraces": 1},
-        "bitwise_ok": True,
-        "gate": {"hit_rate_ok": True, "ab_ok": True,
-                 "bitwise_ok": True, "ok": True},
-    }
-
-
-def test_committed_prefixcache_validated_against_schema(tmp_repo):
-    _analysis_module(tmp_repo, "prefixcache")
-    (tmp_repo / "PREFIXCACHE_r07_bad.json").write_text('{"round": 7}')
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "bad prefixcache")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert not verdict["ok"]
-    assert any("PREFIXCACHE_r07_bad.json" in p
-               for p in verdict["invalid_prefixcaches"])
-    assert gate_hygiene.main(["--repo", str(tmp_repo)]) == 1
-
-
-def test_prefixcache_span_contradiction_fails_hygiene(tmp_repo):
-    """A span claiming a full-prompt match re-dispatched NOTHING is the
-    lie the schema exists to reject: dispatched must equal
-    max(prompt_len - matched, 1) — the CoW rewrite always re-runs one
-    token, so 'free' full hits cannot be typed in."""
-    _analysis_module(tmp_repo, "prefixcache")
-    doc = _valid_prefixcache()
-    doc["sharing"]["prefix"]["requests"][3]["dispatched"] = 0
-    (tmp_repo / "PREFIXCACHE_r08_span.json").write_text(json.dumps(doc))
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "free full hit")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert not verdict["ok"]
-    assert any("CONTRADICTORY" in p and "CoW" in p
-               for p in verdict["invalid_prefixcaches"])
-
-
-def test_prefixcache_hit_tokens_must_derive_from_spans(tmp_repo):
-    """The headline skipped-token total must BE the span sum — an
-    inflated hit_tokens (a faked saving) is rejected by re-derivation."""
-    _analysis_module(tmp_repo, "prefixcache")
-    doc = _valid_prefixcache()
-    doc["sharing"]["prefix"]["hit_tokens"] = 999
-    (tmp_repo / "PREFIXCACHE_r09_fab.json").write_text(json.dumps(doc))
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "inflated hit tokens")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert any("CONTRADICTORY" in p and "hit_tokens" in p
-               for p in verdict["invalid_prefixcaches"])
-
-
-def test_prefixcache_ab_verdict_must_derive_from_arms(tmp_repo):
-    """gate.ab_ok over a baseline that dispatched FEWER tokens than the
-    sharing arm is an unearned win; the verdict must re-derive."""
-    _analysis_module(tmp_repo, "prefixcache")
-    doc = _valid_prefixcache()
-    doc["baseline"]["prefill_tokens_dispatched"] = 20   # < sharing's 33
-    (tmp_repo / "PREFIXCACHE_r10_lie.json").write_text(json.dumps(doc))
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "unearned ab win")
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert any("CONTRADICTORY verdict" in p and "ab_ok" in p
-               for p in verdict["invalid_prefixcaches"])
-
-
-def test_valid_prefixcache_passes_and_untracked_fails(tmp_repo):
-    _analysis_module(tmp_repo, "prefixcache")
-    (tmp_repo / "PREFIXCACHE_r11_ok.json").write_text(
-        json.dumps(_valid_prefixcache()))
-    verdict = gate_hygiene.check(str(tmp_repo))
-    assert not verdict["ok"]            # parked-but-untracked
-    assert verdict["untracked"] == ["PREFIXCACHE_r11_ok.json"]
-    _git(tmp_repo, "add", "-A")
-    _git(tmp_repo, "commit", "-q", "-m", "good prefixcache")
-    assert gate_hygiene.check(str(tmp_repo))["ok"]
-
-
-def test_repo_prefixcache_validates():
-    """The committed PREFIXCACHE artifact is the schema's reference
-    instance; it must stay valid — and its gate must HOLD (real hit
-    rate, fewer dispatched prefill tokens, denser pool, bitwise parity:
-    the ISSUE-17 acceptance bars ride this assertion)."""
-    assert gate_hygiene._validate_prefixcaches(str(REPO)) == []
-    arts = sorted(REPO.glob("PREFIXCACHE_r*.json"))
-    assert arts, "the prefix-sharing gate artifact must be committed"
-    doc = json.loads(arts[-1].read_text())
-    assert doc["gate"]["ok"] is True
-    assert doc["sharing"]["prefix"]["hit_rate"] > 0.5
-    assert doc["sharing"]["prefill_tokens_dispatched"] \
-        < doc["baseline"]["prefill_tokens_dispatched"]
-    assert doc["sharing"]["admitted_requests_per_block"] \
-        > doc["baseline"]["admitted_requests_per_block"]
-    assert doc["bitwise_ok"] is True
 
 
 # ---------------------------------------------------------------------------

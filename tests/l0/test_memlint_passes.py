@@ -254,89 +254,6 @@ def test_cost_roofline_expectation_math():
     assert exp2["bound"] == "compute" and exp2["ceiling_util"] == 1.0
 
 
-def test_cost_floor_above_ceiling_is_error():
-    doc = {"hbm_gbps_peak": 819.0,
-           "kernels": {"k": {"gbps": 400.0, "roofline_frac": 0.49}}}
-    out = cost_mod.audit_kernel_artifact(doc, "KERNELBENCH_rX.json",
-                                         floors={"k": 1.2})
-    assert [f.op for f in out] == ["floor-above-ceiling"]
-    assert all(f.severity == "error" for f in out)
-    # floors at/below the ceiling are fine
-    assert not cost_mod.audit_kernel_artifact(doc, "x",
-                                              floors={"k": 0.5})
-
-
-def test_cost_measured_above_ceiling_is_error():
-    doc = {"hbm_gbps_peak": 819.0,
-           "kernels": {"k": {"gbps": 900.0, "roofline_frac": 1.1}}}
-    out = cost_mod.audit_kernel_artifact(doc, "KERNELBENCH_rX.json")
-    assert len(out) == 2
-    assert {f.op for f in out} == {"measured-above-ceiling"}
-
-
-def test_cost_bench_artifact_hfu_below_mfu_is_error():
-    doc = {"parsed": {"configs": {
-        "good": {"mfu": 0.5, "hfu": 0.55},
-        "bad_mfu": {"mfu": 1.3, "hfu": 1.3},
-        "bad_hfu": {"mfu": 0.5, "hfu": 0.3},
-        "zero_hfu": {"mfu": 0.5, "hfu": 0.0}}}}  # broken counter
-    out = cost_mod.audit_bench_artifact(doc, "BENCH_rX.json",
-                                        mfu_floors={"good": 0.45})
-    msgs = " | ".join(f.message for f in out)
-    assert len(out) == 3 and "bad_mfu" in msgs and "bad_hfu" in msgs
-    assert "zero_hfu" in msgs   # hfu=0.0 must not slip the falsy guard
-
-
-def test_cost_audit_floor_artifacts_over_dir(tmp_path):
-    (tmp_path / "KERNELBENCH_r03.json").write_text(json.dumps(
-        {"hbm_gbps_peak": 819.0,
-         "kernels": {"k": {"gbps": 1000.0, "roofline_frac": 1.2}}}))
-    (tmp_path / "KERNELBENCH_r02.json").write_text(json.dumps(
-        {"hbm_gbps_peak": 819.0,
-         "kernels": {"k": {"gbps": 100.0, "roofline_frac": 0.1}}}))
-    out = cost_mod.audit_floor_artifacts(str(tmp_path))
-    errs = [f for f in out if f.severity == "error"]
-    assert len(errs) == 2        # only the NEWEST round is audited
-    assert all("r03" in f.message for f in errs)
-    # clean dir: single info verdict
-    clean = cost_mod.audit_floor_artifacts(str(tmp_path / "nowhere"))
-    assert len(clean) == 1 and clean[0].severity == "info"
-
-
-def test_cost_audit_floors_fail_without_artifacts(tmp_path):
-    """The floor tables are artifact-independent: an impossible floor
-    (>1.0) must fail even when no KERNELBENCH/BENCH file loads — a
-    corrupt newest round must never launder it through a clean
-    verdict."""
-    out = cost_mod.audit_floor_artifacts(
-        str(tmp_path), kernel_floors={"k": 1.5}, mfu_floors={"c": 2.0})
-    errs = [f for f in out if f.severity == "error"]
-    assert len(errs) == 2
-    assert all(f.op == "floor-above-ceiling" for f in errs)
-    # an unreadable newest artifact is a coverage WARNING, never the
-    # affirmative clean verdict
-    (tmp_path / "KERNELBENCH_r09.json").write_text("{truncated")
-    (tmp_path / "BENCH_r09.json").write_text("not json")
-    out2 = cost_mod.audit_floor_artifacts(str(tmp_path))
-    warns = [f for f in out2 if f.severity == "warning"]
-    assert len(warns) == 2
-    assert any("KERNELBENCH_r09" in f.message for f in warns)
-    assert not any("sit under the cost-model ceilings" in f.message
-                   for f in out2)
-
-
-def test_repo_committed_artifacts_pass_calibration():
-    """The repo's own committed KERNELBENCH/BENCH artifacts and
-    published floor tables must sit under the cost-model ceilings —
-    the 'floors must sit under the ceiling' rule, enforced."""
-    sys.path.insert(0, str(REPO / "tools"))
-    import kernel_bench
-    out = cost_mod.audit_floor_artifacts(
-        str(REPO), kernel_floors=kernel_bench.KERNEL_FLOORS)
-    errs = [f for f in out if f.severity == "error"]
-    assert not errs, [f.message for f in errs]
-
-
 # ---------------------------------------------------------------------------
 # one-lowering sharing (the analyze double-lowering fix)
 # ---------------------------------------------------------------------------

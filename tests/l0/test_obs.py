@@ -77,7 +77,7 @@ def test_histogram_quantiles_match_numpy():
 
 def test_histogram_windowed_quantile_and_empty():
     """``quantile(q, since=state())`` isolates one measurement window —
-    how bench.py reads per-load-level p50/p99 off a long-lived
+    how a caller reads per-load-level p50/p99 off a long-lived
     engine."""
     reg = obs_metrics.Registry()
     h = reg.histogram("lat", buckets=(0.1, 0.2, 0.4, 0.8))
@@ -321,16 +321,11 @@ def test_real_capture_parses_with_op_times(capture_dir):
 
 
 def test_profile_tools_share_the_one_parser():
-    """ISSUE 7 satellite: the three xplane-parsing tools (plus
-    d64_decompose) import apex_tpu.obs.xplane — no private copies."""
-    import profile_step
-    assert profile_step.parse_xplane is xplane.parse_xplane
+    """ISSUE 7 satellite: the xplane-parsing tool imports
+    apex_tpu.obs.xplane — no private copy."""
     src_ca = (REPO / "tools" / "conv_attrib.py").read_text()
-    src_fr = (REPO / "tools" / "fusion_roofline.py").read_text()
     assert "from apex_tpu.obs.xplane import parse_xplane" in src_ca
-    assert "from apex_tpu.obs.xplane import parse_xplane" in src_fr
-    for src in (src_ca, src_fr):
-        assert "xplane_pb2" not in src   # the copies are gone
+    assert "xplane_pb2" not in src_ca   # the copy is gone
 
 
 def _write_trace_json(tmp_path, events):

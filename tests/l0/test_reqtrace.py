@@ -502,13 +502,12 @@ def test_run_resilient_result_carries_flight_history():
 
 def test_merged_quantile_pinned_against_old_bench_math():
     """The recorded-fixture pin: obs.fleet.merged_quantile must
-    reproduce the OLD bench._merged_decode_quantile math (inlined
-    here as the frozen reference) exactly, windows and stale-max
-    guard included — bench and a production scrape can never
-    disagree because there is one copy."""
+    reproduce the merge math it was extracted from (inlined here as
+    the frozen reference) exactly, windows and stale-max guard
+    included."""
     import math as _math
 
-    def old_bench_math(pairs, q):           # bench.py@PR10, verbatim
+    def old_bench_math(pairs, q):           # as of PR 10, verbatim
         merged = Histogram(Registry(), "_merged_decode_window")
         for hist, mark in pairs:
             merged.counts = merged.counts + (hist.counts - mark[0])

@@ -79,7 +79,7 @@ def test_mixed_stream_matches_solo_and_never_retraces(setup, engine):
     # telemetry (apex_tpu.obs): the counters match the scripted
     # stream — 5 admissions, 5 retirements, no preemption, every
     # generated token counted, and the decode-step histogram observed
-    # every step (this is the histogram bench.py reads p50/p99 from)
+    # every step (this is the histogram p50/p99 are read from)
     m = eng.metrics
     assert m.counter("serve_admissions_total").value == 5
     assert m.counter("serve_retirements_total").value == 5

@@ -23,8 +23,7 @@ path is round evidence, not just a test.
 (schema: ``apex_tpu/analysis/export_schema.py``, validated by
 ``tools/gate_hygiene.py``): per-lane cache keys, gating verdicts,
 compile-vs-load wall clock, the bitwise round-trip verdict, and the
-``cold_start`` block ``bench.py`` sources its serve cold-start gate
-from (load must cost <= 0.5x compile on this host).
+``cold_start`` block (load must cost <= 0.5x compile on this host).
 
 ``--verify-reload KEY --io FILE.pkl`` is the fresh-process check: it
 loads ONLY the cache entry (no model build, no trace), calls it on

@@ -141,9 +141,8 @@ def build_workload(seed: int = 0, min_loss_scale: float = 2.0 ** 14,
 def measure_overhead(steps: int = 40, reps: int = 5, seed: int = 0) -> dict:
     """Wall time of a bare jitted loop vs run_resilient with no faults /
     no checkpointing — the normal-path cost of the wrapper, at the CPU
-    bench-smoke scale (a ~dozens-of-ms step, like the bench.py smoke
-    configs; on a microscopic sub-ms step the fixed ~0.1 ms/step Python
-    bookkeeping dominates and the percentage is meaningless).  Reps are
+    bench-smoke scale (a ~dozens-of-ms step; on a microscopic sub-ms
+    step the fixed ~0.1 ms/step Python bookkeeping dominates and the percentage is meaningless).  Reps are
     interleaved bare/wrapped and compared min-to-min: on a shared/noisy
     host the run-to-run spread (±30% observed) dwarfs the effect, and
     the minimum is the standard noise-robust wall-clock estimator."""

@@ -277,8 +277,8 @@ def main(argv=None) -> int:
     if opts.band_source is None:
         opts.band_source = (
             "measured same-host window spread of thread-summed "
-            "XLA:CPU captures (BENCH_VARIANCE carries no decode-"
-            "profile entry; the 0.03 chip-day default is a TPU "
+            "XLA:CPU captures (no recorded variance covers the decode "
+            "profile; the 0.03 chip-day default is a TPU "
             "number)" if opts.band != schema.DEFAULT_BAND
             else "default")
 

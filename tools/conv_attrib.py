@@ -9,8 +9,8 @@ Joins two artifacts of one bench step:
 
 and prints per-conv time + achieved MFU *in situ* — no microbenchmark
 artifacts (dispatch overhead, CSE, false dependencies); the numbers are
-the real step's.  This is how the 73%-convolution-fusion profile
-(`tools/profile_step.py`) decomposes into actionable layers.
+the real step's.  This is how a step profile that is mostly
+convolution fusions decomposes into actionable layers.
 
 FLOPs per conv: 2 * prod(output dims) * prod(window sizes) * C_contract,
 where C_contract is the lhs dim labeled ``f`` in dim_labels — correct
@@ -71,7 +71,7 @@ def parse_hlo(hlo: str):
                 continue
             # Per-output contraction = rhs "i" dim (robust to grouped/
             # depthwise convs, where the lhs "f" dim overcounts by the
-            # group count — same rule as fusion_roofline._conv_flops_in)
+            # group count)
             rhs_dims = comp_shapes[cur].get(rhs)
             rhs_label = dim_labels.split("_")[1].split("->")[0]
             if rhs_dims is not None and "i" in rhs_label:

@@ -1,11 +1,10 @@
 """Decompose the b8 decode step — explain the 0.43-of-ceiling number
-(VERDICT r5 #6) with device-time buckets the way D64_DECOMPOSE did for
-the train step.
+(VERDICT r5 #6) with device-time buckets.
 
 Decode is HBM-bandwidth-bound, so under the roofline model a byte
 accounting IS a device-time accounting: bucket every byte of the decode
 step's HBM traffic and you have bucketed the step.  This tool walks the
-lowered StableHLO of the EXACT bench program
+lowered StableHLO of the decode program
 (``apex_tpu.models.generate._generate_impl`` at gpt_small_tpu b8,
 prefill 2048, 256 new tokens — lowered from ShapeDtypeStructs, nothing
 is initialized or run) and classifies every op of the per-token step
@@ -29,16 +28,11 @@ are counted FUSED (result bytes only, or zero for pure layout ops) —
 the walk models the roofline-ideal step.  The ops XLA *could* fail to
 fuse (the per-layer cache-slice copies, the bf16→f32 cache converts)
 are recorded separately as **materialization candidates** with their
-would-be volumes.  Headline (r01): the measured step (committed r05
-ladder: 3004 tok/s b8 = 2.66 ms/step = 2.18 GB at 819 GB/s) carries
-~1.5× the walk-modeled ideal (1.47 GB) — so the bench's 0.43
-``hbm_frac`` (bench byte model 0.95 GB / measured 2.18 GB) is mostly
-the bench CEILING MODEL undercounting required traffic, plus a real
-~0.7 GB residual that matches the per-layer KV slice-copy candidate
-within 5%.  The serve engine's KV choices act on that residual —
-``preferred_element_type`` attention (kills the materialized f32
-K-cache cast; also applied to ``generate._attn_cached``) and the
-paged pool's layer-leading layout.
+would-be volumes.  The committed r01 round also reconciled the
+modeled step against a decode rate measured in round 5 (its
+``measured`` and ``gap_attribution`` blocks); that measurement's source
+is gone and nothing on the repo's benchmark serves, so a round written
+now carries the static decomposition alone.
 
 The committed ``DECODE_DECOMPOSE_r01.json`` is schema-validated by
 ``tools/gate_hygiene.py`` against
@@ -261,31 +255,6 @@ class Walk:
         self._add("other", res_b, m)
 
 
-def measured_reconciliation(batch: int):
-    """The committed r05 decode measurement for this batch (ladder
-    baselines), restated as bytes/step at the chip's HBM peak — the
-    number the modeled step is reconciled against.  ``None`` off-repo
-    or for un-measured configs."""
-    try:
-        with open(REPO / "BENCH_LADDER_BASELINES.json") as f:
-            doc = json.load(f)
-        entry = doc[f"gpt_small_tpu_decode_b{batch}"][str(batch)]
-    except (OSError, ValueError, KeyError):
-        return None
-    from apex_tpu.utils.chip_peaks import CHIP_PEAKS
-    bw = CHIP_PEAKS["TPU v5 lite"].hbm_bytes_per_s     # the r05 rig
-    step_s = batch / entry["tok_s"]
-    return {
-        "source": "BENCH_LADDER_BASELINES.json",
-        "tok_s": entry["tok_s"],
-        "hbm_frac": entry["hbm_frac"],
-        "hbm_tok_s_ceiling": entry["hbm_tok_s_ceiling"],
-        "step_ms": round(step_s * 1e3, 3),
-        "hbm_bytes_per_s": bw,
-        "implied_bytes_per_step": int(step_s * bw),
-    }
-
-
 def decompose(batch: int, prefill: int, new_tokens: int,
               tiny: bool = False, compile: bool = True) -> dict:
     lowered, cfg = lower_decode(batch, prefill, new_tokens, tiny=tiny)
@@ -307,46 +276,6 @@ def decompose(batch: int, prefill: int, new_tokens: int,
         cand[label] = cand.get(label, 0) + b
     cand = dict(sorted(cand.items(), key=lambda kv: -kv[1]))
 
-    meas = measured_reconciliation(batch)
-    gap = None
-    if meas:
-        residual = meas["implied_bytes_per_step"] - total
-        # name the static candidate whose volume matches the residual
-        best = min(cand.items(), key=lambda kv: abs(kv[1] - residual),
-                   default=(None, 0))
-        match = best[0] if best[0] and residual > 0 and \
-            abs(best[1] - residual) / max(residual, 1) < 0.15 else None
-        verdict = (
-            f"the modeled roofline-ideal step "
-            f"({total / 1e6:.0f} MB) is "
-            f"{total / meas['implied_bytes_per_step']:.2f} of the "
-            f"measured per-step traffic "
-            f"({meas['implied_bytes_per_step'] / 1e6:.0f} MB at the "
-            f"HBM peak) — the 0.43 'gap' is mostly the bench ceiling "
-            f"model undercounting required traffic, plus a real "
-            f"{residual / 1e6:.0f} MB residual")
-        if match:
-            verdict += (
-                f"; the residual matches the {match!r} candidate "
-                f"({cand[match] / 1e6:.0f} MB) within 15% — the "
-                f"per-layer materialization the serve paged layout "
-                f"and the preferred_element_type attention rewrite "
-                f"target; on-chip confirmation is the next driver "
-                f"round's profile")
-        else:
-            verdict += ("; no single static candidate matches it — "
-                        "attribute on-chip next driver round")
-        gap = {
-            "modeled_ideal_bytes": int(total),
-            "implied_measured_bytes": meas["implied_bytes_per_step"],
-            "residual_bytes": int(residual),
-            "residual_frac_of_step": round(
-                residual / meas["implied_bytes_per_step"], 4),
-            "static_candidates_ranked": cand,
-            "residual_matches_candidate": match,
-            "verdict": verdict,
-        }
-
     doc = {
         "round": 1,
         "platform": jax.devices()[0].platform,
@@ -362,16 +291,14 @@ def decompose(batch: int, prefill: int, new_tokens: int,
         "device_time_fractions": fractions,
         "coverage": coverage,
         "host_sync_count": walk.host_sync_count,
-        "measured": meas,
-        "gap_attribution": gap,
+        "static_candidates_ranked": cand,
         "note": (
             "Bytes conventions: elementwise/layout ops fused (result "
             "bytes only / zero); cache DUS in-place (2x update); cache "
             "reads charged at the consuming dot; per-layer ops x "
             "num_layers via the layer-loop walk.  Fractions model the "
             "roofline-IDEAL step: on a bandwidth-bound program they "
-            "are device-time fractions.  gap_attribution reconciles "
-            "against the committed measured rate; the candidates are "
+            "are device-time fractions.  The candidates are "
             "the statically-visible buffers XLA may materialize on "
             "top of the ideal."),
     }

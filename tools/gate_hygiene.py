@@ -1,18 +1,17 @@
 """Gate-artifact hygiene check: the gate's memory must be committed.
 
-VERDICT r5 weak #7: ``BENCH_LADDER_BASELINES.json`` and
-``SCALING_SWEEP.json`` were left modified-but-uncommitted at round end —
-and the ladder file is the regression gate's MEMORY.  An uncommitted
-gate baseline is a gate that can drift silently: the next round compares
-against whatever happens to be on disk, not against what review saw.
+VERDICT r5 weak #7: ``SCALING_SWEEP.json`` was left
+modified-but-uncommitted at round end.  An uncommitted gate baseline is
+a gate that can drift silently: the next round compares against
+whatever happens to be on disk, not against what review saw.
 
 This check fails (exit 1) when
 
 - a REQUIRED gate-baseline artifact is missing or untracked, or
 - ANY gate-baseline artifact (required or optional, e.g. the
-  round-numbered ``KERNELBENCH_r*.json`` kernel-gate artifacts or
-  ``BENCH_VARIANCE.json``) is modified, staged-but-uncommitted, or —
-  for round-numbered artifacts — present but never added, or
+  round-numbered ``MEMLINT_r*.json`` lint artifacts) is modified,
+  staged-but-uncommitted, or — for round-numbered artifacts — present
+  but never added, or
 - a committed ``INCIDENT_r*.json`` does not validate against the
   incident schema (``apex_tpu/resilience/incidents.py``: status, utc or
   date, non-empty evidence) — chaos-run artifacts must not rot into
@@ -21,8 +20,7 @@ This check fails (exit 1) when
   memory-lint schema (``apex_tpu/analysis/memlint.py``: round,
   platform, non-empty lanes each carrying ``peak_hbm_bytes`` / the
   donation-aliasing table / cost-model numbers) — the static HBM
-  story of every lane is gate memory the same way the kernel floors
-  are, or
+  story of every lane is gate memory, or
 - a committed ``PRECLINT_r*.json`` does not validate against the
   precision-lint schema (``apex_tpu/analysis/preclint.py``: round,
   platform, half_dtype, non-empty lanes each carrying the verdict,
@@ -57,13 +55,6 @@ This check fails (exit 1) when
   refused lanes naming the documented finding id, and a ``cold_start``
   block whose ``ok`` agrees with its own load-vs-compile numbers) —
   the executable cache's build evidence is gate memory too, or
-- a committed ``SERVE_DISAGG_r*.json`` does not validate against the
-  disaggregated-serving schema (``apex_tpu/analysis/serve_disagg.py``:
-  disjoint slice topology, both arms' percentile records, the chaos
-  drill, and a ``gate`` whose ``p99_ok``/``ok`` AGREE with the
-  recorded numbers — a verdict contradicting its own A/B is
-  schema-invalid) — the p99 gate of the disaggregated fleet is gate
-  memory like every other floor, or
 - a committed ``SCENARIO_r*.json`` does not validate against the
   serve scenario-matrix schema (``apex_tpu/analysis/scenario.py``:
   >= 10 cells each carrying config/percentiles and a gate verdict
@@ -81,12 +72,6 @@ This check fails (exit 1) when
   incident schema's grown optional ``flight`` field (the
   flight-recorder tail) is validated through the same committed
   ``INCIDENT_r*.json`` check above, or
-- a committed ``BENCH_VARIANCE_r*.json`` does not validate against
-  the variance schema (``apex_tpu/analysis/variance.py``: recorded
-  mean/min/max/std/rel_spread must re-derive from the recorded
-  samples — a spread wide enough to excuse a floor drop cannot be
-  typed in) — the statistics every derived floor and band width ride
-  are gate memory like the floors themselves, or
 - a committed ``PROFILE_DRIFT_r*.json`` does not validate against
   the continuous-profile drift schema
   (``apex_tpu/analysis/profile_drift.py``: band + k, a clean session
@@ -104,14 +89,6 @@ This check fails (exit 1) when
   lanes — a contradictory fleet verdict is schema-invalid) — "every
   rank compiles the same collective schedule" is gate memory, not
   prose, or
-- a committed ``PREFIXCACHE_r*.json`` does not validate against the
-  prefix-sharing schema (``apex_tpu/analysis/prefixcache.py``: the
-  headline hit/skip counters must RE-DERIVE from the recorded
-  per-request spans, and the ``gate`` verdict from the recorded
-  arms — a hit rate the spans refute, a skipped-token total they
-  don't add up to, or a typed-in "ok" is CONTRADICTORY and
-  schema-invalid) — the KV-dedup A/B and its bitwise drill are gate
-  memory like every other floor, or
 - a committed ``TRAINFLEET_r*.json`` does not validate against the
   elastic-training-fleet schema (``apex_tpu/analysis/trainfleet.py``:
   generation chain whose member sets strictly shrink/regrow, recovery
@@ -120,15 +97,7 @@ This check fails (exit 1) when
   from the recorded state digests, and a ``gate`` agreeing with its
   own bitwise table — a typed-in "survived the kill" is CONTRADICTORY
   and schema-invalid) — the chaos drill's shrink/regrow evidence is
-  gate memory like every other floor, or
-- a committed ``TIMELINE_r*.json`` does not validate against the
-  timeline schema (``apex_tpu/analysis/timeline.py``: every
-  regression row must cite a series whose recorded points actually
-  cross its band, no gated series crossing its band may lack a row,
-  and ``gate.ok`` must re-derive from the table), or the NEWEST
-  committed timeline's coverage table is missing ANY committed
-  round-numbered artifact — the cross-round view must never silently
-  go stale as new families/rounds land.
+  gate memory like every other floor.
 
 It is wired into tier-1 (``tests/l0/test_gate_hygiene.py``), so a round
 cannot go green with dirty gate memory.  Best-effort on the VCS side:
@@ -149,26 +118,20 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[1]
 
-#: Artifacts that MUST exist and be tracked: the model-gate ladder
-#: memory and the scaling-law baseline.
-REQUIRED = ("BENCH_LADDER_BASELINES.json", "SCALING_SWEEP.json")
+#: Artifacts that MUST exist and be tracked: the scaling-law baseline.
+REQUIRED = ("SCALING_SWEEP.json",)
 
 #: All gate-baseline patterns whose working-tree copies must match HEAD
-#: (round-numbered artifacts included: a fresh KERNELBENCH_rN.json is
+#: (round-numbered artifacts included: a fresh MEMLINT_rN.json is
 #: gate memory the moment it exists; incident records are round
 #: evidence the same way).
-PATTERNS = ("BENCH_LADDER_BASELINES.json", "SCALING_SWEEP.json",
-            "BENCH_VARIANCE.json", "BENCH_VARIANCE_r*.json",
-            "KERNELBENCH_r*.json",
-            "BENCH_r*.json", "INCIDENT_r*.json", "MEMLINT_r*.json",
+PATTERNS = ("SCALING_SWEEP.json", "INCIDENT_r*.json", "MEMLINT_r*.json",
             "PRECLINT_r*.json", "DECODE_DECOMPOSE_r*.json",
             "OBS_r*.json", "DECODE_PROFILE_r*.json",
             "CONVERGENCE_r*.json", "EXPORT_r*.json",
-            "SERVE_DISAGG_r*.json", "SCENARIO_r*.json",
-            "TRACE_r*.json", "TIMELINE_r*.json",
+            "SCENARIO_r*.json", "TRACE_r*.json",
             "PROFILE_DRIFT_r*.json", "FLEETLINT_r*.json",
-            "PREFIXCACHE_r*.json", "TRAINFLEET_r*.json",
-            "KERNLINT_r*.json", "DETLINT_r*.json")
+            "TRAINFLEET_r*.json", "KERNLINT_r*.json", "DETLINT_r*.json")
 
 #: Round-numbered incident artifacts additionally get schema-validated.
 INCIDENT_PATTERN = "INCIDENT_r*.json"
@@ -194,30 +157,17 @@ CONVERGENCE_PATTERN = "CONVERGENCE_r*.json"
 #: ... and the AOT-export artifacts ...
 EXPORT_PATTERN = "EXPORT_r*.json"
 
-#: ... and the disaggregated-serving gate artifacts ...
-SERVE_DISAGG_PATTERN = "SERVE_DISAGG_r*.json"
-
 #: ... and the serve scenario-matrix gate artifacts ...
 SCENARIO_PATTERN = "SCENARIO_r*.json"
 
 #: ... and the fleet request-trace artifacts ...
 TRACE_PATTERN = "TRACE_r*.json"
 
-#: ... and the recorded-variance artifacts (the statistics under the
-#: derived floors) ...
-VARIANCE_PATTERN = "BENCH_VARIANCE_r*.json"
-
-#: ... and the longitudinal perf-timeline artifacts ...
-TIMELINE_PATTERN = "TIMELINE_r*.json"
-
 #: ... and the continuous-profile drift artifacts ...
 PROFILE_DRIFT_PATTERN = "PROFILE_DRIFT_r*.json"
 
 #: ... and the cross-rank SPMD consistency artifacts ...
 FLEETLINT_PATTERN = "FLEETLINT_r*.json"
-
-#: ... and the cross-request prefix-sharing gate artifacts ...
-PREFIXCACHE_PATTERN = "PREFIXCACHE_r*.json"
 
 #: ... and the elastic-training-fleet chaos-drill artifacts ...
 TRAINFLEET_PATTERN = "TRAINFLEET_r*.json"
@@ -357,21 +307,6 @@ def _validate_exports(repo: str) -> "list[str]":
     return problems
 
 
-def _validate_serve_disaggs(repo: str) -> "list[str]":
-    """Schema problems over every present SERVE_DISAGG_r*.json, as
-    ``path: problem`` strings
-    (``apex_tpu/analysis/serve_disagg.py``)."""
-    schema = _load_by_path(repo, "apex_tpu", "analysis",
-                           "serve_disagg.py")
-    if schema is None:
-        return []
-    problems = []
-    for p in sorted(Path(repo).glob(SERVE_DISAGG_PATTERN)):
-        for msg in schema.validate_serve_disagg_file(str(p)):
-            problems.append(f"{p.name}: {msg}")
-    return problems
-
-
 def _validate_scenarios(repo: str) -> "list[str]":
     """Schema problems over every present SCENARIO_r*.json, as
     ``path: problem`` strings (``apex_tpu/analysis/scenario.py``)."""
@@ -396,42 +331,6 @@ def _validate_traces(repo: str) -> "list[str]":
     problems = []
     for p in sorted(Path(repo).glob(TRACE_PATTERN)):
         for msg in schema.validate_trace_file(str(p)):
-            problems.append(f"{p.name}: {msg}")
-    return problems
-
-
-def _validate_variances(repo: str) -> "list[str]":
-    """Schema problems over every present BENCH_VARIANCE_r*.json, as
-    ``path: problem`` strings (``apex_tpu/analysis/variance.py``)."""
-    schema = _load_by_path(repo, "apex_tpu", "analysis", "variance.py")
-    if schema is None:
-        return []
-    problems = []
-    for p in sorted(Path(repo).glob(VARIANCE_PATTERN)):
-        for msg in schema.validate_variance_file(str(p)):
-            problems.append(f"{p.name}: {msg}")
-    return problems
-
-
-def _validate_timelines(repo: str) -> "list[str]":
-    """Schema problems over every present TIMELINE_r*.json, as
-    ``path: problem`` strings (``apex_tpu/analysis/timeline.py``).
-    Only the NEWEST round is held to coverage-completeness against
-    the checkout's committed artifacts (older rounds were complete
-    when written; they stay valid on internal consistency)."""
-    schema = _load_by_path(repo, "apex_tpu", "analysis", "timeline.py")
-    if schema is None:
-        return []
-    rounds = []
-    for p in sorted(Path(repo).glob(TIMELINE_PATTERN)):
-        parsed = schema.parse_artifact_name(p.name)
-        rounds.append((parsed[1] if parsed else -1, p))
-    rounds.sort()
-    problems = []
-    for i, (_, p) in enumerate(rounds):
-        newest = i == len(rounds) - 1
-        for msg in schema.validate_timeline_file(
-                str(p), repo_dir=repo if newest else None):
             problems.append(f"{p.name}: {msg}")
     return problems
 
@@ -463,22 +362,6 @@ def _validate_fleetlints(repo: str) -> "list[str]":
     problems = []
     for p in sorted(Path(repo).glob(FLEETLINT_PATTERN)):
         for msg in schema.validate_fleetlint_file(str(p)):
-            problems.append(f"{p.name}: {msg}")
-    return problems
-
-
-def _validate_prefixcaches(repo: str) -> "list[str]":
-    """Schema problems over every present PREFIXCACHE_r*.json, as
-    ``path: problem`` strings (``apex_tpu/analysis/prefixcache.py`` —
-    which also re-derives the hit/skip counters from the recorded
-    per-request spans)."""
-    schema = _load_by_path(repo, "apex_tpu", "analysis",
-                           "prefixcache.py")
-    if schema is None:
-        return []
-    problems = []
-    for p in sorted(Path(repo).glob(PREFIXCACHE_PATTERN)):
-        for msg in schema.validate_prefixcache_file(str(p)):
             problems.append(f"{p.name}: {msg}")
     return problems
 
@@ -559,18 +442,16 @@ def check(repo: str = str(REPO)) -> dict:
                 "invalid_memlints": [], "invalid_preclints": [],
                 "invalid_decomposes": [], "invalid_obs": [],
                 "invalid_profiles": [], "invalid_convergences": [],
-                "invalid_exports": [], "invalid_serve_disaggs": [],
-                "invalid_scenarios": [], "invalid_traces": [],
-                "invalid_variances": [], "invalid_timelines": [],
-                "invalid_profile_drifts": [], "invalid_fleetlints": [],
-                "invalid_prefixcaches": [], "invalid_trainfleets": [],
+                "invalid_exports": [], "invalid_scenarios": [],
+                "invalid_traces": [], "invalid_profile_drifts": [],
+                "invalid_fleetlints": [], "invalid_trainfleets": [],
                 "invalid_kernlints": [], "invalid_detlints": []}
     tracked = set(tracked_raw.split())
     missing = [f for f in REQUIRED
                if not (Path(repo) / f).exists() or f not in tracked]
 
     # -uall: surface untracked round artifacts too (a new
-    # KERNELBENCH_rN.json must be committed, not parked)
+    # MEMLINT_rN.json must be committed, not parked)
     status_raw = _git(repo, "status", "--porcelain", "-uall", "--",
                       *PATTERNS) or ""
     untracked, dirty = [], []
@@ -592,24 +473,18 @@ def check(repo: str = str(REPO)) -> dict:
     invalid_prof = _validate_profiles(repo)
     invalid_conv = _validate_convergences(repo)
     invalid_exp = _validate_exports(repo)
-    invalid_disagg = _validate_serve_disaggs(repo)
     invalid_scen = _validate_scenarios(repo)
     invalid_trace = _validate_traces(repo)
-    invalid_var = _validate_variances(repo)
-    invalid_tl = _validate_timelines(repo)
     invalid_pd = _validate_profile_drifts(repo)
     invalid_fl = _validate_fleetlints(repo)
-    invalid_pc = _validate_prefixcaches(repo)
     invalid_tf = _validate_trainfleets(repo)
     invalid_kl = _validate_kernlints(repo)
     invalid_dl = _validate_detlints(repo)
     return {"ok": not (missing or untracked or dirty or invalid
                        or invalid_mem or invalid_prec or invalid_dec
                        or invalid_obs or invalid_prof or invalid_conv
-                       or invalid_exp or invalid_disagg
-                       or invalid_scen or invalid_trace
-                       or invalid_var or invalid_tl
-                       or invalid_pd or invalid_fl or invalid_pc
+                       or invalid_exp or invalid_scen or invalid_trace
+                       or invalid_pd or invalid_fl
                        or invalid_tf or invalid_kl or invalid_dl),
             "missing": missing, "untracked": untracked, "dirty": dirty,
             "invalid_incidents": invalid,
@@ -620,14 +495,10 @@ def check(repo: str = str(REPO)) -> dict:
             "invalid_profiles": invalid_prof,
             "invalid_convergences": invalid_conv,
             "invalid_exports": invalid_exp,
-            "invalid_serve_disaggs": invalid_disagg,
             "invalid_scenarios": invalid_scen,
             "invalid_traces": invalid_trace,
-            "invalid_variances": invalid_var,
-            "invalid_timelines": invalid_tl,
             "invalid_profile_drifts": invalid_pd,
             "invalid_fleetlints": invalid_fl,
-            "invalid_prefixcaches": invalid_pc,
             "invalid_trainfleets": invalid_tf,
             "invalid_kernlints": invalid_kl,
             "invalid_detlints": invalid_dl}
@@ -654,20 +525,14 @@ def main(argv=None) -> int:
               f"convergence records "
               f"{verdict.get('invalid_convergences', [])}; invalid "
               f"export records {verdict.get('invalid_exports', [])}; "
-              f"invalid serve-disagg records "
-              f"{verdict.get('invalid_serve_disaggs', [])}; invalid "
-              f"scenario records {verdict.get('invalid_scenarios', [])}; "
+              f"invalid scenario records "
+              f"{verdict.get('invalid_scenarios', [])}; "
               f"invalid trace records "
-              f"{verdict.get('invalid_traces', [])}; invalid variance "
-              f"records {verdict.get('invalid_variances', [])}; "
-              f"invalid/stale timeline records "
-              f"{verdict.get('invalid_timelines', [])}; invalid "
+              f"{verdict.get('invalid_traces', [])}; invalid "
               f"profile-drift records "
               f"{verdict.get('invalid_profile_drifts', [])}; invalid "
               f"fleetlint records "
               f"{verdict.get('invalid_fleetlints', [])}; invalid "
-              f"prefix-cache records "
-              f"{verdict.get('invalid_prefixcaches', [])}; invalid "
               f"train-fleet records "
               f"{verdict.get('invalid_trainfleets', [])}; invalid "
               f"kernlint records "

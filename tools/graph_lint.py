@@ -38,10 +38,9 @@ are built — the dryrun slices in ``__graft_entry__.py``).
 lane (bare flag = the v5e 16 GiB default; suffixes ``KiB``/``MiB``/
 ``GiB`` accepted).  ``--emit-json MEMLINT_rN.json`` writes the
 committed memory-lint artifact — per-lane ``peak_hbm_bytes``,
-donation-aliasing table, cost-model flops/bytes, the multichip slice
-table, and the gate-calibration audit (committed KERNELBENCH/BENCH
-floors must sit under the cost-model ceiling) — validated by
-``tools/gate_hygiene.py`` against ``apex_tpu/analysis/memlint.py``.
+donation-aliasing table, cost-model flops/bytes and the multichip slice
+table — validated by ``tools/gate_hygiene.py`` against
+``apex_tpu/analysis/memlint.py``.
 
 One JSON line per lane plus a human summary; exit 1 on any finding of
 ``error`` severity — wired as ``tests/l0/test_graph_lint.py`` so the
@@ -733,43 +732,10 @@ def emit_fleetlint(path: str, verbose: bool = False) -> int:
     return n_errors
 
 
-def _calibration_audit() -> "list":
-    """Gate-calibration findings: committed KERNELBENCH/BENCH floors
-    and measurements vs the cost-model ceilings.  An unimportable
-    floor table degrades to a WARNING finding in the artifact — the
-    audit keeps running, but never silently narrows to a clean
-    verdict with the floor half of the check off."""
-    from apex_tpu.analysis.report import Finding
-
-    repo = str(Path(__file__).resolve().parents[1])
-    kernel_floors = mfu_floors = None
-    skipped = []
-    try:
-        import kernel_bench
-        kernel_floors = kernel_bench.KERNEL_FLOORS
-    except Exception as e:  # noqa: BLE001 - audit degrades, never crashes
-        skipped.append(f"kernel_bench.KERNEL_FLOORS ({e})")
-    try:
-        import bench
-        mfu_floors = bench.MFU_FLOORS
-    except Exception as e:  # noqa: BLE001
-        skipped.append(f"bench.MFU_FLOORS ({e})")
-    out = cost_mod.audit_floor_artifacts(repo,
-                                         kernel_floors=kernel_floors,
-                                         mfu_floors=mfu_floors)
-    for what in skipped:
-        out.append(Finding(
-            "cost", "warning",
-            f"floor table unimportable — {what}; published floors NOT "
-            f"audited this round", op="roofline"))
-    return out
-
-
 def emit_memlint(path: str, families, memory_budget=None,
                  verbose: bool = False) -> int:
     """Write the MEMLINT artifact: every family's O1+O2 train lanes,
-    the decode lanes, the multichip slice table, and the calibration
-    audit.  Returns the number of error findings across all lanes."""
+    the decode lanes and the multichip slice table.  Returns the number of error findings across all lanes."""
     lanes: dict = {}
     n_errors = 0
     for family in families:
@@ -807,9 +773,6 @@ def emit_memlint(path: str, families, memory_budget=None,
         if verbose:
             print(f"--- {lane} ---\n{rep.format()}", file=sys.stderr)
 
-    calibration = _calibration_audit()
-    n_errors += sum(1 for f in calibration if f.severity == "error")
-
     m = re.search(r"_r(\d+)\.json$", os.path.basename(path))
     doc = {
         "round": int(m.group(1)) if m else 0,
@@ -818,9 +781,6 @@ def emit_memlint(path: str, families, memory_budget=None,
         "lanes": lanes,
         "multichip": {"n_devices": 8,
                       "slices": multichip_slice_table(8)},
-        "calibration": {
-            "ok": not any(f.severity == "error" for f in calibration),
-            "findings": [f.to_dict() for f in calibration]},
     }
     with open(path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
@@ -947,7 +907,7 @@ def main(argv=None) -> int:
                     help="write a committed lint artifact, dispatched "
                          "on the file name: MEMLINT_r*.json = all "
                          "passes over O1+O2 train + decode + serve + "
-                         "multichip slices + calibration audit; "
+                         "multichip slices; "
                          "PRECLINT_r*.json = the precision pass over "
                          "every O0–O4 train lane + decode + serve "
                          "(lowering only); FLEETLINT_r*.json = the "

@@ -76,8 +76,8 @@ jax.config.update("jax_platforms",
                   os.environ.get("APEX_TPU_TEST_PLATFORM", "cpu"))
 jax.config.update("jax_threefry_partitionable", True)
 
-#: the latency-tail multiplier — the same bar bench.py's serve config
-#: gates (a mid-serve retrace or host sync shows up as 100-1000x)
+#: the latency-tail multiplier (a mid-serve retrace or host sync shows
+#: up as 100-1000x)
 GATE_K = 20.0
 
 #: absolute decode-step p99 SLO budget (seconds) recorded per cell via

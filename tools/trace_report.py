@@ -4,8 +4,7 @@ the committed ``TRACE_r*.json`` lifecycle artifact.
 The drill is PR 10's replica-kill scenario at the c16 fleet topology
 (1 prefill slice + 2 decode replicas x 8 slots on the virtual
 16-device CPU platform — the tool forces
-``--xla_force_host_platform_device_count=16`` exactly like
-``tools/serve_disagg.py``), with :class:`apex_tpu.obs.RequestTracer`
+``--xla_force_host_platform_device_count=16``), with :class:`apex_tpu.obs.RequestTracer`
 and :class:`apex_tpu.obs.FlightRecorder` attached: a request stream is
 admitted, the busiest decode replica is killed mid-stream, the router
 rebuilds its in-flight requests from the streamed-token log and
