@@ -215,8 +215,9 @@ class DeepseekV3Model(nn.Module):
     """``__call__(input_ids)`` returns logits ``(B, L, vocab)``, or with
     ``return_stats`` also the expert layers' routing counters, one entry
     a layer: ``pairs`` the (token, expert) pairs the experts held here
-    served, ``load_peak`` the fullest held expert's load over their mean
-    (no pair is ever dropped)."""
+    served, ``load_peak`` the fullest held expert's load over their mean,
+    ``windows`` the windows of the sorted buffer the layer ran (no pair is
+    ever dropped)."""
 
     cfg: DeepseekV3Config
 

@@ -24,10 +24,20 @@ decoder (:mod:`apex_tpu.models.deepseek_v3`).
   that hold their experts and back by ``lax.all_to_all``, and every
   expert is held somewhere.
 
-Shapes are static whatever the routing: a buffer has room for every
-pair that could fall on it and for aligning each expert's run to the
-grouped product's row tile, and the product skips the tiles no run
-covers.
+Shapes are static whatever the routing.  The sorted buffer has room for
+every pair that could fall on the experts held and for aligning each
+expert's run to the grouped product's row tile, but it is never formed
+whole: it is walked in *windows* of ``2 x expected + held x tile`` rows
+(``expected`` the pairs the held experts get when routing is even;
+:func:`_window_rows`), one traced body under a ``lax.while_loop`` that
+runs the first window and ends with the last one a run reaches.  Even
+routing fills the first window only (16 of 128 experts and 49152 pairs:
+one window of 20480 rows where the buffer has 57344), and so does any
+load up to twice the expected one: the step's time is then the same; a
+load of any skew runs as many windows as it fills, so nothing is
+dropped and nothing approximated.  A layer that holds every expert, or
+whose buffer is no longer than a window, has one window: the same body
+once, with no loop.  ``stats["windows"]`` counts the windows run.
 """
 
 from __future__ import annotations
@@ -212,57 +222,206 @@ def gated_ffn(params: Any, rows: jax.Array,
 # sorting rows to their experts and back
 # ---------------------------------------------------------------------------
 
-@jax.custom_vjp
-def _move_rows(x, take, taken, back, came_back):
+def _taken_rows(x, take, taken):
     """``where(taken, x[take % len(x)], 0)``: rows of ``x`` moved to where
-    ``take`` wants them, nought where nothing is wanted.  ``back`` and
-    ``came_back`` say the same of the way home (for every row of the
-    result's cotangent that a row of ``x`` is owed, where it lies), so
-    the cotangent returns by a gather and a sum over the ``len(back) /
-    len(x)`` places a row went to, not by a scatter-add (five times
-    slower on the v5e at 49152 rows of 2048, PERF.md PR 27)."""
+    ``take`` wants them, nought where nothing is wanted."""
     rows = jnp.take(x, take % x.shape[0], axis=0)
     return jnp.where(taken[:, None], rows, jnp.zeros((), x.dtype))
-
-
-def _move_rows_fwd(x, take, taken, back, came_back):
-    return _move_rows(x, take, taken, back, came_back), \
-        (x.shape[0], back, came_back)
-
-
-def _move_rows_bwd(saved, grad):
-    n, back, came_back = saved
-    home = jnp.where(came_back[:, None], jnp.take(grad, back, axis=0),
-                     jnp.zeros((), grad.dtype))
-    home = home.reshape(-1, n, *grad.shape[1:])
-    if home.shape[0] > 1:
-        home = jnp.sum(home.astype(jnp.float32), axis=0).astype(grad.dtype)
-    return home.reshape(n, *grad.shape[1:]), None, None, None, None
-
-
-_move_rows.defvjp(_move_rows_fwd, _move_rows_bwd)
 
 
 def _row_tile(pairs: int) -> int:
     """Rows each expert's run is aligned to in the sorted buffer: the
     grouped product's row tile at the cell's size (a run then starts on
-    a tile and an expert of up to 512 rows is one visit, whatever the
-    routing: the step's time does not move with it), small where the
-    buffers are."""
+    a tile and an expert of up to 512 rows is one visit, so within a
+    window the step's time does not move with the routing; it moves by
+    a window's time when the held load passes a window's end), small
+    where the buffers are."""
     return 512 if pairs >= 8192 else 128 if pairs >= 1024 else 8
 
 
-def _grouped_apply(expert_fn, expert_params, x, ids, held: int):
+def _window_rows(pairs: int, held: int, expected: int) -> int:
+    """Rows of one window of the sorted buffer: twice the pairs the held
+    experts get when routing is even, and a tile of alignment for each
+    of them; the whole buffer where that is no less."""
+    tile = _row_tile(pairs)
+    return min(pairs + held * tile,
+               -(-2 * expected // tile) * tile + held * tile)
+
+
+class _Windows(NamedTuple):
+    """The sorted buffer, window by window (``w`` windows of ``r`` rows,
+    ``n`` pairs)."""
+
+    holds: jax.Array    #: (w, r) which pair each row holds
+    filled: jax.Array   #: (w, r) whether it holds one (the gaps: none)
+    sizes: jax.Array    #: (w, held) rows of each expert's run in the window
+    reached: jax.Array  #: () windows to run: the first, and to the last
+                        #: that holds a row
+    lies: jax.Array     #: (n,) the buffer row each pair lies in
+    here: jax.Array     #: (n,) whether it lies in any
+
+    def pairs_of(self, first):
+        """For every pair, its row in the window that starts at buffer
+        row ``first``, and whether it lies in that window."""
+        at = self.lies - first
+        return at, self.here & (at >= 0) & (at < self.holds.shape[1])
+
+
+def _zeros(shape, dtype, *likes):
+    """Zeros that vary over the mesh axes the ``likes`` vary over: under
+    ``shard_map`` ``lax.while_loop`` holds its carry and ``custom_vjp``
+    its cotangents to one type, and fresh zeros vary over nothing."""
+    zeros = jnp.zeros(shape, dtype)
+    vma = tuple(frozenset().union(*(
+        jax.typeof(a).vma for a in jax.tree.leaves(likes))))
+    return lax.pcast(zeros, vma, to="varying") if vma else zeros
+
+
+def _each_window(body, carry, windows: _Windows):
+    """``carry = body(carry, first row, holds, filled, sizes)`` for the
+    first window and every further one a run reaches: the runs are
+    packed from row 0, so those are the first ``reached`` windows and
+    the loop ends with them.  A window past them costs nothing; a
+    buffer of one window has no loop."""
+    n_windows, rows = windows.holds.shape
+    if n_windows == 1:
+        return body(carry, 0, windows.holds[0], windows.filled[0],
+                    windows.sizes[0])
+
+    def step(state):
+        w, carry = state
+        with jax.named_scope(MOE_DISPATCH):
+            window = (w * rows, windows.holds[w], windows.filled[w],
+                      windows.sizes[w])
+        return w + 1, body(carry, *window)
+    return lax.while_loop(lambda state: state[0] < windows.reached, step,
+                          (jnp.zeros((), jnp.int32), carry))[1]
+
+
+def _window_vjp(window_fn, rows, sizes, closed):
+    """What the experts make of a window's rows, and its transpose."""
+    return jax.vjp(lambda rows, *closed: window_fn(rows, sizes, *closed),
+                   rows, *closed)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walk(window_fn, closed, x, weights, windows: _Windows):
+    """Each row of ``x``'s weighted sum over its pairs' results, the
+    buffer walked window by window: a window gathers its rows of ``x``,
+    runs the experts on them (``window_fn(rows, sizes, *closed)``) and
+    adds to every row of ``x`` the weighted results of its pairs that
+    lie in the window."""
+    return _walk_fwd(window_fn, closed, x, weights, windows)[0]
+
+
+def _walk_fwd(window_fn, closed, x, weights, windows):
+    t, kept = x.shape[0], []
+
+    def body(y, first, holds, filled, sizes):
+        with jax.named_scope(MOE_DISPATCH):
+            rows = _taken_rows(x, holds, filled)
+        if windows.holds.shape[0] == 1:
+            # outside any loop a window keeps what autodiff keeps of it
+            out, vjp = _window_vjp(window_fn, rows, sizes, closed)
+            kept.append((out, vjp))
+        else:
+            out = window_fn(rows, sizes, *closed)
+        with jax.named_scope(MOE_DISPATCH):
+            results = _taken_rows(out, *windows.pairs_of(first))
+            return y + _combine(results, weights, t, jnp.float32)
+
+    with jax.named_scope(MOE_DISPATCH):
+        made = jax.eval_shape(
+            window_fn, _taken_rows(x, windows.holds[0], windows.filled[0]),
+            windows.sizes[0], *closed)
+        y = _zeros((t,) + made.shape[1:], jnp.float32, closed, x, weights)
+    y = _each_window(body, y, windows)
+    return y.astype(x.dtype), \
+        (closed, x, weights, windows, kept.pop() if kept else None)
+
+
+# inline: a trace that layers of one shape share, and the scopes of each
+@functools.partial(jax.jit, static_argnums=0, inline=True)
+def _walk_bwd(window_fn, saved, grad):
+    """The walk again.  Under the loop nothing of a window was kept but
+    what it was made from: kept, every window's residuals would be held
+    at once (by ``lax.scan`` the experts' weights among them, once a
+    window, and the step of the kanana cell then asks for more than the
+    chip's memory).  So each window gathers its rows of ``grad`` and of
+    ``x``, runs the experts on them once more, and adds what it owes the
+    rows of ``x``, the pairs' weights and the experts' weights to three
+    sums.  What a row of ``x`` is owed comes home by a gather and a sum
+    over the places it went to, not by a scatter-add (five times slower
+    on the v5e at 49152 rows of 2048, PERF.md PR 27).  The first two
+    sums are float32.  The experts' weights' is in their own dtype, each
+    addition made in float32: the grouped product has rounded a window's
+    part to that dtype before it is seen here, so a float32 sum would
+    differ only where one expert's run spans three windows or more (a
+    run of more rows than a window; two parts add to the same number
+    either way), and in the kanana cell it costs 2.1 ms a layer for its
+    zeros, its traffic and its cast (PERF.md PR 31)."""
+    closed, x, weights, windows, kept = saved
+    t = x.shape[0]
+
+    def add(sum_, part):
+        wide = jnp.promote_types(sum_.dtype, jnp.float32)
+        return (sum_.astype(wide) + part.astype(wide)).astype(sum_.dtype)
+
+    def body(owed, first, holds, filled, sizes):
+        with jax.named_scope(MOE_DISPATCH):
+            d_y = jnp.take(grad, holds % t, axis=0).astype(jnp.float32)
+            d_out = d_y * jnp.where(filled, weights[holds], 0.0)[:, None]
+            rows = None if kept else _taken_rows(x, holds, filled)
+        out, vjp = kept or _window_vjp(window_fn, rows, sizes, closed)
+        d_rows, *d_closed = vjp(d_out.astype(out.dtype))
+        with jax.named_scope(MOE_DISPATCH):
+            at, inside = windows.pairs_of(first)
+            counted = jnp.sum(out.astype(jnp.float32) * d_y, axis=-1)
+            home = _taken_rows(d_rows, at, inside).reshape(
+                -1, t, *x.shape[1:])
+            return jax.tree.map(add, owed, (
+                tuple(d_closed), jnp.sum(home.astype(jnp.float32), axis=0),
+                jnp.where(inside, counted[at % counted.shape[0]], 0.0)))
+
+    with jax.named_scope(MOE_DISPATCH):
+        likes = (closed, x, weights, grad)
+        owed = (tuple(_zeros(a.shape, a.dtype, *likes) for a in closed),
+                _zeros(x.shape, jnp.float32, *likes),
+                _zeros(weights.shape, jnp.float32, *likes))
+    d_closed, d_x, d_weights = _each_window(body, owed, windows)
+    return d_closed, d_x.astype(x.dtype), d_weights.astype(weights.dtype), \
+        None
+
+
+_walk.defvjp(_walk_fwd, _walk_bwd)
+
+
+def _window(expert_fn, expert_params, rows, sizes):
+    with jax.named_scope(MOE_EXPERTS):
+        return expert_fn(expert_params, rows, sizes)
+
+
+# inline, as _walk_bwd: the expert layers of a model are one trace
+@functools.partial(jax.jit, static_argnums=(0, 5, 6), inline=True)
+def _grouped_apply(expert_fn, expert_params, x, ids, weights, held: int,
+                   expected: int):
     """Run the experts held here on their rows.  Pair ``i`` is row
     ``x[i % len(x)]`` for local expert ``ids[i]``, or for none of them
-    where ``ids[i] == held``.  The pairs are sorted by expert into a
-    buffer in which every expert's run starts on a row tile (the gaps
-    are zero rows of that expert, which add nothing).  Returns the
-    pairs' results in the order they came, zero rows for the pairs of no
-    expert here, and the held experts' loads."""
+    where ``ids[i] == held``, and counts ``weights[i]``; ``expected`` of
+    the pairs fall on a held expert when routing is even.  The pairs are
+    sorted by expert into a buffer in which every expert's run starts on
+    a row tile (the gaps are zero rows of that expert, which add
+    nothing), and the buffer is walked in windows of
+    :func:`_window_rows`: the first window and every further one the
+    runs reach is the same body on its rows and the overlaps of the runs
+    with it, and the others are not visited.  Returns each row's
+    weighted sum over its pairs' results (the pairs of no expert here
+    add nothing), the held experts' loads and the windows that ran."""
     n = ids.shape[0]
     tile = _row_tile(n)
-    room = n + held * tile
+    window = _window_rows(n, held, expected)
+    n_windows = -(-(n + held * tile) // window)
+    room = n_windows * window
     with jax.named_scope(MOE_DISPATCH):
         order = jnp.argsort(ids, stable=True).astype(jnp.int32)
         experts = jnp.arange(held, dtype=ids.dtype)
@@ -281,11 +440,25 @@ def _grouped_apply(expert_fn, expert_params, x, ids, held: int):
                           held - 1).astype(jnp.int32)
         filled = row - slots[run] < loads[run]
         holds = order[jnp.clip(starts[run] + row - slots[run], 0, n - 1)]
-        rows = _move_rows(x, holds, filled, lies, here)
-    with jax.named_scope(MOE_EXPERTS):
-        out = expert_fn(expert_params, rows, sizes)
-    with jax.named_scope(MOE_DISPATCH):
-        return _move_rows(out, lies, here, holds, filled), loads
+        first = jnp.arange(n_windows, dtype=jnp.int32) * window
+        # a run that straddles two windows splits on a tile: both are
+        # multiples of it, and so is every overlap
+        overlap = jnp.clip(
+            jnp.minimum((slots + sizes)[None, :], first[:, None] + window)
+            - jnp.maximum(slots[None, :], first[:, None]), 0, window)
+        windows = _Windows(
+            holds.reshape(n_windows, window),
+            filled.reshape(n_windows, window), overlap,
+            jnp.maximum(-(-jnp.sum(sizes) // window), 1), lies, here)
+        rows = _taken_rows(x, windows.holds[0], windows.filled[0])
+    # the walk and its backward pass differentiate with respect to the
+    # weights and whatever else expert_fn closed over: both get them as
+    # arguments
+    window_fn, closed = jax.closure_convert(
+        lambda rows, sizes: _window(expert_fn, expert_params, rows, sizes),
+        rows, windows.sizes[0])
+    return _walk(window_fn, tuple(closed), x, weights, windows), loads, \
+        windows.reached
 
 
 def _pairs(per_token):
@@ -294,11 +467,12 @@ def _pairs(per_token):
     return per_token.T.reshape(-1)
 
 
-def _combine(results, weights, dtype):
-    """Each token's weighted sum over its ``k`` pairs, in float32."""
-    t, k = weights.shape
-    y = jnp.sum(results.reshape(k, t, -1).astype(jnp.float32)
-                * weights.T[..., None], axis=0)
+def _combine(results, weights, t: int, dtype):
+    """Each of ``t`` rows' weighted sum over its pairs, in float32;
+    ``results`` and ``weights`` choice-major, as :func:`_pairs` numbers
+    them."""
+    y = jnp.sum(results.reshape(-1, t, results.shape[-1]).astype(jnp.float32)
+                * weights.reshape(-1, t, 1), axis=0)
     return y.astype(dtype)
 
 
@@ -332,7 +506,11 @@ def moe_apply(
     Returns ``(y, stats)``: ``y`` ``(T, d_out)`` in ``x``'s dtype, to be
     added to the residual (and to the shared experts) outside, and
     ``stats`` — ``pairs`` served by the experts held here, ``load_peak``
-    the fullest held expert's load over their mean.  No pair is dropped.
+    the fullest held expert's load over their mean, ``windows`` the
+    windows of the sorted buffer that were run (1 unless the held load
+    passes twice its expectation).  No pair is dropped.  Where the
+    buffer is more than one window, ``expert_fn`` runs once more on each
+    window in the backward pass.
     """
     held = jax.tree.leaves(expert_params)[0].shape[0]
     t, k = routing.experts.shape
@@ -344,17 +522,19 @@ def moe_apply(
         with jax.named_scope(MOE_DISPATCH):
             here = (flat >= first) & (flat < first + held)
             ids = jnp.where(here, flat - first, held)
-        results, loads = _grouped_apply(expert_fn, expert_params, x, ids,
-                                        held)
+        y, loads, windows = _grouped_apply(
+            expert_fn, expert_params, x, ids, _pairs(routing.weights), held,
+            -(-t * k * held // n_experts))
     else:
-        results, loads = _exchanged(expert_fn, expert_params, x, flat, k,
-                                    held, n_experts, axis_name)
-    with jax.named_scope(MOE_DISPATCH):
-        y = _combine(results, routing.weights, x.dtype)
+        results, loads, windows = _exchanged(
+            expert_fn, expert_params, x, flat, k, held, n_experts, axis_name)
+        with jax.named_scope(MOE_DISPATCH):
+            y = _combine(results, _pairs(routing.weights), t, x.dtype)
     pairs = jnp.sum(loads)
     stats = {"pairs": pairs,
              "load_peak": jnp.max(loads) * held
-             / jnp.maximum(pairs, 1).astype(jnp.float32)}
+             / jnp.maximum(pairs, 1).astype(jnp.float32),
+             "windows": windows}
     return y, lax.stop_gradient(stats)
 
 
@@ -387,9 +567,13 @@ def _exchanged(expert_fn, expert_params, x, flat, k, held, n_experts,
         send_ids = jnp.where(taken, experts[pair] % held, held)
         got = lax.all_to_all(send, axis_name, 0, 0)
         got_ids = lax.all_to_all(send_ids, axis_name, 0, 0)
-    out, loads = _grouped_apply(expert_fn, expert_params,
-                                got.reshape(ranks * room, -1),
-                                got_ids.reshape(ranks * room), held)
+        once = _zeros((ranks * room,), jnp.float32, got_ids) + 1.0
+    # of the ranks * room slots that arrive, t * k hold a pair when
+    # routing is even (every rank sends its share); a slot is one row
+    # and one pair, weighed where it came from
+    out, loads, windows = _grouped_apply(
+        expert_fn, expert_params, got.reshape(ranks * room, -1),
+        got_ids.reshape(ranks * room), once, held, t * k)
     with jax.named_scope(MOE_DISPATCH):
         back = lax.all_to_all(out.reshape(ranks, room, -1), axis_name, 0, 0)
-        return back[rank, place][jnp.argsort(order)], loads
+        return back[rank, place][jnp.argsort(order)], loads, windows

@@ -179,6 +179,7 @@ def test_the_step_counts_the_pairs_it_routed_through_has_aux(case):
     aux = jax.device_get(metrics["aux"])
     assert aux["pairs"].shape == (2,) and aux["load_peak"].shape == (2,)
     assert (aux["load_peak"] >= 1.0).all()
+    assert aux["windows"].shape == (2,) and (aux["windows"] >= 1).all()
     # layer 1's input does not depend on any routing: count it plainly
     with jax.default_matmul_precision("highest"):
         x = params["tok_emb"]["embedding"][ids]
