@@ -43,13 +43,19 @@ from apex_tpu.utils.profiling import (
 # fp16util.py:44-70). Matches flax's BatchNorm_*/LayerNorm_*/GroupNorm_* and
 # common hand-rolled names.
 _NORM_NAME_FRAGMENTS = ("batchnorm", "layernorm", "groupnorm", "norm", "bn")
+# A recurrence's decay parameters (Mamba's and Kimi Delta Attention's
+# ``A_log`` and ``dt_bias``) go through exp and softplus into a product
+# over thousands of tokens: the published code keeps them float32 too.
+_DECAY_NAMES = ("a_log", "dt_bias")
 
 
 def default_keep_fp32_filter(path: Tuple[Any, ...]) -> bool:
-    """True for param paths that look like normalization-layer params."""
+    """True for param paths that look like normalization-layer params or
+    a recurrence's decay parameters."""
     for entry in path:
         name = str(getattr(entry, "key", getattr(entry, "name", entry))).lower()
-        if any(frag in name for frag in _NORM_NAME_FRAGMENTS):
+        if name in _DECAY_NAMES or any(
+                frag in name for frag in _NORM_NAME_FRAGMENTS):
             return True
     return False
 

@@ -76,6 +76,9 @@ class DeepseekV3Config:
     v_head_dim: int = 128
     rms_norm_eps: float = 1e-6
     rope_theta: float = 1e6
+    #: latent attention without positions (``mla_use_nope``): no rotary,
+    #: the "rope" lanes of q and the shared ``k_pe`` score as they are
+    mla_use_nope: bool = False
     remat: bool = False
 
     @property
@@ -114,21 +117,25 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, rope_cs):
+        """``rope_cs`` is the rotary's ``(cos, sin)``, or ``None`` where
+        the configuration has no positions (``mla_use_nope``)."""
         c = self.cfg
         from apex_tpu.attention import attention
         b, l = x.shape[0], x.shape[1]
         qk = c.qk_nope_head_dim + c.qk_rope_head_dim
-        cos, sin = rope_cs
+
+        def turn(t):
+            return t if rope_cs is None else apply_rope_interleaved(
+                t, *rope_cs)
+
         with jax.named_scope(MLA_PROJECT):
             q = Dense(c.num_heads * qk, use_bias=False, name="q_proj")(x)
-            q = apply_rope_interleaved(q.reshape(b, l, c.num_heads, qk),
-                                       cos, sin)
+            q = turn(q.reshape(b, l, c.num_heads, qk))
             kva = Dense(c.kv_lora_rank + c.qk_rope_head_dim, use_bias=False,
                         name="kv_a_proj")(x)
             c_kv = FusedRMSNorm(c.kv_lora_rank, eps=c.rms_norm_eps,
                                 name="kv_norm")(kva[..., :c.kv_lora_rank])
-            k_pe = apply_rope_interleaved(
-                kva[..., c.kv_lora_rank:][:, :, None, :], cos, sin)
+            k_pe = turn(kva[..., c.kv_lora_rank:][:, :, None, :])
             kv = Dense(c.num_heads * (c.qk_nope_head_dim + c.v_head_dim),
                        use_bias=False, name="kv_b_proj")(c_kv)
             kv = kv.reshape(b, l, c.num_heads, -1)
@@ -181,6 +188,30 @@ class RoutedExperts(nn.Module):
                              first=c.first_expert)
 
 
+def feed_forward(c: DeepseekV3Config, dense: bool, x):
+    """A block's second half, for the block whose ``nn.compact`` method
+    calls it: ``ffn_norm``, then the dense gated feed-forward or the
+    router, the routed experts held here and the shared ones, all under
+    the ``mlp`` scope; returns the residual sum and the expert layer's
+    counters (none for a dense layer)."""
+    h = FusedRMSNorm(c.hidden_size, eps=c.rms_norm_eps, name="ffn_norm")(x)
+    stats = {}
+    with jax.named_scope(MLP):
+        if dense:
+            y = GatedMLP(c.hidden_size, c.intermediate_size, name="ffn")(h)
+        else:
+            tokens = h.reshape(-1, c.hidden_size)
+            with jax.named_scope(MOE_ROUTE):
+                routing = Router(c, name="router")(tokens)
+            y, stats = RoutedExperts(c, name="experts")(tokens, routing)
+            with jax.named_scope(MOE_SHARED):
+                y = y.reshape(h.shape) + GatedMLP(
+                    c.hidden_size,
+                    c.n_shared_experts * c.moe_intermediate_size,
+                    name="shared")(h)
+    return x + y, stats
+
+
 class DeepseekV3Block(nn.Module):
     cfg: DeepseekV3Config
     dense: bool
@@ -191,24 +222,7 @@ class DeepseekV3Block(nn.Module):
         h = FusedRMSNorm(c.hidden_size, eps=c.rms_norm_eps,
                          name="attn_norm")(x)
         x = x + LatentAttention(c, name="attention")(h, rope_cs)
-        h = FusedRMSNorm(c.hidden_size, eps=c.rms_norm_eps,
-                         name="ffn_norm")(x)
-        stats = {}
-        with jax.named_scope(MLP):
-            if self.dense:
-                y = GatedMLP(c.hidden_size, c.intermediate_size,
-                             name="ffn")(h)
-            else:
-                tokens = h.reshape(-1, c.hidden_size)
-                with jax.named_scope(MOE_ROUTE):
-                    routing = Router(c, name="router")(tokens)
-                y, stats = RoutedExperts(c, name="experts")(tokens, routing)
-                with jax.named_scope(MOE_SHARED):
-                    y = y.reshape(h.shape) + GatedMLP(
-                        c.hidden_size,
-                        c.n_shared_experts * c.moe_intermediate_size,
-                        name="shared")(h)
-        return x + y, stats
+        return feed_forward(c, self.dense, x)
 
 
 class DeepseekV3Model(nn.Module):
@@ -227,9 +241,9 @@ class DeepseekV3Model(nn.Module):
         b, l = input_ids.shape
         x = nn.Embed(c.vocab_size, c.hidden_size, embedding_init=_INIT,
                      name="tok_emb")(input_ids)
-        positions = jnp.broadcast_to(jnp.arange(l)[None, :], (b, l))
-        rope_cs = rope_tables_interleaved(positions, c.qk_rope_head_dim,
-                                          c.rope_theta)
+        rope_cs = None if c.mla_use_nope else rope_tables_interleaved(
+            jnp.broadcast_to(jnp.arange(l)[None, :], (b, l)),
+            c.qk_rope_head_dim, c.rope_theta)
         block_cls = (nn.remat(DeepseekV3Block, prevent_cse=False)
                      if c.remat else DeepseekV3Block)
         stats = []

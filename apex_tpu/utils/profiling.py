@@ -62,6 +62,16 @@ MLP, LM_LOSS, PRETRAINING_LOSS = MODEL_SCOPES = (
  MLA_PROJECT) = MOE_SCOPES = (
     "moe_route", "moe_dispatch", "moe_experts", "moe_shared", "mla_project")
 
+#: The scopes of a Kimi Delta Attention layer
+#: (:mod:`apex_tpu.models.kimi_linear`), all inside its ``attention``
+#: module: the five input projections, the two low-rank gates, the gated
+#: head norm and the output projection; the short convolutions with
+#: their SiLU and the L2 norm of q and k; and the chunkwise gated delta
+#: rule (:mod:`apex_tpu.attention.gated_delta`, which opens it itself,
+#: in its loop bodies and in its hand-written backward too).
+KDA_PROJECT, KDA_CONV, KDA_RECURRENCE = KDA_SCOPES = (
+    "kda_project", "kda_conv", "kda_recurrence")
+
 
 @contextlib.contextmanager
 def nvtx_range(name: str):
