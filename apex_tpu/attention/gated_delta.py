@@ -21,8 +21,16 @@ starts from ``S_0``, with ``G_r`` the chunk's running sum of ``g``::
 (the WY / UT form of the Kimi Linear report, arXiv 2510.26692).  Two
 parts: what needs no state (``A``, ``Aq``, ``T``, ``W_v``, ``W_k`` and
 the decayed ``Q``, ``K``) is computed for all chunks at once; the state
-then walks the chunks under one ``lax.scan`` of ``L / C`` trips, three
-small products a trip.
+then walks the chunks, four small products a chunk and head.  **The walk
+has two routes, read from the operands' shape at trace time**
+(:func:`_kernels`): heads that fill the 128 lanes, in chunks of whole
+sublane tiles of the operand dtype, walk in two Mosaic kernels, one
+forward and one backward, that hold a group of heads' state in VMEM from
+the first chunk to the last (:mod:`apex_tpu.ops.pallas.gated_delta_walk`;
+off the chip in interpret mode); any other shape (narrow heads) walks
+under one ``lax.scan`` of ``L / C`` trips a pass.  One algorithm, the
+same products in the same roundings; only the tiling depends on the
+widths.
 
 **Decays are only ever exponentiated as differences** ``exp(G_r - G_s)``
 with ``s <= r`` inside one chunk, so every exponent is ``<= 0`` and a
@@ -46,8 +54,10 @@ and every product accumulates in float32.
 
 **Backward.**  The state's walk has a hand-written backward
 (``jax.custom_vjp``) that keeps one state a chunk (``L / C x B x H x
-d_k x d_v`` float32) and recomputes ``U`` from it, walking the chunks
-backwards with the state's cotangent; what needs no state is
+d_k x d_v`` float32; the kernels write it only when differentiated, the
+primal call leaves it out) and recomputes ``U`` from it, walking the
+chunks backwards with the state's cotangent, in VMEM scratch or as the
+scan's carry; what needs no state is
 differentiated by autodiff under ``jax.checkpoint``, so nothing of its
 levels but the triangular inverse is kept between the passes, and in up
 to eight slices of the
@@ -63,6 +73,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from apex_tpu.ops.pallas.gated_delta_walk import (walk_bwd, walk_fwd,
+                                                  walk_geometry)
 from apex_tpu.utils.profiling import KDA_RECURRENCE
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -211,16 +223,25 @@ def _chunk(state, w_v, w_k, q_in, scores, k_out, decay):
     return u, out, after
 
 
-@jax.custom_vjp
-def _walk(w_v, w_k, q_in, scores, k_out, decay):
-    """The state through the chunks.  Arguments lead with the chunk axis
-    ``N``; returns the outputs ``(N, B, H, C, d_v)`` and, per chunk, the
-    largest magnitude in the state it leaves."""
-    return _walk_fwd(w_v, w_k, q_in, scores, k_out, decay)[0]
+def _kernels(w_v, w_k):
+    """Heads a grid step of the walk's Mosaic kernels takes where the
+    operands' shape is theirs (full 128-lane heads, a chunk of whole
+    sublane tiles), else ``None``: the scan."""
+    _, b, h, c, d_v = w_v.shape
+    return walk_geometry(b * h, c, w_k.shape[-1], d_v, w_v.dtype)
 
 
-def _walk_fwd(w_v, w_k, q_in, scores, k_out, decay):
+def _forward(operands, keep_states: bool):
+    """``(outs, states, tops)`` of the walk; ``states``, one a chunk, is
+    the backward pass's alone and in the route's own layout.  A scan's
+    stacked output that nothing reads is never computed; a kernel's is
+    written all the same, so the kernels leave it out (``None``) without
+    ``keep_states``."""
+    w_v, w_k = operands[:2]
+    heads = _kernels(w_v, w_k)
     with jax.named_scope(KDA_RECURRENCE):
+        if heads is not None:
+            return walk_fwd(*operands, heads=heads, keep_states=keep_states)
         _, b, h, _, d_v = w_v.shape
         d_k = w_k.shape[-1]
 
@@ -230,16 +251,38 @@ def _walk_fwd(w_v, w_k, q_in, scores, k_out, decay):
                            jnp.max(jnp.abs(after)))
 
         _, (outs, states, tops) = jax.lax.scan(
-            body, jnp.zeros((b, h, d_k, d_v), jnp.float32),
-            (w_v, w_k, q_in, scores, k_out, decay))
-    return (outs, tops), (w_v, w_k, q_in, scores, k_out, decay, states)
+            body, jnp.zeros((b, h, d_k, d_v), jnp.float32), operands)
+    return outs, states, tops
+
+
+@jax.custom_vjp
+def _walk(w_v, w_k, q_in, scores, k_out, decay):
+    """The state through the chunks.  Arguments lead with the chunk axis
+    ``N``; returns the outputs ``(N, B, H, C, d_v)`` and, per chunk, the
+    largest magnitude in the state it leaves.  By the operands' shape
+    (:func:`_kernels`) either two Mosaic kernels that hold the state in
+    VMEM (:mod:`apex_tpu.ops.pallas.gated_delta_walk`) or a ``lax.scan``
+    over the chunks, forward and backward."""
+    outs, _, tops = _forward((w_v, w_k, q_in, scores, k_out, decay),
+                             keep_states=False)
+    return outs, tops
+
+
+def _walk_fwd(*operands):
+    outs, states, tops = _forward(operands, keep_states=True)
+    return (outs, tops), (*operands, states)
 
 
 def _walk_bwd(kept, cotangents):
     w_v, w_k, q_in, scores, k_out, decay, states = kept
     d_outs, _ = cotangents
-    op = w_v.dtype
+    heads = _kernels(w_v, w_k)
     with jax.named_scope(KDA_RECURRENCE):
+        if heads is not None:
+            return walk_bwd(states, d_outs, w_v, w_k, q_in, scores, k_out,
+                            decay, heads=heads)
+        op = w_v.dtype
+
         def body(d_after, xs):
             state, d_out, w_v, w_k, q_in, scores, k_out, decay = xs
             u = _chunk(state, w_v, w_k, q_in, scores, k_out, decay)[0]

@@ -68,7 +68,8 @@ MLP, LM_LOSS, PRETRAINING_LOSS = MODEL_SCOPES = (
 #: head norm and the output projection; the short convolutions with
 #: their SiLU and the L2 norm of q and k; and the chunkwise gated delta
 #: rule (:mod:`apex_tpu.attention.gated_delta`, which opens it itself,
-#: in its loop bodies and in its hand-written backward too).
+#: in its loop bodies, around its walk's kernels and in its hand-written
+#: backward too).
 KDA_PROJECT, KDA_CONV, KDA_RECURRENCE = KDA_SCOPES = (
     "kda_project", "kda_conv", "kda_recurrence")
 
