@@ -7,7 +7,9 @@ largest is a limit's lower reading), and for the first ``--controls``
 seeds puts stand-ins in the program's place (the smallest of a
 stand-in's readings is an upper reading): the float8 ``control``, and
 each fault of ``family.planted_faults(cfg, traffic)`` — the reference
-under a changed configuration or on a part of each batch.  (A batch of
+under a changed configuration, on a part of each batch or (a fault's
+third entry) told something else of the traffic: ``no_warmup`` and
+``no_balance`` leave out a part of the cell's recipe.  (A batch of
 one row has no half for ``calibrate.py``'s ``half_batch``; a family's
 own ``half_tokens`` takes its place.)
 
@@ -31,6 +33,8 @@ def main(argv=None) -> int:
     ap.add_argument("--controls", type=int, default=2)
     ap.add_argument("--first-seed", type=int, default=2_200_000_033)
     ap.add_argument("--out", required=True)
+    ap.add_argument("--only", default="",
+                    help="stand-ins to read, by name and comma (all)")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     from benchmark import run as bench_run
@@ -85,17 +89,22 @@ def main(argv=None) -> int:
     kw = train.reference_kwargs(cfg, traffic, devices)
     spec = family.reference.param_spec(cfg)
 
-    def follow(seed, cfg_=cfg, part=None, **extra):
+    def follow(seed, cfg_=cfg, part=None, told=None, **extra):
         kept = batches[seed] if part is None else [part(b)
                                                    for b in batches[seed]]
+        kw_ = kw if told is None else train.reference_kwargs(
+            cfg_, dict(traffic, **told), devices)
         return ref.follow(family.reference, cfg_, spec, seed, kept,
-                          **dict(kw, **extra))
+                          **dict(kw_, **extra))
 
     stand_ins = {"control": lambda seed: follow(seed,
                                                 q=common.fp8_operands)}
-    for name, (cfg_, part) in family.planted_faults(cfg, traffic).items():
-        stand_ins[name] = (lambda seed, cfg_=cfg_, part=part:
-                           follow(seed, cfg_, part))
+    for name, (cfg_, part, *told) in family.planted_faults(
+            cfg, traffic).items():
+        stand_ins[name] = (lambda seed, cfg_=cfg_, part=part, told=told:
+                           follow(seed, cfg_, part, *told))
+    if args.only:
+        stand_ins = {name: stand_ins[name] for name in args.only.split(",")}
     record = {"workload": args.workload, "seeds": seeds, "program": {},
               **{name: {} for name in stand_ins}}
     for n, seed in enumerate(seeds):
@@ -133,7 +142,8 @@ def main(argv=None) -> int:
         with open(args.out, "w") as f:
             json.dump(record, f, indent=1)
 
-    for number in ("loss_gap", "grad_gap", "delta_gap"):
+    for number in [k for k, v in record["program"][seeds[0]].items()
+                   if isinstance(v, float)]:
         lower = max(r[number] for r in record["program"].values())
         uppers = {name: min(r[number] for r in record[name].values())
                   for name in stand_ins if record[name]}

@@ -15,6 +15,21 @@ queued behind it is waited for and counted too.
 
 After the window the peak memory is read, the program's state is freed,
 and the plain reference follows the first steps on the same batches.
+
+Two parts of a training recipe come from the cell's ``parameters``
+(they define the job), not from the configuration: ``lr_warmup_steps``,
+a linear warm-up of the optimizer's rate (:func:`warmup`), and whatever
+a family's ``step_state(cfg, traffic)`` reads there (the expert
+families: ``balance_rate``).  A family that has such a function and
+returns something from it says that its step keeps state which no
+gradient moves: ``loss`` (``loss_fn(params, *batch) -> (loss, aux)``),
+the ``paths`` of the leaves, their ``update(values, aux) -> values`` on
+the float32 masters, and optionally ``describe(aux of some steps) ->
+str``.  The driver then builds the step with ``has_aux`` and applies
+the update inside the same compiled step, where the optimizer's step
+was not skipped (:func:`stateful`); the reference's side is
+``reference/train.py`` ``follow``.  A cell with neither key gets the
+step it always got.
 """
 
 from __future__ import annotations
@@ -60,6 +75,7 @@ class Run:
     busy_by_chip: list = dataclasses.field(default_factory=list)
     busy_s0: "float | None" = None
     exposed_collective_s: "float | None" = None
+    aux_traced: list = dataclasses.field(default_factory=list)
     notes: dict = dataclasses.field(default_factory=dict)
 
 
@@ -103,10 +119,55 @@ def moment(opt_state):
     return found[0]
 
 
+def warmup(lr: float, steps: int):
+    """``learning_rate(step)`` for the program's optimizer (``step`` its
+    own 1-based counter): ``lr * min(1, step / steps)`` in float32, the
+    rate ``reference/optim.py`` ``warmup`` gives its ``t``.  The
+    program's counter stands still on a step that a gradient overflow
+    skips (``Amp.step_if`` leaves the optimizer's state as it was), so
+    the rate is a function of the steps *applied*; the reference has no
+    loss scale, skips nothing and counts every step.  The two counters
+    agree while no step is skipped, and a step skipped among the first,
+    which ``correct`` compares, is a step the reference took and the
+    program did not: it reads 1 in ``grad_gap`` (the first) or a third
+    and more in ``delta_gap``, as before there was a schedule.  The
+    family's update of its own state is skipped with the optimizer's
+    (:func:`stateful`), so all the step's state stands still together."""
+    import jax.numpy as jnp
+
+    def rate(step):
+        return jnp.float32(lr) * jnp.minimum(
+            jnp.float32(1.0),
+            step.astype(jnp.float32) / jnp.float32(steps))
+
+    return rate
+
+
+def stateful(inner, kept: dict):
+    """``inner`` (a step built with ``has_aux``) followed, in the same
+    compiled step, by the family's update of the leaves no gradient
+    moves: on the float32 masters (the bfloat16 view is cast from them
+    at every step's start), from this step's ``aux``, and not where the
+    optimizer's step was skipped."""
+    import jax.numpy as jnp
+
+    def step(state, *batch):
+        state, m = inner(state, *batch)
+        flat = weights.flatten(state.master_params)
+        moved = kept["update"]({p: flat[p] for p in kept["paths"]},
+                               m["aux"])
+        flat.update({p: jnp.where(m["overflow"], flat[p], v)
+                     for p, v in moved.items()})
+        return state._replace(master_params=weights.nest(flat)), m
+
+    return step
+
+
 def make_step(cell: dict, cfg: dict, family, devices, step_wrapper=None):
     """The program's train step for this cell, not yet compiled: the
-    ``Amp`` object, the step function, the parameter spec, and (for a
-    data-parallel cell) the mesh with its two shardings."""
+    ``Amp`` object, the step function, the parameter spec, (for a
+    data-parallel cell) the mesh with its two shardings, and what the
+    family's ``step_state`` said (``hook``)."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -117,8 +178,16 @@ def make_step(cell: dict, cfg: dict, family, devices, step_wrapper=None):
     spec = family.reference.param_spec(cfg)
     opt = cfg["optimizer"]
     args = dict(opt["args"], betas=tuple(opt["args"]["betas"]))
+    if traffic.get("lr_warmup_steps"):
+        args["lr"] = warmup(args["lr"], traffic["lr_warmup_steps"])
     a = amp.initialize(optimizer=getattr(optimizers, opt["program"])(**args),
                        opt_level=cfg["opt_level"], verbosity=0)
+    kept = family.step_state(cfg, traffic) \
+        if hasattr(family, "step_state") else None
+    if kept and traffic.get("data_parallel"):
+        raise ValueError("a step with state of its own is not built for a "
+                         "data-parallel cell yet: what its rule reads would "
+                         "have to be summed over the chips")
     loss_fn = family.program_loss(cfg, traffic)
 
     replicated = by_rows = None
@@ -144,20 +213,27 @@ def make_step(cell: dict, cfg: dict, family, devices, step_wrapper=None):
     elif chips != 1:
         raise ValueError("a cell on several chips has to say how it uses "
                          "them (parameters.data_parallel)")
+    elif kept:
+        step_fn = stateful(amp.make_train_step(a, kept["loss"],
+                                               has_aux=True), kept)
     else:
         step_fn = amp.make_train_step(a, loss_fn)
     if step_wrapper is not None:
         step_fn = step_wrapper(step_fn)
     return dict(a=a, step_fn=step_fn, spec=spec, replicated=replicated,
-                by_rows=by_rows, beta1=args["betas"][0])
+                by_rows=by_rows, beta1=args["betas"][0], hook=kept)
 
 
 def reference_kwargs(cfg: dict, traffic: dict, devices) -> dict:
     """How ``reference.train.follow`` is to follow this cell."""
     args = cfg["optimizer"]["args"]
+    opt_kwargs = dict(args, betas=tuple(args["betas"]))
+    if traffic.get("lr_warmup_steps"):
+        opt_kwargs["lr_warmup_steps"] = traffic["lr_warmup_steps"]
     return dict(optimizer=cfg["optimizer"]["reference"],
-                opt_kwargs=dict(args, betas=tuple(args["betas"])),
-                block_rows=traffic["reference_block_rows"], devices=devices)
+                opt_kwargs=opt_kwargs,
+                block_rows=traffic["reference_block_rows"], devices=devices,
+                traffic=traffic)
 
 
 def build(cell: dict, cfg: dict, family, seed: int, devices, watch: Watch,
@@ -213,7 +289,7 @@ def build(cell: dict, cfg: dict, family, seed: int, devices, watch: Watch,
     return dict(a=a, spec=spec, key=key, state=state, compiled=compiled,
                 feed=feed, first=first, kept=kept,
                 init_s=init_s, compile_s=compile_s, warm_s=warm_s,
-                cache_hit=hit, beta1=made["beta1"])
+                cache_hit=hit, beta1=made["beta1"], hook=made["hook"])
 
 
 def first_steps(b: dict, n: int):
@@ -376,6 +452,13 @@ def run(cell: dict, cfg: dict, family, args, t_start: float, root: str,
     metrics = jax.device_get(metrics + (traced or []))
 
     n = len(stamps)
+    if b["hook"]:
+        # the counters of the step's own state: the traced steps' for the
+        # per-layer readers, the window's on a line of their own
+        run_.aux_traced = [m["aux"] for m in metrics[n:]]
+        if "describe" in b["hook"]:
+            say("step state: " + b["hook"]["describe"](
+                [m["aux"] for m in metrics[:n]]))
     intervals = [1e3 * (y - x) for x, y in zip(stamps, stamps[1:])]
     run_.tokens_per_s = n * tokens_per_step / stamps[-1]
     losses = [float(m["loss"]) for m in metrics]

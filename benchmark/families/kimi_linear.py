@@ -94,6 +94,19 @@ def program_loss(cfg: dict, traffic: dict):
     return loss_fn
 
 
+def step_state(cfg: dict, traffic: dict):
+    """The correction biases' balance update, as the DeepSeek-V3 family
+    has it, on this model and under its keys."""
+    return _deepseek.step_state(
+        cfg, traffic, model=program_model(cfg),
+        prepare=lambda params: with_dt_shift(params, cfg),
+        names=reference.as_deepseek(cfg))
+
+
+def expected_held_pairs(cfg: dict, traffic: dict) -> float:
+    return _deepseek.expected_held_pairs(reference.as_deepseek(cfg), traffic)
+
+
 def planted_faults(cfg: dict, traffic: dict) -> dict:
     """Faults of this model's own for ``benchmark/calibrate_faults.py``:
     per name the configuration the reference is computed under in the
@@ -111,6 +124,7 @@ def planted_faults(cfg: dict, traffic: dict) -> dict:
         "beta_one": (dict(cfg, planted="beta_one"), None),
         "rotary_on_latent": (dict(cfg, mla_use_nope=False), None),
         "unscaled": (dict(cfg, routed_scaling_factor=1.0), None),
+        **_deepseek.recipe_faults(cfg, traffic),
         "probe_g_bfloat16": (dict(cfg, planted="g_bfloat16"), None),
         "probe_state_bfloat16": (dict(cfg, planted="state_bfloat16"),
                                  None)}

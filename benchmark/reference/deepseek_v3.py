@@ -19,9 +19,11 @@ as each layer is.
 
 Departures from the published model, each stated in the configuration
 file: the depth, the experts held and the vocabulary are the chip's
-share; the correction bias is seeded and held fixed (it gets no
-gradient; the published balance update is not a gradient step either);
-no dropout.  The router's product is float32 in the published code and
+share; no dropout.  The correction bias is seeded and gets no gradient;
+where the cell's traffic gives a ``balance_rate`` it moves by the
+published balance update after every step (:func:`step_state`), from
+this chip's tokens' counts over all the router's experts.  The router's
+product is float32 in the published code and
 here, so the control's lower precision (``q``) does not reach it.
 """
 
@@ -176,17 +178,23 @@ def routed_experts(x, p, weights, experts, first: int, q=C.identity):
     return jax.lax.scan(one, jnp.zeros_like(x), (jnp.arange(held), p))[0]
 
 
-def layer(x, p, cfg: dict, q=C.identity):
+def layer_and_choice(x, p, cfg: dict, q=C.identity):
+    """The layer's output and the experts its router chose ``(B, L, k)``,
+    ``None`` for a dense layer."""
     eps = cfg["rms_norm_eps"]
     x = x + latent_attention(rms_norm(x, p["attn_norm"], eps),
                              p["attention"], cfg, q)
     h = rms_norm(x, p["ffn_norm"], eps)
     if "ffn" in p:
-        return x + gated(h, p["ffn"], q)
+        return x + gated(h, p["ffn"], q), None
     weights, experts = routing(h, p["router"], cfg)
     return x + gated(h, p["shared"], q) + routed_experts(
         h, p["experts"], weights, experts, cfg.get("first_expert_held", 0),
-        q)
+        q), experts
+
+
+def layer(x, p, cfg: dict, q=C.identity):
+    return layer_and_choice(x, p, cfg, q)[0]
 
 
 def chosen_experts(params, ids, cfg: dict, q=C.identity):
@@ -196,29 +204,91 @@ def chosen_experts(params, ids, cfg: dict, q=C.identity):
     x = params["tok_emb"]["embedding"][ids]
     chosen = []
     for n in range(cfg["num_hidden_layers"]):
-        p = params[f"block_{n}"]
-        if "router" in p:
-            eps = cfg["rms_norm_eps"]
-            h = x + latent_attention(rms_norm(x, p["attn_norm"], eps),
-                                     p["attention"], cfg, q)
-            chosen.append(routing(rms_norm(h, p["ffn_norm"], eps),
-                                  p["router"], cfg)[1])
-        x = layer(x, p, cfg, q)
+        x, experts = layer_and_choice(x, params[f"block_{n}"], cfg, q)
+        if experts is not None:
+            chosen.append(experts)
     return jnp.stack(chosen)
 
 
-def logits(params, ids, cfg: dict, q=C.identity):
-    x = params["tok_emb"]["embedding"][ids]
-    for n in range(cfg["num_hidden_layers"]):
-        x = jax.checkpoint(lambda x, p: layer(x, p, cfg, q))(
-            x, params[f"block_{n}"])
+def expert_counts(experts, n_experts: int):
+    """``(n_experts,)`` float32: the (token, expert) pairs each of *all*
+    the router's experts got."""
+    return jnp.sum(experts.reshape(-1, 1) == jnp.arange(n_experts), axis=0,
+                   dtype=jnp.float32)
+
+
+def run_layers(layers, x, params, n_experts: int):
+    """``x`` through ``layers`` (one ``(x, p) -> (x, experts or None)`` a
+    block, each recomputed in the backward pass) and every expert
+    layer's counts, ``(expert layers, n_experts)``."""
+    counts = []
+    for n, one in enumerate(layers):
+        def counted(x, p, one=one):
+            x, experts = one(x, p)
+            return x, (None if experts is None
+                       else expert_counts(experts, n_experts))
+        x, c = jax.checkpoint(counted)(x, params[f"block_{n}"])
+        if c is not None:
+            counts.append(c)
+    return x, jax.lax.stop_gradient(jnp.stack(counts)) if counts else None
+
+
+def logits_and_counts(params, ids, cfg: dict, q=C.identity):
+    x, counts = run_layers(
+        [lambda x, p: layer_and_choice(x, p, cfg, q)]
+        * cfg["num_hidden_layers"],
+        params["tok_emb"]["embedding"][ids], params, cfg["n_routed_experts"])
     return C.dense(rms_norm(x, params["final_norm"], cfg["rms_norm_eps"]),
-                   params["lm_head"], q)
+                   params["lm_head"], q), counts
+
+
+def logits(params, ids, cfg: dict, q=C.identity):
+    return logits_and_counts(params, ids, cfg, q)[0]
+
+
+def block_loss_and_counts(params, block, totals, cfg: dict, q=C.identity,
+                          forward=logits_and_counts):
+    """This block of rows' share of the batch's mean next-token cross
+    entropy over the vocabulary's slice, and its tokens' share of every
+    expert layer's counts (blocks add up)."""
+    (ids,) = block
+    out, counts = forward(params, ids, cfg, q)
+    ce = C.cross_entropy(out[:, :-1], ids[:, 1:])
+    return jnp.sum(ce) / totals["targets"], counts
 
 
 def block_loss(params, block, totals, cfg: dict, q=C.identity):
-    """This block of rows' share of the batch's mean next-token cross
-    entropy over the vocabulary's slice."""
-    (ids,) = block
-    ce = C.cross_entropy(logits(params, ids, cfg, q)[:, :-1], ids[:, 1:])
-    return jnp.sum(ce) / totals["targets"]
+    return block_loss_and_counts(params, block, totals, cfg, q)[0]
+
+
+def balance_update(bias, counts, rate: float):
+    """The published balance update of the correction bias (DeepSeek-V3,
+    arXiv:2412.19437 section 2.1.2, after arXiv:2408.15664): up by
+    ``rate`` for an expert that got fewer pairs than the mean over all
+    the experts, down for one that got more, unmoved at the mean."""
+    return bias + rate * jnp.sign(jnp.mean(counts) - counts)
+
+
+def bias_paths(cfg: dict) -> list:
+    """The correction biases, one an expert layer, in the layers' order."""
+    return [f"block_{n}/router/e_score_correction_bias"
+            for n in range(cfg["first_k_dense_replace"],
+                           cfg["num_hidden_layers"])]
+
+
+def step_state(cfg: dict, traffic: dict, loss=block_loss_and_counts):
+    """What a step keeps that no gradient moves (``reference/train.py``
+    ``follow``): nothing without a ``balance_rate`` in the cell's
+    traffic; with one, the correction biases, moved after the optimizer
+    by :func:`balance_update` from the counts of the step's own forward
+    pass."""
+    rate = traffic.get("balance_rate")
+    if not rate:
+        return None
+    paths = bias_paths(cfg)
+
+    def update(values: dict, counts) -> dict:
+        return {p: balance_update(values[p], counts[i], rate)
+                for i, p in enumerate(paths)}
+
+    return {"paths": paths, "block_loss": loss, "update": update}

@@ -224,32 +224,47 @@ def latent_attention(x, p, cfg: dict, q=C.identity):
     return C.dense(a.reshape(b, l, -1), p["o_proj"], q)
 
 
-def layer(x, p, kind: str, cfg: dict, q=C.identity):
+def layer_and_choice(x, p, kind: str, cfg: dict, q=C.identity):
+    """The layer's output and the experts its router chose ``(B, L, k)``,
+    ``None`` for a dense layer."""
     eps = cfg["rms_norm_eps"]
     mixer = kda if kind == "kda" else latent_attention
     x = x + mixer(D.rms_norm(x, p["attn_norm"], eps), p["attention"], cfg, q)
     h = D.rms_norm(x, p["ffn_norm"], eps)
     if "ffn" in p:
-        return x + D.gated(h, p["ffn"], q)
+        return x + D.gated(h, p["ffn"], q), None
     weights, experts = D.routing(h, p["router"], cfg)
     return x + D.gated(h, p["shared"], q) + D.routed_experts(
         h, p["experts"], weights, experts, cfg.get("first_expert_held", 0),
-        q)
+        q), experts
+
+
+def logits_and_counts(params, ids, cfg: dict, q=C.identity):
+    cfg = as_deepseek(cfg)
+    x, counts = D.run_layers(
+        [lambda x, p, kind=kind: layer_and_choice(x, p, kind, cfg, q)
+         for kind in kinds(cfg)],
+        params["tok_emb"]["embedding"][ids], params, cfg["n_routed_experts"])
+    return C.dense(D.rms_norm(x, params["final_norm"], cfg["rms_norm_eps"]),
+                   params["lm_head"], q), counts
 
 
 def logits(params, ids, cfg: dict, q=C.identity):
-    cfg = as_deepseek(cfg)
-    x = params["tok_emb"]["embedding"][ids]
-    for n, kind in enumerate(kinds(cfg)):
-        x = jax.checkpoint(lambda x, p, kind=kind: layer(x, p, kind, cfg, q))(
-            x, params[f"block_{n}"])
-    return C.dense(D.rms_norm(x, params["final_norm"], cfg["rms_norm_eps"]),
-                   params["lm_head"], q)
+    return logits_and_counts(params, ids, cfg, q)[0]
+
+
+def block_loss_and_counts(params, block, totals, cfg: dict, q=C.identity):
+    return D.block_loss_and_counts(params, block, totals, cfg, q,
+                                   forward=logits_and_counts)
 
 
 def block_loss(params, block, totals, cfg: dict, q=C.identity):
     """This block of rows' share of the batch's mean next-token cross
     entropy over the vocabulary's slice."""
-    (ids,) = block
-    ce = C.cross_entropy(logits(params, ids, cfg, q)[:, :-1], ids[:, 1:])
-    return jnp.sum(ce) / totals["targets"]
+    return block_loss_and_counts(params, block, totals, cfg, q)[0]
+
+
+def step_state(cfg: dict, traffic: dict):
+    """The correction biases' balance update, as the DeepSeek-V3
+    reference has it."""
+    return D.step_state(cfg, traffic, loss=block_loss_and_counts)

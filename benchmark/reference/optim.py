@@ -2,7 +2,9 @@
 
 ``adam``: Kingma & Ba (2015) with bias correction, epsilon outside the
 root, and (where ``weight_decay`` is set) L2 decay added to the
-gradient.  ``lamb``: You et al. (2020) as NVIDIA's apex computes it:
+gradient; with ``lr_warmup_steps`` the rate rises linearly to ``lr``
+over that many steps (:func:`warmup`).  ``lamb``: You et al. (2020) as
+NVIDIA's apex computes it:
 the gradient is first divided by ``max(global_norm / max_grad_norm, 1)``,
 then Adam's moments with bias correction, the decay added to the
 update, and each leaf's step scaled by ``|p| / |update|`` (1 where
@@ -20,10 +22,22 @@ def init(params):
     return {"m": zeros, "v": zeros, "t": jnp.float32(0.0)}
 
 
+def warmup(lr, t, lr_warmup_steps: int = 0):
+    """The rate at step ``t`` (1-based): ``lr * min(1, t /
+    lr_warmup_steps)``, float32; ``lr`` itself where there is no warm-up
+    (DeepSeek-V3, arXiv:2412.19437 section 4.2: linear from 0 over the
+    first 2K steps)."""
+    if not lr_warmup_steps:
+        return lr
+    return jnp.float32(lr) * jnp.minimum(
+        jnp.float32(1.0), t / jnp.float32(lr_warmup_steps))
+
+
 def adam(params, grads, state, *, lr, betas=(0.9, 0.999), eps=1e-8,
-         weight_decay=0.0):
+         weight_decay=0.0, lr_warmup_steps=0):
     b1, b2 = betas
     t = state["t"] + 1.0
+    lr = warmup(lr, t, lr_warmup_steps)
     if weight_decay:
         grads = jax.tree.map(lambda g, p: g + weight_decay * p, grads, params)
     m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, state["m"], grads)

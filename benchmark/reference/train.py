@@ -12,6 +12,12 @@ readings the driver takes from the program:
 - ``delta_norms``: per leaf, the norm of the parameters' change over
   the steps followed.
 
+A reference that keeps state no gradient moves says so
+(``ref.step_state(cfg, traffic)``: the leaves' ``paths``, a
+``block_loss`` that also returns what the rule reads, and the
+``update`` of those leaves): :func:`follow` applies it after its
+optimizer, every step, and names the leaves under ``state_paths``.
+
 :func:`gaps` reduces a pair of such readings to the numbers compared.
 """
 
@@ -61,7 +67,8 @@ def on_host(losses, grad_norms: dict, deltas: dict) -> dict:
 
 def follow(ref, cfg: dict, spec: dict, seed: int, batches, *, optimizer: str,
            opt_kwargs: dict, block_rows: int, rows: "int | None" = None,
-           q=common.identity, devices=None) -> dict:
+           q=common.identity, devices=None,
+           traffic: "dict | None" = None) -> dict:
     """Train ``len(batches)`` plain steps from the seed's weights.
 
     ``rows`` keeps only the first rows of every batch (a planted fault:
@@ -69,10 +76,15 @@ def follow(ref, cfg: dict, spec: dict, seed: int, batches, *, optimizer: str,
     the precision of the matmul operands (the control lowers it).
     With several ``devices`` the rows of a block are spread over them and
     the weights copied to each: the compiler divides the same plain
-    program, which is then the faster by that many chips.
+    program, which is then the faster by that many chips.  ``traffic``
+    is the cell's, for a reference whose step keeps state of its own.
     """
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     update = optim.OPTIMIZERS[optimizer]
+    kept = ref.step_state(cfg, traffic or {}) \
+        if hasattr(ref, "step_state") else None
+    block_loss = kept["block_loss"] if kept else (
+        lambda *args: (ref.block_loss(*args), None))
     key = weights.seed_key(seed)
     if rows is not None:
         batches = [tuple(a[:rows] for a in b) for b in batches]
@@ -97,13 +109,20 @@ def follow(ref, cfg: dict, spec: dict, seed: int, batches, *, optimizer: str,
 
         def body(carry, block):
             loss, grads = carry
-            l, g = jax.value_and_grad(ref.block_loss)(
+            (l, read), g = jax.value_and_grad(block_loss, has_aux=True)(
                 params, block, totals, cfg, q)
-            return (loss + l, jax.tree.map(jnp.add, grads, g)), None
+            return (loss + l, jax.tree.map(jnp.add, grads, g)), read
 
         zero = (jnp.float32(0.0), jax.tree.map(jnp.zeros_like, params))
-        (loss, grads), _ = jax.lax.scan(body, zero, blocks)
+        (loss, grads), read = jax.lax.scan(body, zero, blocks)
         params, ostate = update(params, grads, ostate, **opt_kwargs)
+        if kept:
+            # what the rule reads adds up over the blocks of rows
+            flat = weights.flatten(params)
+            flat.update(kept["update"](
+                {p: flat[p] for p in kept["paths"]},
+                jax.tree.map(lambda a: jnp.sum(a, axis=0), read)))
+            params = weights.nest(flat)
         return params, ostate, loss
 
     beta1 = opt_kwargs.get("betas", (0.9, 0.999))[0]
@@ -124,6 +143,7 @@ def follow(ref, cfg: dict, spec: dict, seed: int, batches, *, optimizer: str,
         deltas = jax.jit(lambda p, k: delta_norms(p, spec, k))(params, key)
         out = on_host(losses, grad_norms, deltas)
     del params, ostate
+    out["state_paths"] = list(kept["paths"]) if kept else []
     return out
 
 
@@ -138,6 +158,14 @@ def _worst(got: dict, want: dict, paths) -> "tuple[float, str]":
         (abs(got[p] - want[p]) / max(want[p], floor, 1e-30), p)
         for p in paths)
     return gap, path
+
+
+def _median(got: dict, want: dict, paths) -> float:
+    """The median leaf's gap of norms, measured as :func:`_worst`
+    measures the worst leaf's."""
+    floor = statistics.median(want[p] for p in paths)
+    return statistics.median(
+        abs(got[p] - want[p]) / max(want[p], floor, 1e-30) for p in paths)
 
 
 def gaps(got: dict, want: dict) -> dict:
@@ -156,7 +184,20 @@ def gaps(got: dict, want: dict) -> dict:
             if want["grad_norms"][p] >= DEAD_GRADIENT_SHARE * median]
     delta_gap, delta_leaf = _worst(got["delta_norms"], want["delta_norms"],
                                    live)
-    return {"loss_gap": loss_gap, "grad_gap": grad_gap,
-            "delta_gap": delta_gap,
-            "worst_leaves": {"grad_gap": grad_leaf, "delta_gap": delta_leaf},
-            "leaves_left_out": len(paths) - len(live)}
+    # the worst leaf of an expert model is a router's or an expert's, set
+    # by the few (token, expert) pairs that rounding moves; the median
+    # leaf's gap is steady from seed to seed and reads the precision
+    out = {"loss_gap": loss_gap, "grad_gap": grad_gap,
+           "grad_gap_median": _median(got["grad_norms"], want["grad_norms"],
+                                      paths),
+           "delta_gap": delta_gap,
+           "worst_leaves": {"grad_gap": grad_leaf, "delta_gap": delta_leaf},
+           "leaves_left_out": len(paths) - len(live)}
+    kept = want.get("state_paths")
+    if kept:
+        # leaves that a rule of the step's own moves, not a gradient:
+        # each against the reference's own change of it, no median floor
+        out["state_gap"], out["worst_leaves"]["state_gap"] = max(
+            (abs(got["delta_norms"][p] - want["delta_norms"][p])
+             / max(want["delta_norms"][p], 1e-30), p) for p in kept)
+    return out

@@ -110,7 +110,36 @@ def test_every_moves_target_is_reported_where_the_metric_is():
             assert cell in {w["name"] for w in BENCH["workloads"]}
 
 
+#: what ``reduced`` may never name, at the top or inside a group: a
+#: hidden, intermediate, latent, state or projection size, a key that
+#: ends in ``_dim`` or ``_rank``, a head size, an expansion factor, the
+#: number of experts a token takes
+WIDTH = re.compile(
+    r"(hidden_size|hidden_dim|d_model|n_embd|intermediate|n_inner|latent"
+    r"|state_size|d_state|proj|head_dim|head_size|expand|expansion|_dim$"
+    r"|_rank$|per_tok|experts_per)")
+
+
+def may_be_reduced(key: str) -> bool:
+    return bool(NAME.match(key)) and not WIDTH.search(key)
+
+
+@pytest.mark.parametrize("key, allowed", [
+    ("num_hidden_layers", True), ("n_layer", True), ("vocab_size", True),
+    ("n_routed_experts_held", True), ("num_experts_held", True),
+    ("hidden_size", False), ("n_embd", False), ("n_inner", False),
+    ("moe_intermediate_size", False), ("kv_lora_rank", False),
+    ("qk_nope_head_dim", False), ("head_dim", False), ("v_head_dim", False),
+    ("num_experts_per_tok", False), ("num_experts_per_token", False),
+    ("ssm_state_size", False), ("expand", False), ("proj_size", False),
+    ("a key", False)])
+def test_what_reduced_may_list(key, allowed):
+    assert may_be_reduced(key) is allowed
+
+
 def test_configurations_are_files_under_paths_at_published_widths():
+    """``reduced`` lists cuts of depth and of a chip's share, never a
+    width, and each cut stands beside its published value."""
     used = {w["config"] for w in BENCH["workloads"]}
     files = [c["file"] for c in BENCH["configs"]]
     assert len(files) == len(set(files))
@@ -119,7 +148,10 @@ def test_configurations_are_files_under_paths_at_published_widths():
         assert any(c["file"].startswith(p + "/") for p in BENCH["paths"])
         cfg = load(c["file"])
         assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
-        assert c["reduced"] == []
+        for key in c["reduced"]:
+            assert may_be_reduced(key), key
+            assert key in cfg and key in cfg["assumed"], key
+        assert bool(c["reduced"]) == ("published" in cfg)
     gpt = load("benchmark/configs/gpt2-medium.json")
     assert (gpt["n_embd"], gpt["n_layer"], gpt["n_head"], gpt["n_inner"],
             gpt["vocab_size"]) == (1024, 24, 16, 4096, 50257)

@@ -64,3 +64,33 @@ def test_parameter_counts_are_the_ones_the_configurations_state():
         == 405
     assert round(count(bert.reference.param_spec(
         cfg("bert-large-uncased"))) / 1e6) == 367
+
+
+def test_held_load_ratio_reads_the_steps_own_counters():
+    """Pairs served by the held experts over ``tokens x k x held / n``,
+    the mean over layers and traced steps, with the layers on ``note:``
+    lines; nothing where the step carried no counters."""
+    import types
+
+    import numpy as np
+
+    from benchmark.families import deepseek_v3 as family
+    from benchmark.readers import moe_held_load_ratio as reader
+    cfg = {"n_routed_experts": 128, "n_routed_experts_held": 16,
+           "num_experts_per_tok": 6}
+    traffic = {"rows_per_chip": 1, "seq": 8192}
+    assert family.expected_held_pairs(cfg, traffic) == 6144.0
+    steps = [{"pairs": np.array([6144, 3072]),
+              "load_peak": np.array([1.5, 2.0]), "windows": np.array([1, 1])},
+             {"pairs": np.array([6144, 9216]),
+              "load_peak": np.array([1.25, 3.0]),
+              "windows": np.array([1, 2])}]
+    run = types.SimpleNamespace(family=family, cfg=cfg, traffic=traffic,
+                                aux_traced=steps, notes={})
+    assert reader.read(run) == 1.0
+    assert run.notes["moe.held_load_ratio.by_layer"] == [1.0, 1.0]
+    assert run.notes["moe.held_load_ratio.fullest_expert_by_layer"] == \
+        [1.5, 3.0]
+    assert run.notes["moe.held_load_ratio.windows_by_layer"] == [1, 2]
+    run.aux_traced = []
+    assert reader.read(run) is None

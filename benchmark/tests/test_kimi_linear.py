@@ -1,9 +1,8 @@
 """The cut Kimi-Linear configuration's contract, this model's own planted
-faults, and its rehearsal on a second seed.  (``test_contract.py`` holds
-every listed configuration to ``reduced == []``; this one is one chip's
-share of a deployment, so its contract is here: published widths kept,
-exactly three keys cut, each beside its published value, and the
-deployment stated.)"""
+faults (the two parts of its cell's recipe among them), and its
+rehearsal on a second seed.  The configuration is one chip's share of a
+deployment: published widths kept, exactly three keys cut, each beside
+its published value, and the deployment stated."""
 
 import json
 import os
@@ -13,7 +12,7 @@ import pytest
 from benchmark.tests.conftest import ROOT
 from benchmark.tests.test_runs import in_process
 
-CELL = "kimi_linear_48b_a3b.lm_b1_s8192"
+CELL = "kimi_linear_48b_a3b.lm_b1_s8192_balanced"
 FILE = "benchmark/configs/kimi-linear-48b-a3b-instruct.json"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 #: no key of ``reduced`` may be one of these, at the top or in a group
@@ -102,11 +101,12 @@ def followed():
     rng = np.random.default_rng(23)
     rows = traffic["rows_per_chip"] * cell["chips"]
     batches = [family.make_batch(rng, rows, cfg, traffic) for _ in range(3)]
-    kw = train.reference_kwargs(cfg, traffic, jax.devices()[:1])
     spec = family.reference.param_spec(cfg)
 
-    def follow(cfg_, part=None):
+    def follow(cfg_, part=None, told=None):
         kept = batches if part is None else [part(b) for b in batches]
+        kw = train.reference_kwargs(cfg_, dict(traffic, **(told or {})),
+                                    jax.devices()[:1])
         return ref.follow(family.reference, cfg_, spec, 23, kept, **kw)
 
     return cell, cfg, family, traffic, follow, follow(cfg)
@@ -116,7 +116,8 @@ def followed():
 #: layers the latent scores are a few hundredths, the softmax is even and
 #: a rotation of q and k moves no norm (PERF.md section 2 has its reading
 #: on the chip, at the published widths)
-SEEN_AT_REHEARSAL_SIZE = ("half_tokens", "no_decay", "beta_one", "unscaled")
+SEEN_AT_REHEARSAL_SIZE = ("half_tokens", "no_decay", "beta_one", "unscaled",
+                          "no_warmup", "no_balance")
 #: readings, not faults: no limit tells them from float32 (PERF.md 6f)
 PROBES = ("probe_g_bfloat16", "probe_state_bfloat16")
 
@@ -126,7 +127,21 @@ def test_the_faults_and_the_probes_are_told_apart_by_name():
     _, cell, cfg, family, _ = run.resolve(CELL, rehearse=True)
     names = tuple(family.planted_faults(cfg, cell["parameters"]))
     assert names == ("half_tokens", "no_decay", "beta_one",
-                     "rotary_on_latent", "unscaled") + PROBES
+                     "rotary_on_latent", "unscaled", "no_warmup",
+                     "no_balance") + PROBES
+
+
+def test_the_cell_trains_under_the_published_recipe():
+    cell = load("benchmark/workloads", CELL + ".json")
+    assert cell["parameters"] == {
+        "rows_per_chip": 1, "seq": 8192, "lr_warmup_steps": 2000,
+        "balance_rate": 0.001, "warmup_steps": 8, "traced_steps": 6,
+        "reference_steps": 3, "reference_block_rows": 1}
+    cfg = load(FILE)
+    assert cfg["optimizer"]["args"]["lr"] == 0.0003
+    for words in ("0.001", "arXiv:2412.19437", "not all-reduced", "256"):
+        assert words in cfg["assumed"]["e_score_correction_bias"], words
+    assert "arXiv:2412.19437 section 4.2" in cfg["assumed"]["lr_warmup_steps"]
 
 
 @pytest.mark.parametrize("fault", SEEN_AT_REHEARSAL_SIZE
@@ -139,12 +154,14 @@ def test_a_planted_fault_comes_out_not_correct(followed, fault):
     from benchmark import run
     from benchmark.reference import train as ref
     cell, cfg, family, traffic, follow, want = followed
-    cfg_, part = family.planted_faults(cfg, traffic)[fault]
-    gaps = ref.gaps(follow(cfg_, part), want)
+    gaps = ref.gaps(follow(*family.planted_faults(cfg, traffic)[fault]),
+                    want)
     correct, table = run.judge({k: v for k, v in gaps.items()
                                 if k.endswith("_gap")}, cell["limits"])
     if fault in SEEN_AT_REHEARSAL_SIZE:
         assert correct is False, table
+        if fault == "no_balance":       # by the number that is the rule's
+            assert gaps["state_gap"] == pytest.approx(1.0, abs=1e-3), table
     else:
         assert gaps["grad_gap"] > 0.0, table       # the plant is there
     if fault in PROBES:

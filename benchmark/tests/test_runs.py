@@ -1,6 +1,8 @@
 """Whole runs at rehearsal size on the CPU: every cell end to end, the
 refusal without a chip, the faults and the control that ``correct`` has
-to catch, and a benchmark that grows by files alone."""
+to catch, a cell that asks for no recipe and gets the step it always
+got, and a benchmark that grows by files alone, a family with a rule of
+its own among them."""
 
 import json
 import os
@@ -174,7 +176,8 @@ def test_a_cell_a_configuration_a_driver_and_a_metric_are_added_by_files(
     assert done.returncode == 0, done.stderr[-2000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert line["attempted"] > 2 and line["rehearsal"] is True
-    assert set(line["compared"]) == {"loss_gap", "grad_gap", "delta_gap"}
+    assert set(line["compared"]) == {"loss_gap", "grad_gap",
+                                     "grad_gap_median", "delta_gap"}
 
     # the new metric's reader is found by name and read in its cell only
     probe = (
@@ -193,3 +196,138 @@ def test_a_cell_a_configuration_a_driver_and_a_metric_are_added_by_files(
                           env=dict(os.environ, PYTHONPATH=str(tmp_path)))
     assert done.returncode == 0, done.stderr[-2000:]
     assert done.stdout.splitlines() == ["['steps.traced']", "[]"]
+
+
+PLAIN_CELLS = [c for c in CELLS if not {"lr_warmup_steps", "balance_rate"}
+               & set(json.load(open(os.path.join(
+                   ROOT, "benchmark", "workloads", c + ".json")))
+                   ["parameters"])]
+
+
+@pytest.mark.parametrize("cell", PLAIN_CELLS)
+def test_a_cell_that_asks_for_no_recipe_gets_the_step_it_always_got(
+        monkeypatch, cell):
+    """Neither the schedule nor the stateful step is reached, the
+    optimizer gets the configuration's plain numbers, and (one chip) the
+    step lowers to the text of the step built the way it was before
+    there was a recipe: ``amp.make_train_step`` of the family's loss."""
+    import jax
+    import numpy as np
+
+    from apex_tpu import amp, optimizers
+    from benchmark import run, weights
+    from benchmark.drivers import train
+
+    def never(*args, **kwargs):
+        raise AssertionError("a cell without the keys reached the recipe")
+
+    monkeypatch.setattr(train, "warmup", never)
+    monkeypatch.setattr(train, "stateful", never)
+    _, c, cfg, family, _ = run.resolve(cell, rehearse=True)
+    assert not hasattr(family, "step_state") \
+        or family.step_state(cfg, c["parameters"]) is None
+    made = train.make_step(c, cfg, family, jax.devices()[:c["chips"]])
+    assert made["hook"] is None
+    kw = train.reference_kwargs(cfg, c["parameters"], None)
+    assert "lr_warmup_steps" not in kw["opt_kwargs"]
+    if c["chips"] != 1:
+        return
+    opt = cfg["optimizer"]
+    a = amp.initialize(optimizer=getattr(optimizers, opt["program"])(
+        **dict(opt["args"], betas=tuple(opt["args"]["betas"]))),
+        opt_level=cfg["opt_level"], verbosity=0)
+    before = amp.make_train_step(a, family.program_loss(cfg, c["parameters"]))
+    state = jax.eval_shape(lambda k: a.init(weights.make(made["spec"], k)),
+                           weights.seed_key(1))
+    batch = family.make_batch(np.random.default_rng(0),
+                              c["parameters"]["rows_per_chip"], cfg,
+                              c["parameters"])
+
+    def text(step):
+        return jax.jit(step, donate_argnums=(0,)).lower(
+            state, *batch).as_text()
+
+    assert text(made["step_fn"]) == text(before)
+
+
+OWN_FAMILY = '''
+"""A family added by files alone: the DeepSeek-V3 family's model and
+counters, a warm-up, and a balance rule of its own (in proportion to an
+expert's excess over the mean, at the cell's ``own_rate``)."""
+from benchmark.families import deepseek_v3 as _base
+from benchmark.families.deepseek_v3 import *  # noqa: F401,F403
+from benchmark.reference import deepseek_own as reference  # noqa: F401
+
+
+def step_state(cfg, traffic):
+    kept = _base.step_state(cfg, dict(traffic, balance_rate=1.0))
+    return dict(kept, update=reference.own_update(kept["paths"],
+                                                  traffic["own_rate"],
+                                                  lambda aux: aux["counts"]),
+                describe=lambda steps: f"a rule of its own over "
+                                       f"{len(steps)} steps")
+'''
+
+OWN_REFERENCE = '''
+"""The plain reference of ``families/deepseek_own.py``."""
+import jax.numpy as jnp
+
+from benchmark.reference import deepseek_v3 as _base
+from benchmark.reference.deepseek_v3 import *  # noqa: F401,F403
+
+
+def own_update(paths, rate, counts_of):
+    def update(values, read):
+        counts = counts_of(read).astype(jnp.float32)
+        excess = counts / jnp.mean(counts, axis=-1, keepdims=True) - 1.0
+        return {p: values[p] - rate * excess[i] for i, p in enumerate(paths)}
+    return update
+
+
+def step_state(cfg, traffic):
+    kept = _base.step_state(cfg, dict(traffic, balance_rate=1.0))
+    return dict(kept, update=own_update(kept["paths"], traffic["own_rate"],
+                                        lambda counts: counts))
+'''
+
+
+def test_a_family_with_a_rule_of_its_own_is_added_by_files(tmp_path):
+    """What a later ``model_config`` PR needs: a new family asks for the
+    warm-up through its cell's parameters and brings the update of its
+    own state through ``step_state`` on both sides, and no file that is
+    there is edited.  ``correct`` holds the two sides' rule to each
+    other (``state_gap``): a program that left it out would read 1."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    here = tmp_path / "benchmark"
+    (here / "families/deepseek_own.py").write_text(OWN_FAMILY)
+    (here / "reference/deepseek_own.py").write_text(OWN_REFERENCE)
+    bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    listed = [c for c in bench["configs"]
+              if c["name"].startswith("kanana")][0]
+    cfg = json.loads((tmp_path / listed["file"]).read_text())
+    cfg.update(name="deepseek-own", family="deepseek_own")
+    (here / "configs/deepseek-own.json").write_text(json.dumps(cfg))
+    cell = json.loads((here / "workloads" / (
+        [w["name"] for w in bench["workloads"]
+         if w["config"] == listed["name"]][0] + ".json")).read_text())
+    cell.update(name="deepseek_own.lm", config="deepseek-own", traffic="lm")
+    del cell["parameters"]["balance_rate"]
+    cell["parameters"].update(lr_warmup_steps=100, own_rate=0.002)
+    (here / "workloads/deepseek_own.lm.json").write_text(json.dumps(cell))
+    bench["configs"].append(dict(listed, name="deepseek-own",
+                                 file="benchmark/configs/deepseek-own.json"))
+    bench["workloads"].append({k: cell[k] for k in (
+        "name", "config", "traffic", "chips", "why")})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    done = command("--workload", "deepseek_own.lm", "--seed", "7",
+                   "--seconds", "0.2", "--trace", "0", "--rehearse",
+                   cwd=tmp_path, env={"PYTHONPATH": ROOT})
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert "step state: a rule of its own over" in done.stdout
+    assert line["correct"] is True, line["compared"]
+    gap = line["compared"]["state_gap"]
+    assert gap["limit"] is not None and 0.0 < gap["value"] < 0.1, gap
