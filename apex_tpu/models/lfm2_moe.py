@@ -11,9 +11,9 @@ is the kind the published ``layer_types`` gives it:
 - *Gated short convolution* (:class:`ShortConvMixer`, ``"conv"``):
   ``[B | C | u] = x W_in`` (three hidden-wide parts, in that order),
   ``y = C * conv(B * u)`` with ``conv`` depthwise and causal over
-  ``conv_L_cache`` taps (:func:`apex_tpu.layers.short_conv`, the taps
-  Kimi Delta Attention's convolutions use), no activation, then
-  ``W_out``.
+  ``conv_L_cache`` taps (:func:`gate`: on the chip a pair of Mosaic
+  kernels, else :func:`apex_tpu.layers.short_conv`, the taps Kimi Delta
+  Attention's convolutions use), no activation, then ``W_out``.
 - *Grouped-KV attention* (:class:`GroupedQueryAttention`,
   ``"full_attention"``): ``num_heads`` q heads over
   ``num_key_value_heads`` KV heads; q and k each through a per-head
@@ -55,6 +55,9 @@ import flax.linen as nn
 from apex_tpu.layers import Dense, ShortConvTaps, short_conv
 from apex_tpu.models.deepseek_v3 import DeepseekV3Config, feed_forward
 from apex_tpu.normalization import FusedRMSNorm
+from apex_tpu.ops import use_pallas
+from apex_tpu.ops.pallas.shortconv_gate import (gate_bwd, gate_fwd,
+                                                gate_geometry)
 from apex_tpu.ops.rope import rope_tables
 from apex_tpu.utils.profiling import SHORTCONV_GATE, SHORTCONV_PROJECT
 
@@ -99,24 +102,70 @@ class Lfm2MoeConfig(DeepseekV3Config):
         return self.layer_types[n], n < self.num_dense_layers
 
 
+def _kernel_rows(bcu, taps) -> "int | None":
+    """Rows a grid step of the gate's Mosaic kernels takes where the
+    kernels run (``apex_tpu.ops.use_pallas``) and the shape is theirs
+    (:func:`~apex_tpu.ops.pallas.shortconv_gate.gate_geometry`), else
+    ``None``: the XLA gate."""
+    _, length, three_h = bcu.shape
+    if not use_pallas():
+        return None
+    return gate_geometry(length, three_h // 3, taps.shape[0], bcu.dtype)
+
+
+@jax.checkpoint
+def _xla_gate(bcu, taps):
+    """``C * conv(B * u)`` as XLA ops; under ``jax.checkpoint`` the
+    backward pass recomputes ``B * u`` and its convolution from ``bcu``
+    and keeps none of the float32 products."""
+    b, g, u = jnp.split(bcu.astype(jnp.float32), 3, axis=-1)
+    return (g * short_conv(b * u, taps.astype(jnp.float32))
+            ).astype(bcu.dtype)
+
+
+@jax.custom_vjp
+def _kernel_gate(bcu, taps):
+    return gate_fwd(bcu, taps, rows=_kernel_rows(bcu, taps))
+
+
+def _kernel_gate_fwd(bcu, taps):
+    with jax.named_scope(SHORTCONV_GATE):
+        return gate_fwd(bcu, taps, rows=_kernel_rows(bcu, taps)), (bcu, taps)
+
+
+def _kernel_gate_bwd(kept, d_y):
+    bcu, taps = kept
+    with jax.named_scope(SHORTCONV_GATE):
+        return gate_bwd(d_y, bcu, taps, rows=_kernel_rows(bcu, taps))
+
+
+_kernel_gate.defvjp(_kernel_gate_fwd, _kernel_gate_bwd)
+
+
+def gate(bcu, taps):
+    """``C * conv(B * u)`` of ``bcu = [B | C | u]`` over the taps ``(n,
+    h)``, formed in float32 and rounded once to ``bcu``'s dtype.  By the
+    shape (:func:`_kernel_rows`) either two Mosaic kernels, forward and
+    backward (:mod:`apex_tpu.ops.pallas.shortconv_gate`; the residuals
+    are ``bcu`` and the taps), or :func:`_xla_gate`."""
+    if _kernel_rows(bcu, taps) is None:
+        return _xla_gate(bcu, taps)
+    return _kernel_gate(bcu, taps)
+
+
 class ShortConvMixer(nn.Module):
-    """``W_out (C * conv(B * u))`` with ``[B | C | u] = x W_in``.  The
-    gate runs under ``jax.checkpoint``: the backward pass recomputes
-    ``B * u`` and its convolution from the in-projection's output and
-    keeps none of the float32 products."""
+    """``W_out (C * conv(B * u))`` with ``[B | C | u] = x W_in``; the gate
+    is :func:`gate`: on the chip, at a width of whole 128 lanes, a pair
+    of Mosaic kernels that form ``B * u``, the taps and ``C * s`` in VMEM
+    forward and again backward, where they also sum the taps' gradient;
+    else XLA ops under ``jax.checkpoint``.  Either way the backward pass
+    keeps only the in-projection's output and the taps."""
 
     cfg: Lfm2MoeConfig
 
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-
-        @jax.checkpoint
-        def gate(bcu, taps):
-            b, g, u = jnp.split(bcu.astype(jnp.float32), 3, axis=-1)
-            return (g * short_conv(b * u, taps.astype(jnp.float32))
-                    ).astype(bcu.dtype)
-
         with jax.named_scope(SHORTCONV_PROJECT):
             bcu = Dense(3 * c.hidden_size, use_bias=False,
                         name="in_proj")(x)
