@@ -21,13 +21,19 @@ starts from ``S_0``, with ``G_r`` the chunk's running sum of ``g``::
 (the WY / UT form of the Kimi Linear report, arXiv 2510.26692).  Two
 parts: what needs no state (``A``, ``Aq``, ``T``, ``W_v``, ``W_k`` and
 the decayed ``Q``, ``K``) is computed for all chunks at once; the state
-then walks the chunks, four small products a chunk and head.  **The walk
-has two routes, read from the operands' shape at trace time**
-(:func:`_kernels`): heads that fill the 128 lanes, in chunks of whole
-sublane tiles of the operand dtype, walk in two Mosaic kernels, one
-forward and one backward, that hold a group of heads' state in VMEM from
-the first chunk to the last (:mod:`apex_tpu.ops.pallas.gated_delta_walk`;
-off the chip in interpret mode); any other shape (narrow heads) walks
+then walks the chunks, four small products a chunk and head.  **Each
+part has two routes, read from the operands' shape at trace time by one
+rule** (:func:`_kernels`): heads that fill the 128 lanes, in chunks of
+whole sublane tiles of the operand dtype, take Mosaic kernels, off the
+chip in interpret mode.  What needs no state is then one kernel forward
+and one backward over every chunk at once, a grid step a chunk of up to
+eight heads, all of it formed in VMEM
+(:mod:`apex_tpu.ops.pallas.gated_delta_chunk`); the walk is one kernel
+forward and one backward that hold a group of heads' state in VMEM from
+the first chunk to the last
+(:mod:`apex_tpu.ops.pallas.gated_delta_walk`).  Any other shape (narrow
+heads) computes what needs no state as XLA operations
+(:func:`_without_state`, which is also the kernels' oracle) and walks
 under one ``lax.scan`` of ``L / C`` trips a pass.  One algorithm, the
 same products in the same roundings; only the tiling depends on the
 widths.
@@ -47,8 +53,9 @@ substitution in float32, three bfloat16 passes a product: it does not
 lose digits when keys repeat, where a sum of powers would); its backward pass is the
 inverse's own, ``-T^T dT T^T``, not autodiff through the levels.
 
-**Precision.**  ``g``, its running sums (a product at ``highest``), the
-exponentials, ``T`` and the state are float32.  The products' operands are ``q.dtype`` (bfloat16
+**Precision.**  ``g``, its running sums (a product at ``highest``; in
+the kernels shifted adds), the exponentials, ``T`` and the state are
+float32.  The products' operands are ``q.dtype`` (bfloat16
 under amp O2; the state is rounded as an operand and carried in float32)
 and every product accumulates in float32.
 
@@ -57,22 +64,26 @@ and every product accumulates in float32.
 d_k x d_v`` float32; the kernels write it only when differentiated, the
 primal call leaves it out) and recomputes ``U`` from it, walking the
 chunks backwards with the state's cotangent, in VMEM scratch or as the
-scan's carry; what needs no state is
-differentiated by autodiff under ``jax.checkpoint``, so nothing of its
-levels but the triangular inverse is kept between the passes, and in up
-to eight slices of the
-chunks one after another (``lax.map``), so that a pass holds one slice's
-levels at a time.
+scan's carry.  What needs no state has, on the kernels' route, a
+backward derived by hand (``jax.custom_vjp``; the derivation is
+:mod:`apex_tpu.ops.pallas.gated_delta_chunk`'s): it keeps only its five
+operands and forms ``G``, ``A`` and ``T`` again in VMEM.  On the XLA
+route it is differentiated by autodiff under ``jax.checkpoint``, so
+nothing of its levels but the triangular inverse is kept between the
+passes, and in up to eight slices of the chunks one after another
+(``lax.map``), so that a pass holds one slice's levels at a time.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from apex_tpu.ops.pallas.gated_delta_chunk import chunk_bwd, chunk_fwd
 from apex_tpu.ops.pallas.gated_delta_walk import (walk_bwd, walk_fwd,
                                                   walk_geometry)
 from apex_tpu.utils.profiling import KDA_RECURRENCE
@@ -199,6 +210,27 @@ def _without_state(q, k, v, g, beta):
             (k * jnp.exp(last - big)).astype(op), jnp.exp(last[..., 0, :]))
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _stateless(q, k, v, g, beta, heads: int):
+    """:func:`_without_state` by the Mosaic kernels
+    (:mod:`apex_tpu.ops.pallas.gated_delta_chunk`) for the walk's groups
+    of ``heads`` heads (:func:`_kernels`); the residuals are the five
+    operands.  Called inside the scope; the backward opens it again."""
+    return chunk_fwd(q, k, v, g, beta, heads=heads)
+
+
+def _stateless_fwd(q, k, v, g, beta, heads):
+    return _stateless(q, k, v, g, beta, heads), (q, k, v, g, beta)
+
+
+def _stateless_bwd(heads, operands, cotangents):
+    with jax.named_scope(KDA_RECURRENCE):
+        return chunk_bwd(*operands, *cotangents, heads=heads)
+
+
+_stateless.defvjp(_stateless_fwd, _stateless_bwd)
+
+
 def _in_groups(fn, groups: int, *args):
     """``fn`` over the leading axis of ``args``, ``groups`` slices of it
     one after another (``lax.map``), so that what ``fn`` holds while it
@@ -226,7 +258,9 @@ def _chunk(state, w_v, w_k, q_in, scores, k_out, decay):
 def _kernels(w_v, w_k):
     """Heads a grid step of the walk's Mosaic kernels takes where the
     operands' shape is theirs (full 128-lane heads, a chunk of whole
-    sublane tiles), else ``None``: the scan."""
+    sublane tiles), else ``None``: the scan, and ``_without_state``.
+    ``(N, B, H, C, d)`` operands of the walk's, or ``v`` and ``q`` cut
+    into chunks."""
     _, b, h, c, d_v = w_v.shape
     return walk_geometry(b * h, c, w_k.shape[-1], d_v, w_v.dtype)
 
@@ -337,13 +371,17 @@ def chunk_gated_delta_rule(q, k, v, g, beta, *, chunk_size: int = 64,
 
     with jax.named_scope(KDA_RECURRENCE):
         g = chunks(g.astype(jnp.float32))
-        parts = _in_groups(
-            jax.checkpoint(
-                _without_state,
-                policy=jax.checkpoint_policies.save_only_these_names(_KEPT)),
-            math.gcd(n, _GROUPS),
-            chunks(q), chunks(k.astype(q.dtype)), chunks(v.astype(q.dtype)),
-            g, chunks(beta.astype(jnp.float32)))
+        operands = (chunks(q), chunks(k.astype(q.dtype)),
+                    chunks(v.astype(q.dtype)), g,
+                    chunks(beta.astype(jnp.float32)))
+        heads = _kernels(operands[2], operands[0])
+        if heads is not None:
+            parts = _stateless(*operands, heads)
+        else:
+            parts = _in_groups(
+                jax.checkpoint(_without_state, policy=jax.checkpoint_policies
+                               .save_only_these_names(_KEPT)),
+                math.gcd(n, _GROUPS), *operands)
     outs, tops = _walk(*parts)
     with jax.named_scope(KDA_RECURRENCE):
         o = jnp.moveaxis(jnp.moveaxis(outs, 3, 2), 0, 1).reshape(

@@ -2,13 +2,14 @@
 token by token (``benchmark/reference/kimi_linear.py`` ``delta_rule``,
 which imports nothing of ``apex_tpu``): outputs and every gradient in
 float32, at lengths that are and are not whole chunks, with decays from
-mild to ``exp(-30)`` and far beyond a chunk; the state's walk by its two
-routes, the Mosaic kernels (full 128-lane heads; interpret mode here)
-against the scan on the same operands, and which shapes take which; what
-the compiled rule loops over; the scope its loop bodies and its kernels
-carry.
+mild to ``exp(-30)`` and far beyond a chunk, by both routes (full
+128-lane heads take the Mosaic kernels of both parts, interpret mode
+here); the state's walk by its two routes on the same operands, and
+which shapes take which; what the compiled rule loops over; the scope
+its loop bodies and its kernels carry.
 """
 
+import math
 import re
 import sys
 from pathlib import Path
@@ -21,7 +22,7 @@ import pytest
 
 from apex_tpu.attention import gated_delta
 from apex_tpu.attention.gated_delta import chunk_gated_delta_rule
-from apex_tpu.ops.pallas import gated_delta_walk
+from apex_tpu.ops.pallas import gated_delta_chunk, gated_delta_walk
 from apex_tpu.utils.profiling import KDA_RECURRENCE
 
 REPO = Path(__file__).resolve().parents[2]
@@ -29,8 +30,8 @@ sys.path.insert(0, str(REPO))
 from benchmark.reference import kimi_linear as reference  # noqa: E402
 
 B, H, D = 2, 3, 16
-#: heads the walk's kernels take: full lanes, ``B x WIDE_H`` one group of
-#: eight (six heads of any width keep the scan)
+#: heads the kernels take: full lanes, ``B x WIDE_H`` one group of eight
+#: (six heads of any width keep the scan and ``_without_state``)
 WIDE_H, WIDE_D = 4, 128
 ROUTES = {"scan": (H, D), "kernels": (WIDE_H, WIDE_D)}
 
@@ -49,13 +50,17 @@ def inputs(length, per_token_decay, seed=0, h=H, d=D):
 
 
 # a chunk of 16: 1.9 a token is exp(-30) a chunk; 40 a token underflows
-# float32 within three tokens
-# and the last two through the walk's kernels, 150 tokens being no whole
-# chunk and 24 two chunks of float32's sublane tile
+# float32 within three tokens; by the kernels besides 150 tokens, no whole
+# chunk of 64, and 24, two chunks of float32's sublane tile.  (At 128
+# channels g's cotangent under 40 a token cancels to 1.7e-5 of its largest
+# entry by either route: the kernels' underflow is tested against the XLA
+# part in test_gated_delta_chunk.py)
 @pytest.mark.parametrize("length,chunk,decay,route", [
     (64, 16, 0.1, "scan"), (50, 16, 0.1, "scan"), (37, 16, 1.9, "scan"),
     (48, 16, 40.0, "scan"), (70, 64, 0.47, "scan"),
-    (150, 64, 0.47, "kernels"), (24, 8, 3.8, "kernels")])
+    (150, 64, 0.47, "kernels"), (24, 8, 3.8, "kernels"),
+    (64, 16, 0.1, "kernels"), (50, 16, 0.1, "kernels"),
+    (37, 16, 1.9, "kernels")])
 def test_outputs_and_every_gradient_match_the_token_recurrence(
         length, chunk, decay, route):
     """Float32 by two routes; 1e-5 of the largest entry is a few
@@ -128,9 +133,12 @@ def test_bfloat16_operands_keep_float32_decay_and_state():
 
 
 def eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    """Every equation of a jaxpr and of the jaxprs its equations hold,
+    but not of a kernel's body."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
         for value in eqn.params.values():
             for inner in (value if isinstance(value, (tuple, list))
                           else (value,)):
@@ -142,11 +150,11 @@ def eqns(jaxpr):
 @pytest.mark.parametrize("route", ROUTES)
 def test_no_loop_runs_a_trip_a_token(route):
     """The state's walk is one loop of ``L / chunk`` trips forward and
-    one backward (narrow heads), or one kernel forward and one backward
-    and no such loop (full-lane heads); what needs no state runs in at
-    most as many slices; no ``while``, and no scan over tokens (the
-    reference's own scan, by contrast, has ``L`` trips in blocks of
-    64)."""
+    one backward, and what needs no state runs in at most as many slices
+    (narrow heads); or each part is one kernel forward and one backward,
+    and nothing loops (full-lane heads); no ``while``, and no scan over
+    tokens (the reference's own scan, by contrast, has ``L`` trips in
+    blocks of 64)."""
     length, chunk = 384, 64
     args = inputs(length, 0.1, 0, *ROUTES[route])
     jaxpr = jax.make_jaxpr(jax.grad(
@@ -162,8 +170,9 @@ def test_no_loop_runs_a_trip_a_token(route):
         assert trips.count(length // chunk) >= 2 and not kernels
         assert max(trips) == length // chunk
     else:
-        assert kernels == ["kda_walk_fwd", "kda_walk_bwd"]
-        assert max(trips) < length // chunk
+        assert kernels == ["kda_chunk_fwd", "kda_walk_fwd", "kda_walk_bwd",
+                           "kda_chunk_bwd"]
+        assert not trips
     slow = jax.make_jaxpr(lambda *a: reference.delta_rule(*a))(*args)
     slow = [e.params["length"] for e in eqns(slow.jaxpr)
             if e.primitive.name == "scan"]
@@ -175,8 +184,9 @@ def test_the_scope_stands_on_the_instructions_inside_the_loops(route):
     """``benchmark/kda_scopes.py`` reads the ``op_name`` of each
     instruction of the compiled step: inside both loops' bodies every
     instruction that has one carries ``kda_recurrence``, and the
-    backward loop's are autodiff's ``transpose``; so do the two kernels'
-    calls as they are lowered for the chip, each under its own name."""
+    backward loop's are autodiff's ``transpose``; so do the four kernels'
+    calls as they are lowered for the chip, each under its own name, the
+    backward ones under ``transpose``."""
     args = inputs(64, 0.1, 0, *ROUTES[route])
     grad = jax.jit(jax.grad(
         lambda *a: jnp.sum(chunk_gated_delta_rule(*a, chunk_size=16)),
@@ -188,20 +198,23 @@ def test_the_scope_stands_on_the_instructions_inside_the_loops(route):
                   if "/while/body/" in name]
         assert len(inside) > 10
     else:
-        with mock.patch.object(gated_delta_walk, "on_tpu", lambda: True):
+        names = ["kda_chunk_bwd", "kda_chunk_fwd", "kda_walk_bwd",
+                 "kda_walk_fwd"]
+        with mock.patch.object(gated_delta_walk, "on_tpu", lambda: True), \
+                mock.patch.object(gated_delta_chunk, "on_tpu", lambda: True):
             text = grad.trace(*args).lower(
                 lowering_platforms=("tpu",)).as_text(debug_info=True)
-        assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
-            "kda_walk_bwd", "kda_walk_fwd"]
+        assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == names
         # each kernel is lowered inside a function of its own, as
         # ``<name>/pallas_call``; XLA puts the call's ``op_name`` before
         # it when it inlines the function
-        assert all(f'loc("{name}/pallas_call"' in text
-                   for name in ("kda_walk_fwd", "kda_walk_bwd"))
+        assert all(f'loc("{name}/pallas_call"' in text for name in names)
         inside = [set(re.split(r"[/()]+", name)) for name in re.findall(
-            r'loc\("([^"]*/jit\(walk_\w+\))"', text)]
-        assert len(inside) == 2
-        assert [s for s in inside if "transpose" in s][0] >= {"walk_bwd"}
+            r'loc\("([^"]*/jit\((?:walk|chunk)_\w+\))"', text)]
+        assert len(inside) == 4
+        backward = [s for s in inside if "transpose" in s]
+        assert len(backward) == 2
+        assert {"walk_bwd", "chunk_bwd"} <= set().union(*backward)
     assert all(KDA_RECURRENCE in s for s in inside)
     assert any("transpose" in s for s in inside)
     assert any("transpose" not in s for s in inside)
@@ -257,9 +270,11 @@ def test_the_kernels_walk_as_the_scan_walks(dtype):
     (2, 5, 64, 128, jnp.float32, None),        # ten heads: no group
 ])
 def test_the_route_is_read_from_the_shape(b, h, chunk, d, dtype, heads):
-    """Kernels where the heads fill the lanes, the chunk whole sublane
-    tiles of the operand dtype and the heads come in groups of sixteen
-    or eight or as one smaller group; the scan for any other shape."""
+    """Kernels for both parts where the heads fill the lanes, the chunk
+    whole sublane tiles of the operand dtype and the heads come in
+    groups of sixteen or eight or as one smaller group; for any other
+    shape the scan, and ``_without_state`` under ``jax.checkpoint`` in
+    slices of the chunks (``lax.map``)."""
     q = jax.ShapeDtypeStruct((b, 4 * chunk, h, d), dtype)
     g = jax.ShapeDtypeStruct(q.shape, jnp.float32)
     beta = jax.ShapeDtypeStruct(q.shape[:3], jnp.float32)
@@ -269,9 +284,16 @@ def test_the_route_is_read_from_the_shape(b, h, chunk, d, dtype, heads):
                if e.primitive.name == "pallas_call"]
     walks = [e for e in eqns(jaxpr.jaxpr) if e.primitive.name == "scan"
              and e.params["length"] == 4 and e.params["num_carry"]]
+    slices = [e for e in eqns(jaxpr.jaxpr) if e.primitive.name == "scan"
+              and not e.params["num_carry"]]
+    remat = [e for e in eqns(jaxpr.jaxpr) if e.primitive.name == "remat2"]
     if heads is None:
         assert not kernels and len(walks) == 1
+        assert len(slices) == 1 and len(remat) == 1
     else:
-        assert [e.params["name"] for e in kernels] == ["kda_walk_fwd"]
-        assert not walks
-        assert kernels[0].params["grid_mapping"].grid == (b * h // heads, 4)
+        assert [e.params["name"] for e in kernels] == ["kda_chunk_fwd",
+                                                       "kda_walk_fwd"]
+        assert not walks and not slices and not remat
+        # the stateless part's kernels take up to eight of them a step
+        assert [e.params["grid_mapping"].grid for e in kernels] == [
+            (4, b * h // math.gcd(heads, 8)), (b * h // heads, 4)]
