@@ -52,8 +52,9 @@ def _vary_like(reference_array, axis_name):
     return lambda t: lax.pcast(t, vma, to="varying")
 
 
-def _block_scores(q, k, scale, q_off, k_off, causal, kv_mask):
-    """fp32 attention scores for one (local-q, rotating-k) block pair."""
+def _block_scores(q, k, scale, q_off, k_off, causal, kv_mask, window=None):
+    """fp32 attention scores for one (local-q, rotating-k) block pair; a
+    ``window`` (with ``causal``) hides keys ``window`` or more behind."""
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if kv_mask is not None:
@@ -64,6 +65,9 @@ def _block_scores(q, k, scale, q_off, k_off, causal, kv_mask):
         kpos = k_off + jnp.arange(lk)
         s = jnp.where(qpos[None, None, :, None] >= kpos[None, None, None, :],
                       s, NEG_INF)
+        if window is not None:
+            s = jnp.where(qpos[None, None, :, None] - kpos[None, None, None, :]
+                          < window, s, NEG_INF)
     return s
 
 
@@ -284,7 +288,8 @@ def attention(
     ``impl="flash"`` / ``impl="jnp"``), else the selected sequence-parallel
     implementation (``impl="flash"``/``"jnp"`` with an ``axis_name`` select
     the ring path's block engine).  ``k`` and ``v`` may hold fewer heads
-    than ``q`` (grouped KV heads, locally only): see
+    than ``q`` (grouped KV heads, locally only), and ``window=W`` keeps
+    each query to its ``W`` latest keys (locally only): see
     :func:`~apex_tpu.ops.pallas.flash_attention.flash_attention`."""
     if impl not in ("ring", "ulysses", "flash", "jnp"):
         raise ValueError(f"unknown attention impl {impl!r}")
@@ -298,6 +303,13 @@ def attention(
     if k.shape[head_ax] != q.shape[head_ax] and axis_name is not None:
         # Grouped KV heads (fewer k/v heads than q's) run locally only.
         raise ValueError("grouped KV heads require axis_name=None")
+    window = kwargs.pop("window", None)
+    if window is not None and axis_name is not None:
+        # A window runs locally only: the ring and Ulysses engines see
+        # whole blocks of keys.
+        raise ValueError("a window requires axis_name=None")
+    if window is not None and not kwargs.get("causal", False):
+        raise ValueError("a window requires causal attention")
     if rope is not None and axis_name is not None:
         # The sequence-parallel engines take pre-rotated q/k (positions
         # are global, each rank rotates its shard before the collective).
@@ -315,7 +327,7 @@ def attention(
                                    block_k=kwargs.get("block_k"),
                                    return_lse=kwargs.get("return_lse",
                                                          False),
-                                   rope=rope)
+                                   rope=rope, window=window)
         if rope is not None:
             from apex_tpu.ops.rope import apply_rope_tables
             q, k = apply_rope_tables(q, k, rope, layout)
@@ -323,7 +335,7 @@ def attention(
             # jnp fallback speaks (B, L, H, D)
             out = attention(jnp.moveaxis(q, 1, 2), jnp.moveaxis(k, 1, 2),
                             jnp.moveaxis(v, 1, 2), axis_name=None,
-                            impl=impl, **kwargs)
+                            impl=impl, window=window, **kwargs)
             if kwargs.get("return_lse", False):
                 return jnp.moveaxis(out[0], 1, 2), out[1]
             return jnp.moveaxis(out, 1, 2)
@@ -331,7 +343,7 @@ def attention(
         k, v = repeat_kv_heads(k, v, q.shape[2])
         s = _block_scores(q, k, kwargs.get("scale") or 1.0 / (q.shape[-1] ** 0.5),
                           0, 0, kwargs.get("causal", False),
-                          kwargs.get("kv_mask"))
+                          kwargs.get("kv_mask"), window)
         m = s.max(axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = p.sum(axis=-1, keepdims=True)

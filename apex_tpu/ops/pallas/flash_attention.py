@@ -44,6 +44,11 @@ fewer (grouped KV heads); every kernel's K/V index map then reads the
 KV head of its q head's group (:func:`_kv_head`), and the backward's
 per-q-head dk/dv are summed over each group outside the kernels.
 
+A sliding window (``window=W``, causal) has kernels of its own,
+``flash_window_fwd`` / ``flash_window_bwd``: each block of q rows meets
+the band of key blocks that holds its last ``W`` keys in one pair, and
+no other (see the section ``sliding window`` below).
+
 Masking: ``kv_mask`` (key padding) arrives as an additive fp32 bias row
 ``(B, L)`` (0 = attend, ``NEG_INF`` = ignore); causal masking is computed
 from block offsets inside the kernel.  Fully-masked query rows produce
@@ -479,6 +484,12 @@ def _bwd_pair(q, k, v, do, lse_col, delta_col, bias_row, *, masked,
     p = _bwd_p(q, k, bias_row, lse_col, masked=masked, has_bias=has_bias,
                q_start=q_start, k_start=k_start, block_q=block_q,
                block_k=block_k)
+    return _pair_grads(p, q, k, v, do, delta_col)
+
+
+def _pair_grads(p, q, k, v, do, delta_col):
+    """A pair's fp32 ``(dq, dk, dv)`` from its recomputed probabilities:
+    p and dp feed all three gradients."""
     dv = jax.lax.dot_general(
         p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # (bk, d)
@@ -1234,6 +1245,303 @@ def _zeros_typed_like(shape, like):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+# -- sliding window --------------------------------------------------------
+#
+# ``window=W``: query ``i`` sees keys ``i - W < j <= i``.  The head's K and
+# V are one block a KV head (fetched once for its group of q heads, whose
+# grid steps are consecutive), q walks the grid ``block`` rows at a time,
+# and each q block meets the band of ``n`` key blocks that ends at its own,
+# ``n = ceil((W - 1) / block) + 1`` (:func:`_band`), in one pair: a score
+# tile ``(block, n block)``, one row max and one row sum.  No key block
+# outside the band is fetched, scored or masked; the pair's band mask
+# covers both of its edges.  The backward walks q the same way: dq is
+# whole when its pair ends; dk and dv add up in fp32 scratch over the
+# KV head's group and leave the kernel once per KV head, summed.
+
+
+#: Rows of q a windowed call scores at a time; its key blocks are as tall.
+_WINDOW_BLOCK = 256
+
+
+def _band(lp: int, block: int, window: int) -> int:
+    """Key blocks of ``block`` rows that the band of a q block of as many
+    rows meets under a window of ``window`` keys: ``ceil((W - 1) /
+    block) + 1``, and no more than the head's ``lp / block``."""
+    return min(-(-(window - 1) // block) + 1, lp // block)
+
+
+def _band_start(iq, n: int, nk: int):
+    """The first key block of q block ``iq``'s band of ``n`` blocks: the
+    band ends at the q block's own, and a band that would start before
+    the head starts at its first block (its later keys are then masked
+    as the diagonal's are)."""
+    return jnp.minimum(jnp.maximum(iq - (n - 1), 0), nk - n)
+
+
+def _window_mask(rows, width, q_start, k_start, window):
+    qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0)
+    kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    return jnp.logical_and(qpos >= kpos, qpos - kpos < window)
+
+
+def _window_refs(rest, n_in, rope):
+    """``(rope_refs, outs_and_scratch, krot_scr)`` of a window kernel."""
+    nrope = 2 if rope else 0
+    return (rest[:nrope], rest[nrope:nrope + n_in],
+            rest[nrope + n_in] if rope else None)
+
+
+def _rotate_k_once(k_ref, krot_scr, rope_refs, nk, block):
+    """K rotated into scratch a block at a time (once per KV head)."""
+    @pl.loop(0, nk)
+    def _(j):
+        start = pl.multiple_of(j * block, block)
+        rows = pl.ds(start, block)
+        ck, sk = _rope_k(rope_refs, "resident", start, block)
+        krot_scr[rows, :] = _rot(k_ref[0, rows, :], ck, sk).astype(
+            krot_scr.dtype)
+
+
+def _window_fwd_kernel(q_ref, k_ref, v_ref, *rest, rope, block, n, nk,
+                       window, groups):
+    rope_refs, (o_ref, lse_ref), krot_scr = _window_refs(rest, 2, rope)
+    bh, iq = pl.program_id(0), pl.program_id(1)
+    q_start = iq * block
+    k_start = pl.multiple_of(_band_start(iq, n, nk) * block, block)
+    band = pl.ds(k_start, n * block)
+    q = q_ref[0]
+    if rope:
+        @pl.when(jnp.logical_and(bh % groups == 0, iq == 0))
+        def _():
+            _rotate_k_once(k_ref, krot_scr, rope_refs, nk, block)
+
+        cq, sq = _rope_q(rope_refs, "resident", q_start, block)
+        q = _rot(q, cq, sq).astype(q_ref.dtype)
+        k = krot_scr[band, :]
+    else:
+        k = k_ref[0, band, :]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = jnp.where(_window_mask(block, n * block, q_start, k_start, window),
+                  s, NEG_INF)
+    # every row sees its own key: m is finite and masked entries give 0
+    m = jnp.max(s, axis=1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=1, keepdims=True)
+    pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, band, :],
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    o_ref[0] = (pv / l).astype(o_ref.dtype)
+    lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), (block, _STATS_W))
+
+
+def _window_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *rest,
+                       rope, block, n, nk, window, groups):
+    rope_refs, (dq_ref, dk_ref, dv_ref, dk_scr, dv_scr), krot_scr = \
+        _window_refs(rest, 5, rope)
+    bh, iq = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(jnp.logical_and(bh % groups == 0, iq == 0))
+    def _init():
+        if rope:
+            _rotate_k_once(k_ref, krot_scr, rope_refs, nk, block)
+
+        @pl.loop(0, nk)
+        def _(j):
+            rows = pl.ds(pl.multiple_of(j * block, block), block)
+            dk_scr[rows, :] = jnp.zeros((block, dk_scr.shape[1]),
+                                        jnp.float32)
+            dv_scr[rows, :] = jnp.zeros((block, dv_scr.shape[1]),
+                                        jnp.float32)
+
+    q_start = iq * block
+    k_start = pl.multiple_of(_band_start(iq, n, nk) * block, block)
+    band = pl.ds(k_start, n * block)
+    q = q_ref[0]
+    if rope:
+        cq, sq = _rope_q(rope_refs, "resident", q_start, block)
+        q = _rot(q, cq, sq).astype(q_ref.dtype)
+        k = krot_scr[band, :]
+    else:
+        k = k_ref[0, band, :]
+    do = do_ref[0]
+    delta = jnp.sum(o_ref[0].astype(jnp.float32) * do.astype(jnp.float32),
+                    axis=1, keepdims=True)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = jnp.where(_window_mask(block, n * block, q_start, k_start, window),
+                  s, NEG_INF)
+    p = jnp.exp(s - lse_ref[0][:, :1])
+    dq, dk, dv = _pair_grads(p, q, k, v_ref[0, band, :], do, delta)
+    dk_scr[band, :] += dk
+    dv_scr[band, :] += dv
+    if rope:
+        # dq is w.r.t. the ROTATED q: R^T is the rotation with -sin
+        dq = _rot(dq, cq, -sq)
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(bh % groups == groups - 1,
+                             iq == pl.num_programs(1) - 1))
+    def _emit():
+        @pl.loop(0, nk)
+        def _(j):
+            start = pl.multiple_of(j * block, block)
+            rows = pl.ds(start, block)
+            dk_j = dk_scr[rows, :]
+            if rope:
+                ck, sk = _rope_k(rope_refs, "resident", start, block)
+                dk_j = _rot(dk_j, ck, -sk)
+            dk_ref[0, rows, :] = dk_j.astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_scr[rows, :].astype(dv_ref.dtype)
+
+
+def _window_bytes(lp: int, d: int, d_v: int, itemsize: int, rope: bool,
+                  block: int, n: int) -> int:
+    """VMEM the windowed backward, the larger of the two kernels, needs:
+    K, V, dk and dv whole and double-buffered, the fp32 dk/dv sums (and
+    the rotated K) as scratch, the tables whole, the q walk's streams,
+    and four fp32 tiles of the band's pair."""
+    row, vrow = lp * _ceil_to(d, _LANES), lp * _ceil_to(d_v, _LANES)
+    held = 2 * 2 * (row + vrow) * itemsize
+    scratch = (row + vrow) * 4 + (row * itemsize if rope else 0)
+    tables = 2 * 2 * row * itemsize if rope else 0
+    streams = 2 * block * (2 * (_ceil_to(d, _LANES) + _ceil_to(d_v, _LANES))
+                           * itemsize + _STATS_W * 4)
+    return held + scratch + tables + streams + 4 * block * n * block * 4
+
+
+def _window_params(need: int):
+    """The window kernels' compiler parameters: the grid's two axes are
+    sequential (scratch carries over a KV head's q heads and q blocks),
+    and a scoped-VMEM limit of the estimate and a quarter."""
+    limit = max(5 * need // 4, 16 << 20)
+    if limit > 7 * _vmem_capacity() // 8:
+        raise ValueError(
+            f"flash_attention: a window over a head of this length needs "
+            f"{need} bytes of VMEM, more than the chip holds")
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=int(limit))
+
+
+def _window_specs(lp, d, block, h, groups, rope, cos_t, sin_t):
+    """Shared in_specs pieces: q-walk rows, the KV head whole, tables."""
+    by_q = lambda w: pl.BlockSpec((1, block, w), lambda bh, iq: (bh, iq, 0))
+    kv = lambda w: pl.BlockSpec((1, lp, w),
+                                lambda bh, iq: (bh // groups, 0, 0))
+    table = pl.BlockSpec((1, lp, d), lambda bh, iq: (bh // h, 0, 0))
+    return by_q, kv, ([cos_t, sin_t], [table, table]) if rope else ([], [])
+
+
+@functools.partial(jax.jit, static_argnames=("rope", "block", "window",
+                                             "num_heads", "groups"))
+def _flash_window_fwd(qf, kf, vf, cos_t, sin_t, *, rope, block, window,
+                      num_heads, groups):
+    bh, lp, d = qf.shape
+    d_v = vf.shape[2]
+    nk, n = lp // block, _band(lp, block, window)
+    by_q, kv, (tables, table_specs) = _window_specs(
+        lp, d, block, num_heads, groups, rope, cos_t, sin_t)
+    scratch = [pltpu.VMEM((lp, d), kf.dtype)] if rope else []
+    need = _window_bytes(lp, d, d_v, qf.dtype.itemsize, rope, block, n)
+    return pl.pallas_call(
+        functools.partial(_window_fwd_kernel, rope=rope, block=block, n=n,
+                          nk=nk, window=window, groups=groups),
+        grid=(bh, nk),
+        in_specs=[by_q(d), kv(d), kv(d_v)] + table_specs,
+        out_specs=[by_q(d_v), by_q(_STATS_W)],
+        out_shape=[_sds((bh, lp, d_v), qf.dtype, qf),
+                   _sds((bh, lp, _STATS_W), jnp.float32, qf)],
+        scratch_shapes=scratch,
+        name="flash_window_fwd",
+        interpret=not on_tpu(),
+        compiler_params=_window_params(need),
+    )(qf, kf, vf, *tables)
+
+
+@functools.partial(jax.jit, static_argnames=("rope", "block", "window",
+                                             "num_heads", "groups"))
+def _flash_window_bwd(qf, kf, vf, of, do_f, lse, cos_t, sin_t, *, rope,
+                      block, window, num_heads, groups):
+    bh, lp, d = qf.shape
+    d_v = vf.shape[2]
+    nk, n = lp // block, _band(lp, block, window)
+    by_q, kv, (tables, table_specs) = _window_specs(
+        lp, d, block, num_heads, groups, rope, cos_t, sin_t)
+    scratch = [pltpu.VMEM((lp, d), jnp.float32),
+               pltpu.VMEM((lp, d_v), jnp.float32)]
+    if rope:
+        scratch.append(pltpu.VMEM((lp, d), kf.dtype))
+    need = _window_bytes(lp, d, d_v, qf.dtype.itemsize, rope, block, n)
+    bkv = kf.shape[0]
+    return pl.pallas_call(
+        functools.partial(_window_bwd_kernel, rope=rope, block=block, n=n,
+                          nk=nk, window=window, groups=groups),
+        grid=(bh, nk),
+        in_specs=[by_q(d), kv(d), kv(d_v), by_q(d_v), by_q(d_v),
+                  by_q(_STATS_W)] + table_specs,
+        out_specs=[by_q(d), kv(d), kv(d_v)],
+        out_shape=[_sds((bh, lp, d), qf.dtype, qf),
+                   _sds((bkv, lp, d), qf.dtype, qf),
+                   _sds((bkv, lp, d_v), qf.dtype, qf)],
+        scratch_shapes=scratch,
+        name="flash_window_bwd",
+        interpret=not on_tpu(),
+        compiler_params=_window_params(need),
+    )(qf, kf, vf, do_f, of, lse, *tables)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_window(q, k, v, cos_t, sin_t, scale, window, block, rope,
+                  layout):
+    return _window_core(q, k, v, cos_t, sin_t, scale, window, block, rope,
+                        layout)[0]
+
+
+def _window_core(q, k, v, cos_t, sin_t, scale, window, block, rope, layout):
+    b, h = q.shape[0], q.shape[1 if layout == "bhld" else 2]
+    l = q.shape[2 if layout == "bhld" else 1]
+    qf, kf, vf, _, lp = _prep(q, k, v, None, block, block, layout)
+    groups = qf.shape[0] // kf.shape[0]
+    qf = qf * jnp.asarray(scale, qf.dtype)
+    if rope and cos_t.shape[1] != lp:
+        pad = ((0, 0), (0, lp - cos_t.shape[1]), (0, 0))
+        cos_t, sin_t = jnp.pad(cos_t, pad), jnp.pad(sin_t, pad)
+    of, lse = _flash_window_fwd(qf, kf, vf, cos_t, sin_t, rope=rope,
+                                block=block, window=window, num_heads=h,
+                                groups=groups)
+    return (_unprep(of, b, l, h, layout),
+            (qf, kf, vf, of, lse, cos_t, sin_t))
+
+
+def _window_fwd_rule(q, k, v, cos_t, sin_t, scale, window, block, rope,
+                     layout):
+    out, res = _window_core(q, k, v, cos_t, sin_t, scale, window, block,
+                            rope, layout)
+    return out, (res, q.shape, cos_t.shape)
+
+
+def _window_bwd_rule(scale, window, block, rope, layout, saved, dout):
+    (qf, kf, vf, of, lse, cos_t, sin_t), shape, table_shape = saved
+    b, h = shape[0], shape[1 if layout == "bhld" else 2]
+    l = shape[2 if layout == "bhld" else 1]
+    groups = qf.shape[0] // kf.shape[0]
+    do_f = _pad_bhld(dout, qf.shape[1], layout)
+    dqf, dkf, dvf = _flash_window_bwd(
+        qf, kf, vf, of, do_f, lse, cos_t, sin_t, rope=rope, block=block,
+        window=window, num_heads=h, groups=groups)
+    # the kernels differentiate w.r.t. the pre-scaled q and sum dk, dv
+    # over each KV head's group
+    return (_unprep(dqf, b, l, h, layout) * jnp.asarray(scale, dqf.dtype),
+            _unprep(dkf, b, l, h // groups, layout),
+            _unprep(dvf, b, l, h // groups, layout),
+            _zeros_typed_like(table_shape, cos_t),
+            _zeros_typed_like(table_shape, sin_t))
+
+
+_flash_window.defvjp(_window_fwd_rule, _window_bwd_rule)
+
+
 def repeat_kv_heads(k, v, heads: int):
     """``(B, L, Hkv, D)`` ``k`` and ``v`` with each KV head repeated for
     the ``heads // Hkv`` q heads that read it (q head ``h`` reads KV head
@@ -1246,11 +1554,13 @@ def repeat_kv_heads(k, v, heads: int):
     return jnp.repeat(k, groups, axis=2), jnp.repeat(v, groups, axis=2)
 
 
-def _jnp_attention(q, k, v, *, causal, kv_mask, scale, return_lse=False):
+def _jnp_attention(q, k, v, *, causal, kv_mask, scale, return_lse=False,
+                   window=None):
     """Materializing jnp path with the kernel's exact conventions (fp32
     softmax, masked rows emit zeros) — the cross-attention fallback and
     the interpret-mode stand-in under ``shard_map`` (see
-    :func:`flash_attention`).  Grouped KV heads are repeated to q's."""
+    :func:`flash_attention`).  Grouped KV heads are repeated to q's; a
+    ``window`` (with ``causal``) hides keys ``window`` or more behind."""
     k, v = repeat_kv_heads(k, v, q.shape[2])
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
@@ -1261,6 +1571,8 @@ def _jnp_attention(q, k, v, *, causal, kv_mask, scale, return_lse=False):
         qpos = jnp.arange(q.shape[1])[:, None]
         kpos = jnp.arange(k.shape[1])[None, :]
         visible = visible & (qpos >= kpos)[None, None]
+        if window is not None:
+            visible = visible & (qpos - kpos < window)[None, None]
     s = jnp.where(visible, s, NEG_INF)
     m = s.max(axis=-1, keepdims=True)
     p = jnp.where(visible, jnp.exp(s - m), 0.0)
@@ -1384,14 +1696,14 @@ def _long_head_bytes(lp: int, d: int, itemsize: int, rope: bool,
     return held + scratch + tables + streams + tiles
 
 
-def _grid_block(l: int, itemsize: int, rope: bool) -> int:
+def _grid_block(l: int, itemsize: int) -> int:
     """The grid walk's default block edge: :func:`_default_block`, capped
-    at 512 for fp32 activations with rope tables — the fused backward at
-    1024-blocks already sits near the 16 MB scoped-VMEM cliff in fp32,
-    and the table blocks push it over (16.93 MB on the O0 L2048 train
-    step, round 4)."""
+    at 512 for fp32 activations — the fused backward at 1024-blocks
+    sits at the 16 MB scoped-VMEM cliff in fp32: Mosaic refuses it
+    without rope tables, and the table blocks push it further over
+    (16.93 MB on the O0 L2048 train step, round 4)."""
     block = _default_block(l)
-    return min(block, 512) if rope and itemsize == 4 else block
+    return min(block, 512) if itemsize == 4 else block
 
 
 def _geometry(l: int, d: int, itemsize: int, causal: bool, rope: bool,
@@ -1427,7 +1739,7 @@ def _geometry(l: int, d: int, itemsize: int, causal: bool, rope: bool,
                 need = _long_head_bytes(lp, d, itemsize, rope, c, span, d_v)
             if 5 * need // 4 <= 7 * _vmem_capacity() // 8:
                 return _Geometry(True, c, span, 5 * need // 4)
-    return _Geometry(False, _grid_block(l, itemsize, rope))
+    return _Geometry(False, _grid_block(l, itemsize))
 
 
 @functools.lru_cache(maxsize=None)
@@ -1446,7 +1758,7 @@ def _warn_block_override(name: str, asked: int, got: int) -> None:
 
 def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
                     block_q=None, block_k=None, return_lse=False,
-                    layout="blhd", rope=None):
+                    layout="blhd", rope=None, window=None):
     """Blockwise exact attention, ``(B, L, H, D)`` convention.
 
     ``v`` may have a head width of its own, ``(B, L, H, Dv)`` (latent
@@ -1499,6 +1811,15 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
     With ``return_lse`` also returns the per-row logsumexp ``(B, L, H)``
     fp32 (``NEG_INF`` for fully-masked rows) — differentiable, so partial
     results can be merged online (ring attention's carry).
+
+    ``window=W`` (with ``causal``) lets query ``i`` see keys ``i - W < j
+    <= i`` only.  Its kernels (``flash_window_fwd`` / ``flash_window_bwd``)
+    visit the band's key blocks and no other: each block of q rows meets
+    the ``ceil((W - 1) / block) + 1`` key blocks that end at its own in
+    one pair, K and V held whole a KV head and dk, dv summed over its
+    group inside the backward.  ``block_q`` / ``block_k`` (the larger)
+    set the block; there is no key mask and no logsumexp on this path.
+    ``W >= L`` is the causal call.
     """
     if layout not in ("blhd", "bhld"):
         raise ValueError(f"unknown layout {layout!r}")
@@ -1519,6 +1840,14 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
     if rope is not None and k.shape[seq_ax] != l:
         raise ValueError("rope requires self-attention (Lq == Lk): q and "
                          "k share one position table")
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise ValueError(f"a window (got {window}) is a positive count "
+                             f"of keys and requires causal attention")
+        if kv_mask is not None or return_lse:
+            raise ValueError("a window takes no key mask and returns no "
+                             "logsumexp")
+        window = None if int(window) >= k.shape[seq_ax] else int(window)
     if k.shape[seq_ax] != l or (not on_tpu() and jax.typeof(q).vma):
         # Cross-attention (blockwise packing needs one shared length) and
         # interpret-mode-under-shard_map (a VMA propagation limitation in
@@ -1534,12 +1863,16 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
             from apex_tpu.ops.rope import apply_rope_tables
             qb, kb = apply_rope_tables(qb, kb, rope)
         out = _jnp_attention(qb, kb, vb, causal=causal, kv_mask=kv_mask,
-                             scale=float(scale), return_lse=return_lse)
+                             scale=float(scale), return_lse=return_lse,
+                             window=window)
         if layout == "bhld":
             if return_lse:
                 return jnp.moveaxis(out[0], 1, 2), out[1]
             return jnp.moveaxis(out, 1, 2)
         return out
+    if window is not None:
+        return _windowed(q, k, v, scale, window, block_q, block_k, layout,
+                         rope)
     explicit = (block_q, block_k)
     itemsize = jnp.dtype(q.dtype).itemsize
     geo = _geometry(l, d_head, itemsize, bool(causal), rope is not None,
@@ -1547,7 +1880,7 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
     # Explicit blocks are the caller's blocks: the grid walks them.
     resident = geo.resident and explicit == (None, None)
     default = (geo.block if resident
-               else _grid_block(l, itemsize, rope is not None))
+               else _grid_block(l, itemsize))
     if block_q is None:
         block_q = default
     if block_k is None:
@@ -1582,21 +1915,7 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
     rope_mode = None
     cos_t = sin_t = jnp.zeros((), jnp.float32)   # unused placeholder
     if rope is not None:
-        from apex_tpu.ops.rope import KernelRopeTables, rope_kernel_tables
-        table_dtype = (jnp.bfloat16 if q.dtype == jnp.bfloat16
-                       else jnp.float32)
-        if isinstance(rope, KernelRopeTables):
-            # Prebuilt kernel-format tables: callers with scanned/remat
-            # layer bodies construct them once per step so the
-            # concat/sign-fold/cast stays out of the compiled layer loop.
-            cos_t = rope.cos_full.astype(table_dtype)
-            sin_t = rope.sin_signed.astype(table_dtype)
-            if cos_t.shape[0] != b:
-                cos_t = jnp.broadcast_to(cos_t, (b,) + cos_t.shape[1:])
-                sin_t = jnp.broadcast_to(sin_t, (b,) + sin_t.shape[1:])
-        else:
-            cos_t, sin_t = rope_kernel_tables(rope[0], rope[1], b, l,
-                                              d_head, table_dtype)
+        cos_t, sin_t = _kernel_tables(rope, q, b, l, d_head)
         lp = _ceil_to(l, math.lcm(int(block_q), int(block_k)))
         per_side = 2 * lp * d_head * cos_t.dtype.itemsize
         # A resident head holds its tables whole (_resident_bytes counts
@@ -1608,3 +1927,35 @@ def flash_attention(q, k, v, *, causal=False, kv_mask=None, scale=None,
                   resident, bool(return_lse),
                   geo.span if resident else None,
                   geo.vmem_limit if resident else None)
+
+
+def _kernel_tables(rope, q, b, l, d_head):
+    """The kernels' ``(cos_full, sin_signed)`` tables ``(B, L, D)`` from a
+    public ``rope`` argument, in q's dtype where that is bf16."""
+    from apex_tpu.ops.rope import KernelRopeTables, rope_kernel_tables
+    table_dtype = (jnp.bfloat16 if q.dtype == jnp.bfloat16
+                   else jnp.float32)
+    if isinstance(rope, KernelRopeTables):
+        # Prebuilt kernel-format tables: callers with scanned/remat
+        # layer bodies construct them once per step so the
+        # concat/sign-fold/cast stays out of the compiled layer loop.
+        cos_t = rope.cos_full.astype(table_dtype)
+        sin_t = rope.sin_signed.astype(table_dtype)
+        if cos_t.shape[0] != b:
+            cos_t = jnp.broadcast_to(cos_t, (b,) + cos_t.shape[1:])
+            sin_t = jnp.broadcast_to(sin_t, (b,) + sin_t.shape[1:])
+        return cos_t, sin_t
+    return rope_kernel_tables(rope[0], rope[1], b, l, d_head, table_dtype)
+
+
+def _windowed(q, k, v, scale, window, block_q, block_k, layout, rope):
+    """:func:`flash_attention`'s windowed kernel path (see there)."""
+    seq_ax = 2 if layout == "bhld" else 1
+    b, l = q.shape[0], q.shape[seq_ax]
+    asked = max(block_q or 0, block_k or 0) or _WINDOW_BLOCK
+    block = min(_ceil_to(int(asked), _LANES), _ceil_to(l, _LANES))
+    cos_t = sin_t = jnp.zeros((), jnp.float32)   # unused placeholder
+    if rope is not None:
+        cos_t, sin_t = _kernel_tables(rope, q, b, l, q.shape[-1])
+    return _flash_window(q, k, v, cos_t, sin_t, float(scale), window, block,
+                         rope is not None, layout)

@@ -41,8 +41,7 @@ def rope_tables(positions: jax.Array, head_dim: int,
     half = head_dim // 2
     freqs = jnp.exp(-jnp.log(theta)
                     * jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[:, :, None].astype(jnp.float32) * freqs  # (B, L, half)
-    return jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    return scaled_rope_tables(positions, freqs)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -203,3 +202,96 @@ def apply_rope_interleaved(x: jax.Array, cos: jax.Array,
                     precision="highest")
     out = x.astype(jnp.float32) * cos + xr.astype(jnp.float32) * sin
     return out.astype(x.dtype)
+
+
+def rope_inv_freq(rope_parameters: dict, head_dim: int) -> tuple:
+    """``(inv_freq, attention_factor)`` of one layer kind's published
+    ``rope_parameters`` (Hugging Face's ``rope_type`` ``default`` or
+    ``yarn``; ``_compute_yarn_parameters`` with ``truncate`` true).
+
+    The rotary turns ``rotary_dim = head_dim * partial_rotary_factor``
+    lanes, ``rotary_dim / 2`` frequencies ``theta ** (-2i / rotary_dim)``.
+    YaRN blends, frequency by frequency, the original one
+    (extrapolation) and the one slowed by ``factor`` (interpolation) by
+    a linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position_embeddings``;
+    ``attention_factor`` (``0.1 ln(factor) + 1`` where not given) scales
+    cos and sin.  float32 numpy, as the published code computes them."""
+    import math
+
+    import numpy as np
+    p = rope_parameters
+    dim = int(head_dim * p.get("partial_rotary_factor", 1.0))
+    base = float(p["rope_theta"])
+    pos = base ** (np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim))
+    kind = p.get("rope_type", "default")
+    if kind == "default":
+        return (1.0 / pos).astype(np.float32), 1.0
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r} has no tables here")
+    factor = float(p["factor"])
+    original = float(p["original_max_position_embeddings"])
+    scale = p.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    def correction_dim(rotations):
+        return (dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(p.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(p.get("beta_slow", 1))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / np.float32(high - low if high != low else 0.001), 0, 1)
+    extrapolation = 1.0 - ramp
+    inv_freq = ((1.0 / (factor * pos)) * (1.0 - extrapolation)
+                + (1.0 / pos) * extrapolation)
+    return inv_freq.astype(np.float32), float(scale)
+
+
+def scaled_rope_tables(positions: jax.Array, inv_freq,
+                       attention_factor: float = 1.0) -> tuple:
+    """(cos, sin) ``(B, L, 1, len(inv_freq))`` of global positions at the
+    given frequencies, both times ``attention_factor``: the half-width
+    tables :func:`rope_tables` gives, for any frequencies."""
+    angles = (positions[:, :, None].astype(jnp.float32)
+              * jnp.asarray(inv_freq, jnp.float32))      # (B, L, half)
+    cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    if attention_factor == 1.0:
+        return cos, sin
+    return attention_factor * cos, attention_factor * sin
+
+
+def to_rotary_lanes(x: jax.Array, rotary_dim: int) -> jax.Array:
+    """``x`` ``(..., head_dim)`` in the kernels' lane order, where the
+    rotary turns only the first ``rotary_dim`` lanes of a head
+    (rotate-half inside them: lanes ``i`` and ``i + rotary_dim / 2``).
+    The kernels turn lane ``j`` with ``j + head_dim / 2``, so the pairs
+    go to lanes ``i`` and ``i + head_dim / 2`` and the lanes that pass
+    fill the rest in order: four runs of lanes, a concatenation of
+    slices and no gather.  q and k take the same order, so their
+    product, and every score, is unchanged."""
+    d = x.shape[-1]
+    if rotary_dim == d:
+        return x
+    half, rhalf = d // 2, rotary_dim // 2
+    split = rotary_dim + half - rhalf
+    return jnp.concatenate([x[..., :rhalf], x[..., rotary_dim:split],
+                            x[..., rhalf:rotary_dim], x[..., split:]],
+                           axis=-1)
+
+
+def partial_rope_kernel_tables(cos: jax.Array, sin: jax.Array,
+                               head_dim: int) -> KernelRopeTables:
+    """The kernels' full-width tables for a head whose lanes are in
+    :func:`to_rotary_lanes`' order: ``cos`` and ``sin`` ``(B, L, 1,
+    rotary_dim / 2)`` on the turning lanes (sine folded as
+    :func:`rope_kernel_tables` folds it), cos 1 and sin 0 on the lanes
+    that pass, so those come out as they went in."""
+    b, l, rhalf = cos.shape[0], cos.shape[1], cos.shape[-1]
+    cos = cos.reshape(b, l, rhalf)
+    sin = sin.reshape(b, l, rhalf)
+    ones = jnp.ones((b, l, head_dim // 2 - rhalf), cos.dtype)
+    zeros = jnp.zeros_like(ones)
+    return KernelRopeTables(jnp.concatenate([cos, ones, cos, ones], -1),
+                            jnp.concatenate([-sin, zeros, sin, zeros], -1))

@@ -81,6 +81,12 @@ KDA_PROJECT, KDA_CONV, KDA_RECURRENCE = KDA_SCOPES = (
 SHORTCONV_PROJECT, SHORTCONV_GATE = SHORTCONV_SCOPES = (
     "shortconv_project", "shortconv_gate")
 
+#: The scope of a sliding-window attention layer's windowed flash call
+#: (:mod:`apex_tpu.models.laguna`), inside its ``attention`` module:
+#: the window kernels and what their call reshapes, forward, backward and
+#: what remat recomputes.
+SWA_WINDOW, = SWA_SCOPES = ("swa_window",)
+
 
 @contextlib.contextmanager
 def nvtx_range(name: str):

@@ -354,11 +354,12 @@ class TestRopeFused:
             flash_attention(q, k[:, :128], v[:, :128], rope=(cos, sin))
 
     def test_fp32_defaults_capped_at_512(self, monkeypatch):
-        """fp32 + rope caps *defaulted* blocks at 512 (1024-blocks blow
-        the scoped-VMEM limit in the fused backward — measured on the
-        O0 L2048 train step); explicit requests pass through.  The
-        grid walk's defaults: a causal head of 2048 rows is resident
-        since PR 28, so the calls here are not causal."""
+        """fp32 caps *defaulted* blocks at 512, with rope or without
+        (1024-blocks blow the scoped-VMEM limit in the fused backward —
+        measured on the O0 L2048 train step with rope, and without it
+        compiling for a described v5e at 8 rows of 12 heads); explicit
+        requests pass through.  The grid walk's defaults: a causal head
+        of 2048 rows is resident, so the calls here are not causal."""
         from apex_tpu.ops.pallas import flash_attention as fa
         seen = []
         real = fa._flash
@@ -383,9 +384,9 @@ class TestRopeFused:
                            v.astype(jnp.bfloat16), causal=False,
                            rope=(cos, sin))
         assert seen[-1][:2] == (1024, 1024)
-        # no rope: fp32 keeps the 1024 default (unchanged behavior)
+        # no rope: fp32 is capped too
         fa.flash_attention(q, k, v, causal=False)
-        assert seen[-1][:2] == (1024, 1024)
+        assert seen[-1][:2] == (512, 512)
         assert seen[-1][2] is None
 
     @pytest.mark.slow
