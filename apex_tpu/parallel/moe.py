@@ -38,6 +38,9 @@ load of any skew runs as many windows as it fills, so nothing is
 dropped and nothing approximated.  A layer that holds every expert, or
 whose buffer is no longer than a window, has one window: the same body
 once, with no loop.  ``stats["windows"]`` counts the windows run.
+A window's combine into the tokens' rows, and the way home of its
+cotangent, read on the chip only the rows of the pairs held in the
+window (:mod:`apex_tpu.ops.pallas.moe_combine`).
 """
 
 from __future__ import annotations
@@ -51,6 +54,7 @@ from jax import lax
 
 from apex_tpu.models.generate import greedy_argmax
 from apex_tpu.ops import on_tpu, use_pallas
+from apex_tpu.ops.pallas.moe_combine import combine_geometry, moe_combine
 from apex_tpu.utils.profiling import MOE_DISPATCH, MOE_EXPERTS
 
 
@@ -310,7 +314,7 @@ def _walk(window_fn, closed, x, weights, windows: _Windows):
     buffer walked window by window: a window gathers its rows of ``x``,
     runs the experts on them (``window_fn(rows, sizes, *closed)``) and
     adds to every row of ``x`` the weighted results of its pairs that
-    lie in the window."""
+    lie in the window (:func:`_combine_block` says by which route)."""
     return _walk_fwd(window_fn, closed, x, weights, windows)[0]
 
 
@@ -327,7 +331,11 @@ def _walk_fwd(window_fn, closed, x, weights, windows):
         else:
             out = window_fn(rows, sizes, *closed)
         with jax.named_scope(MOE_DISPATCH):
-            results = _taken_rows(out, *windows.pairs_of(first))
+            at, inside = windows.pairs_of(first)
+            block = _combine_block(y, out, at)
+            if block:
+                return moe_combine(y, out, at, inside, weights, block=block)
+            results = _taken_rows(out, at, inside)
             return y + _combine(results, weights, t, jnp.float32)
 
     with jax.named_scope(MOE_DISPATCH):
@@ -352,7 +360,9 @@ def _walk_bwd(window_fn, saved, grad):
     rows of ``x``, the pairs' weights and the experts' weights to three
     sums.  What a row of ``x`` is owed comes home by a gather and a sum
     over the places it went to, not by a scatter-add (five times slower
-    on the v5e at 49152 rows of 2048, PERF.md PR 27).  The first two
+    on the v5e at 49152 rows of 2048, PERF.md PR 27): on the chip the
+    kernel ``moe_combine``, which reads the rows of the pairs held in the
+    window only (:func:`_combine_block`).  The first two
     sums are float32.  The experts' weights' is in their own dtype, each
     addition made in float32: the grouped product has rounded a window's
     part to that dtype before it is seen here, so a float32 sum would
@@ -377,11 +387,18 @@ def _walk_bwd(window_fn, saved, grad):
         with jax.named_scope(MOE_DISPATCH):
             at, inside = windows.pairs_of(first)
             counted = jnp.sum(out.astype(jnp.float32) * d_y, axis=-1)
-            home = _taken_rows(d_rows, at, inside).reshape(
-                -1, t, *x.shape[1:])
-            return jax.tree.map(add, owed, (
-                tuple(d_closed), jnp.sum(home.astype(jnp.float32), axis=0),
-                jnp.where(inside, counted[at % counted.shape[0]], 0.0)))
+            block = _combine_block(owed[1], d_rows, at)
+            # the kernel adds what comes home to the sum itself
+            home = moe_combine(owed[1], d_rows, at, inside, block=block) \
+                if block else jnp.sum(_taken_rows(d_rows, at, inside).reshape(
+                    -1, t, *x.shape[1:]).astype(jnp.float32), axis=0)
+            d_weights = jnp.where(inside, counted[at % counted.shape[0]], 0.0)
+            if not block:
+                return jax.tree.map(add, owed, (tuple(d_closed), home,
+                                                d_weights))
+            d_closed, d_weights = jax.tree.map(
+                add, (owed[0], owed[2]), (tuple(d_closed), d_weights))
+            return d_closed, home, d_weights
 
     with jax.named_scope(MOE_DISPATCH):
         likes = (closed, x, weights, grad)
@@ -474,6 +491,20 @@ def _combine(results, weights, t: int, dtype):
     y = jnp.sum(results.reshape(-1, t, results.shape[-1]).astype(jnp.float32)
                 * weights.reshape(-1, t, 1), axis=0)
     return y.astype(dtype)
+
+
+def _combine_block(acc, src, at):
+    """Tokens a grid step of the kernel ``moe_combine`` takes in a
+    window's combine of ``src``'s rows into ``acc`` (or the way home of
+    its cotangent), or ``None``: where ``use_pallas()`` and
+    :func:`~apex_tpu.ops.pallas.moe_combine.combine_geometry` allow, the
+    kernel reads the rows of the pairs held in the window and nothing
+    else; every other shape, and the CPU, takes the XLA form, a row for
+    every (token, choice) pair summed over the choices (the kernel's
+    oracle).  Both sum a token's terms in float32 from 0 in the order of
+    its choices and then add that sum to ``acc``."""
+    return combine_geometry(acc.shape[0], src.shape[-1], src.shape[0],
+                            at.shape[0], src.dtype) if use_pallas() else None
 
 
 def moe_apply(

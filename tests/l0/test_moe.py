@@ -451,11 +451,19 @@ def test_the_walk_holds_the_layer_once(n_experts, windows):
     assert {int(shape.split(",")[0]) for shape in per_row} == {rows}
 
 
-def test_the_scopes_stand_on_the_instructions_inside_the_walk(walk_data):
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_the_scopes_stand_on_the_instructions_inside_the_walk(request,
+                                                             route):
     """``benchmark/scopes.py`` reads the ``op_name`` of each instruction
     of the compiled step: inside the loop's body every instruction of a
-    window is under ``moe_dispatch`` or ``moe_experts``, never both."""
-    p, x, w_r, _ = walk_data
+    window is under ``moe_dispatch`` or ``moe_experts``, never both.  On
+    the kernel route (a width the combine's kernel takes, the kernels
+    asked for) every instruction of ``moe_combine``, forward and
+    backward, is under ``moe_dispatch``."""
+    if route == "kernel":
+        request.getfixturevalue("kernel_route")
+    p, x, w_r, _ = request.getfixturevalue(
+        "kernel_walk_data" if route == "kernel" else "walk_data")
     hlo = jax.jit(jax.value_and_grad(
         lambda *a: jnp.sum(walked(*a, boosts((8, 8)))[0]),
         argnums=(0, 1, 2))).lower(take(p, WHELD), x, w_r).compile().as_text()
@@ -470,3 +478,89 @@ def test_the_scopes_stand_on_the_instructions_inside_the_walk(walk_data):
     # the loop's own counter apart, every instruction of the body is scoped
     bare = [s for s in inside if not s & {MOE_DISPATCH, MOE_EXPERTS}]
     assert len(bare) <= 3, bare
+    combine = [s for s in inside if "moe_combine" in s]
+    assert bool(combine) == (route == "kernel")
+    assert all(MOE_DISPATCH in s for s in combine)
+    assert {"transpose" in s for s in combine} == (
+        {False, True} if combine else set())
+
+
+# --------------------------------------------- the walk on the kernel route
+#
+# The kernels asked for on the CPU (interpret mode): the grouped products
+# by megablox, the combine and its transpose by ``moe_combine``, at a
+# width the combine's kernel takes.
+
+KD, KF = 256, 128
+
+
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """The kernels asked for.  ``use_pallas()`` is read when a function is
+    traced, and the walk's jitted parts keep their traces: none is kept
+    from before or carried past the test."""
+    jax.clear_caches()
+    monkeypatch.setenv("APEX_TPU_KERNELS", "pallas")
+    yield
+    monkeypatch.delenv("APEX_TPU_KERNELS")
+    jax.clear_caches()
+
+
+@pytest.fixture(scope="module")
+def kernel_walk_data():
+    return (jax.tree.map(lambda a: a * 0.25,
+                         experts(jax.random.PRNGKey(4), WE, KD, KF)),
+            jax.random.normal(jax.random.PRNGKey(5), (WT, KD)),
+            jax.random.normal(jax.random.PRNGKey(6), (KD, WE)) * 0.03,
+            jax.random.normal(jax.random.PRNGKey(7), (WT, KD)))
+
+
+def test_the_walk_on_the_kernel_route_matches_the_oracle_and_the_dense_layer(
+        kernel_walk_data, kernel_route):
+    """A run straddles two windows, so the second window's combine adds
+    to what the first left and the cotangent comes home from both: the
+    output against the per-token oracle, the gradients of the rows, the
+    experts' weights and the router's against the dense formulation."""
+    p, x, w_r, t = kernel_walk_data
+    loads, windows = LOADS["a_run_straddles_two_windows"]
+    boost, p_held = boosts(loads), take(p, WHELD)
+    y, stats = jax.jit(walked)(p_held, x, w_r, boost)
+    assert int(stats["windows"]) == windows
+    r = moe.route(x @ w_r + boost, WK, scoring="sigmoid", renormalize=True)
+    np.testing.assert_allclose(np.asarray(y), oracle(p, x, r, WHELD),
+                               rtol=2e-4, atol=2e-5)
+
+    def loss(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a, boost) * t),
+                        argnums=(0, 1, 2))
+    walk = loss(lambda *a: walked(*a)[0])
+    assert "moe_combine" in str(jax.make_jaxpr(walk)(p_held, x, w_r))
+    got, want = [jax.jit(g)(p_held, x, w_r) for g in (walk, loss(densely))]
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5)
+
+
+def test_the_kernel_route_holds_no_array_of_a_row_per_pair(
+        kernel_walk_data, request):
+    """The XLA form gathers a row for every (token, choice) pair, ``(k T,
+    d)``, forward and backward; the lowered value and gradient of the
+    layer on the kernel route holds no array of that shape."""
+    p, x, w_r, _ = kernel_walk_data
+    k = 3
+
+    def layer(p_held, x, w_r):
+        r = moe.route(x @ w_r + boosts((8, 8)), k, scoring="sigmoid",
+                      renormalize=True)
+        return jnp.sum(moe.moe_apply(moe.gated_ffn, p_held, x, r,
+                                     n_experts=WE, first=WHELD.start)[0])
+
+    def lowered():
+        return jax.jit(jax.value_and_grad(layer, argnums=(0, 1, 2))).lower(
+            take(p, WHELD), x, w_r).as_text()
+
+    per_pair = re.compile(rf"tensor<{k * WT}x{KD}x(bf16|f32)>")
+    assert per_pair.search(lowered())
+    request.getfixturevalue("kernel_route")
+    text = lowered()
+    assert "moe_combine" in text and not per_pair.search(text)
